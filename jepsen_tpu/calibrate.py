@@ -16,8 +16,8 @@ scheduling from live instrumentation, arXiv 2605.07881):
     current estimate (one wedged 60 s chunk cannot blow up the fit)
     and folded in with a step that decays from plain averaging to an
     EWMA (``ALPHA_MIN``), so the fit converges fast from cold and
-    still tracks drift (thermal throttling, a relay slowdown).
-  * **Persistence.** Coefficients live in a small JSON file *next to
+    still tracks drift (thermal throttling, a slower host).
+  * **Persistence.** Coefficients live in a small JSON file *in
     the JAX compile cache* (per platform:
     ``calibration-<platform>.json``), written by the service daemon
     at drain and loaded at daemon start — a restarted fleet prices
@@ -92,13 +92,13 @@ def detect_platform() -> str:
 
 
 def default_path(platform: str | None = None) -> str:
-    """`calibration-<platform>.json` next to the JAX compile cache
-    (same placement lever as `_platform.enable_compilation_cache`):
-    the compile cache keeps kernels warm across daemon restarts, this
-    file keeps the cost model warm."""
-    base = os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.join(
-        os.path.expanduser("~"), ".cache", "jepsen-tpu", "jax")
-    return os.path.join(os.path.dirname(base.rstrip(os.sep)),
+    """`calibration-<platform>.json` in the JAX compile cache directory
+    (`_platform.compilation_cache_dir`): the compile cache keeps
+    kernels warm across daemon restarts, this file keeps the cost
+    model warm."""
+    from ._platform import compilation_cache_dir
+
+    return os.path.join(compilation_cache_dir(),
                         f"calibration-{platform or detect_platform()}"
                         ".json")
 

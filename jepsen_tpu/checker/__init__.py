@@ -104,7 +104,7 @@ def check_safe(checker, test, hist, opts=None, name=None) -> dict:
 
     Backend failures are routed through
     `_platform.classify_backend_error`: only an exception the
-    classifier recognizes (jax's XlaRuntimeError family — device init,
+    classifier recognizes (jax's JaxRuntimeError family — device init,
     device OOM, preemption, a wedged sync — plus the platform module's
     own classified fault types) reports 'degraded': True with its
     'fault' bucket. An ordinary checker bug raised as a plain

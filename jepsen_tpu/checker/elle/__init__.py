@@ -12,7 +12,7 @@ short-circuit with zero device work, which is what lets 100k-txn
 north-star histories (BASELINE config 5) check in seconds.
 
 Anomaly specs accept Adya shorthand: 'G1' expands to G1a+G1b+G1c, 'G2'
-to G-single+G2-item (matching `tests/cycle/wr.clj:31-45`'s taxonomy).
+to G-single+G2-item (matching `tests/cycle/wr.clj:31-45`'s classification).
 """
 
 from __future__ import annotations

@@ -30,7 +30,7 @@ vmapped batch axis, so the MXU kernel itself never changes). Cycles
 that *require* a precedence edge classify as G0-process, G0-realtime,
 G1c-process, G1c-realtime, G-single-process, G-single-realtime,
 G2-item-process, G2-item-realtime — the reference's `elle.txn`
-taxonomy.
+classification.
 """
 
 from __future__ import annotations

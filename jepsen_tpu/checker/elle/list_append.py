@@ -319,4 +319,5 @@ def check(hist, anomalies=DEFAULT_ANOMALIES, mesh=None,
         "anomaly-types": sorted(reported),
         "anomalies": reported,
         "txn-count": len(txns),
+        **kernels.classifier_info(cyc),
     }

@@ -1,6 +1,6 @@
 """Elle-class rw-register checker (reference consumes
 `elle.rw-register/check` via `jepsen/src/jepsen/tests/cycle/wr.clj:14-54`,
-anomaly taxonomy documented there at lines 31-45).
+anomaly classification documented there at lines 31-45).
 
 Txns mix ['w', k, v] and ['r', k, v] micro-ops over registers. Writes are
 assumed globally unique per key (duplicates are flagged); version order is
@@ -228,4 +228,5 @@ def check(hist, anomalies=DEFAULT_ANOMALIES, mesh=None,
         "anomaly-types": sorted(reported),
         "anomalies": reported,
         "txn-count": len(txns),
+        **kernels.classifier_info(cyc),
     }

@@ -182,9 +182,11 @@ def adversarial_register_history(n_ops: int, concurrency: int = 6,
     return History(ops)
 
 
-def corrupt(hist: History, seed: int = 7) -> History:
+def corrupt(hist: History, seed: int = 7, value: int = 10 ** 6) -> History:
     """Break a valid register history: rewrite one :ok read to a value that
-    was never current at any point in its window (forced stale/phantom)."""
+    was never current at any point in its window (forced stale/phantom).
+    `value` must lie outside the generator's domain; one just past it
+    (`values`) keeps the state range, and so the engine, unchanged."""
     rng = random.Random(seed)
     ops = [dict(o) for o in hist.ops]
     reads = [i for i, o in enumerate(ops)
@@ -194,7 +196,7 @@ def corrupt(hist: History, seed: int = 7) -> History:
     i = rng.choice(reads)
     # a value outside the generator's domain can never be read legally
     # (NIL aside), so this must be caught
-    ops[i]["value"] = 10 ** 6
+    ops[i]["value"] = value
     return History(ops)
 
 

@@ -53,10 +53,12 @@ a (W+2)-lane sort network stage, and skips dead candidates (stage B's
 legality mask is usually almost empty) in one compare each.
 
 Status: opt-in everywhere via JEPSEN_TPU_PALLAS_DEDUP=1 (interpret
-mode off-TPU), DEFAULT ON for real TPU backends per the closure
-kernel's precedent, opt-out with =0.  Correctness is pinned in
-interpret mode by tests/test_wgl_dedup.py; hardware numbers land in
-doc/perf/dedup.md once measured on the chip.
+mode off-TPU), OFF by default on TPU too: Mosaic refuses the kernel
+for v5e ("Cannot store scalars to VMEM" — the scalar table and output
+stores), pinned by tests/test_chip_compile.py.  On a TPU the opt-in
+raises that compiler error; it never switches paths quietly.  The
+repair is a later perf PR with chip numbers (ROADMAP.md, Speed).
+Correctness is pinned in interpret mode by tests/test_wgl_dedup.py.
 """
 
 from __future__ import annotations
@@ -75,37 +77,6 @@ def table_size(n: int) -> int:
     from .wgl import _bucket
 
     return 2 * _bucket(n)
-
-
-_PROBE: bool | None = None   # one-time Mosaic compile probe result
-
-
-def compiles() -> bool:
-    """Does the hash kernel actually lower through Mosaic on this
-    backend?  The kernel's scalar probe loop (dynamic VMEM indexing
-    inside while_loop inside fori_loop) is exactly the kind of shape
-    a Mosaic release can reject, and the hardware numbers are still
-    pending (doc/perf/dedup.md) — so the first real-TPU use pays one
-    tiny compile here, and a rejection downgrades to the proven sort
-    path instead of raising out of the checker mid-run.  Resolved
-    once per process; interpret mode never needs it (pure JAX)."""
-    global _PROBE
-    if _PROBE is None:
-        try:
-            import numpy as np
-
-            from .._platform import guarded_device_get
-
-            fn = dedup_fn(8, 4, interpret=False)
-            # guarded: a wedged relay at probe time must downgrade to
-            # the sort path (via the except below), not hang the first
-            # checker call of the process forever
-            out, _new, cnt, _dig = guarded_device_get(
-                fn(np.arange(8, dtype=np.int32)), site="dedup probe")
-            _PROBE = int(cnt) == 8 and list(map(int, out)) == [0, 1, 2, 3]
-        except Exception:   # Mosaic lowering/compile failure
-            _PROBE = False
-    return _PROBE
 
 
 def eligible(F: int, P: int) -> bool:

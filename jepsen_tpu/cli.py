@@ -309,22 +309,15 @@ def _resolve_opt_fn(opts: dict):
     return opts.get("opt_fn_") or opt_fn
 
 
-def _enable_compile_cache(options: dict) -> None:
-    """Persistent JAX compilation cache for the CLI runner, under the
-    run's store directory (bench.py has used the same lever for its
-    per-section subprocesses since r05: the cache is what keeps repeat
-    invocations from re-paying every kernel compile). Env-gated via
-    JEPSEN_TPU_COMPILE_CACHE=0 / an explicit JAX_COMPILATION_CACHE_DIR
-    — see _platform.enable_compilation_cache."""
-    import os
+def _enable_compile_cache() -> None:
+    """Persistent JAX compilation cache for the CLI runner and the
+    service daemon (_platform.use_compilation_cache: the environment's
+    JAX_COMPILATION_CACHE_DIR, else <checkout>/.jax_cache), so repeat
+    invocations skip recompiling the checker kernels."""
+    from ._platform import use_compilation_cache
 
-    from ._platform import enable_compilation_cache
-
-    store_dir = options.get("store-dir") or options.get("store_dir")
-    d = enable_compilation_cache(
-        os.path.join(store_dir, ".jax_cache") if store_dir else None)
-    if d:
-        log.info("JAX persistent compilation cache: %s", d)
+    log.info("JAX persistent compilation cache: %s",
+             use_compilation_cache())
 
 
 def single_test_cmd(opts: dict) -> dict:
@@ -343,7 +336,7 @@ def single_test_cmd(opts: dict) -> dict:
 
     def run_test(options):
         log.info("Test options:\n%s", _pprint.pformat(options))
-        _enable_compile_cache(options)
+        _enable_compile_cache()
         # test_count fallback: an opt_fn_ override replaces the pipeline
         # that remaps argparse's test_count to test-count
         for _ in range(options.get("test-count",
@@ -357,7 +350,7 @@ def single_test_cmd(opts: dict) -> dict:
     def run_analyze(options):
         from . import store
         log.info("Test options:\n%s", _pprint.pformat(options))
-        _enable_compile_cache(options)
+        _enable_compile_cache()
         cli_test = test_fn(options)
         latest = store.latest(cli_test.get("store-dir", "store"))
         if latest is None:
@@ -589,6 +582,7 @@ def service_cmd() -> dict:
         # cache, loaded at start, saved back at drain — a restarted
         # fleet prices work in measured device-seconds from its
         # first chunk (jepsen_tpu/calibrate.py)
+        _enable_compile_cache()
         cal = _calibrate.Calibration.load()
         if cal.coefficients():
             log.info("calibration loaded: %s", cal.coefficients())

@@ -319,8 +319,9 @@ class IndependentChecker(Checker):
     one vmapped kernel call over all keys instead of per-key host checks.
 
     strict_device=True turns a failed device batch into a raised error
-    instead of a silent host fallback — use in tests/CI so a broken
-    kernel can't hide behind the (correct but slow) host oracle.
+    instead of a host fallback — use in tests/CI so a broken kernel
+    can't hide behind the (correct but slow) host oracle. Without it,
+    a fallback is logged and named in the result ('device-fallback').
     """
 
     def __init__(self, subchecker, strict_device: bool = False):
@@ -328,7 +329,8 @@ class IndependentChecker(Checker):
         self.strict_device = strict_device
 
     def _batched_tpu(self, test, hist, opts, ks):
-        """Batched per-key device check, or None if not applicable."""
+        """Batched per-key device check; None if not applicable, the
+        exception if the device batch failed (non-strict)."""
         from .checker.linear import Linearizable
         c = self.subchecker
         if not isinstance(c, Linearizable):
@@ -343,7 +345,7 @@ class IndependentChecker(Checker):
         try:
             return dict(zip(ks, analysis_tpu_batch(c.model, subs,
                                                    **c.opts)))
-        except Exception:
+        except Exception as e:
             if self.strict_device:
                 raise
             import logging
@@ -351,12 +353,16 @@ class IndependentChecker(Checker):
                 "batched device check failed; falling back to per-key "
                 "host checks (pass strict_device=True to raise instead)",
                 exc_info=True)
-            return None
+            return e
 
     def check(self, test, hist, opts):
         hist = as_history(hist).index()
         ks = history_keys(hist)
         results = self._batched_tpu(test, hist, opts, ks)
+        fallback = None
+        if isinstance(results, Exception):
+            fallback = {"error": f"{type(results).__name__}: {results}"}
+            results = None
         if results is None:
             def one(k):
                 sub_opts = dict(opts)
@@ -367,11 +373,14 @@ class IndependentChecker(Checker):
         valids = {k: (r or {}).get("valid?", True)
                   for k, r in results.items()}
         failures = [k for k, v in valids.items() if v is False]
-        return {
+        out = {
             "valid?": merge_valid(valids.values()) if valids else True,
             "results": results,
             "failures": failures,
         }
+        if fallback is not None:
+            out["device-fallback"] = fallback
+        return out
 
 
 def checker(subchecker, strict_device: bool = False) -> Checker:

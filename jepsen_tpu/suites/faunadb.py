@@ -209,7 +209,7 @@ def with_retry(thunk, tries: int = 5, sleep_s: float = 0.2):
 def with_errors(op: dict, idempotent: frozenset, thunk,
                 pause_s: float = 1.0) -> dict:
     """Run thunk, mapping Fauna/network failures to :fail / :info per
-    the reference's taxonomy (`client.clj:375-418`)."""
+    the reference's classification (`client.clj:375-418`)."""
     crash = "fail" if op["f"] in idempotent else "info"
     try:
         return thunk()

@@ -58,7 +58,7 @@ METRICS_ENV = "JEPSEN_TPU_METRICS"
 PROFILE_ENV = "JEPSEN_TPU_PROFILE"
 
 # latency buckets (seconds): device chunks span ~100us (warm CPU sort
-# chunk) to minutes (a cold compile on a wedged relay)
+# chunk) to minutes (a cold compile of a large kernel)
 DEFAULT_BUCKETS = (0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05,
                    0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0, 60.0,
                    120.0)

@@ -1,6 +1,6 @@
 """Adya G2 anti-dependency-cycle probe over *predicates* (reference
 `jepsen/src/jepsen/tests/adya.clj`; see Adya's thesis for the anomaly
-taxonomy).
+classification).
 
 For each key, exactly two concurrent :insert txns run: one holding an
 a-table id, one a b-table id ({'f': 'insert', 'value': (key, [a_id,

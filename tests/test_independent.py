@@ -156,6 +156,7 @@ def test_independent_strict_device_raises_and_default_falls_back(
         res = independent.checker(c).check({}, _kv_history(), {})
     assert res["valid?"] is False and res["failures"] == ["b"]
     assert any("falling back" in r.message for r in caplog.records)
+    assert "simulated" in res["device-fallback"]["error"]
 
 
 def test_concurrent_generator_skips_empty_key_generators():

@@ -15,6 +15,8 @@ each kernel compile once.
 
 from __future__ import annotations
 
+import os
+
 import numpy as np
 import pytest
 
@@ -79,8 +81,24 @@ def _always(kind, site):
     ("INTERNAL: something opaque", "wedged"),   # xla but unmatched
 ])
 def test_classifier_buckets_xla_errors(msg, bucket):
-    from jaxlib.xla_extension import XlaRuntimeError
-    assert plat.classify_backend_error(XlaRuntimeError(msg)) == bucket
+    from jax.errors import JaxRuntimeError
+    assert plat.classify_backend_error(JaxRuntimeError(msg)) == bucket
+
+
+def test_compilation_cache_uses_the_environment_dir(monkeypatch, tmp_path):
+    import jax
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert plat.use_compilation_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+
+
+def test_compilation_cache_defaults_to_the_checkout(monkeypatch):
+    import jax
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    want = os.path.join(root, ".jax_cache")
+    assert plat.use_compilation_cache() == want
+    assert jax.config.jax_compilation_cache_dir == want
 
 
 def test_classifier_rejects_ordinary_exceptions():

@@ -518,7 +518,8 @@ def test_core_run_abort_on_violation(tmp_path):
 def test_cli_online_end_to_end(tmp_path, monkeypatch):
     from jepsen_tpu import cli
 
-    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    cache_dir = str(tmp_path / "jax-cache")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", cache_dir)
 
     def test_fn(options):
         t = _atom_test(tmp_path, n=120)
@@ -537,10 +538,10 @@ def test_cli_online_end_to_end(tmp_path, monkeypatch):
                        "--abort-on-violation",
                        "--store-dir", str(tmp_path / "store")])
     assert e.value.code == 0
-    # the persistent compilation cache satellite: env-gated enablement
-    import os
-    assert os.environ["JAX_COMPILATION_CACHE_DIR"].endswith(
-        ".jax_cache")
+    # the CLI applied the persistent compilation cache, in the
+    # environment's directory and no other
+    import jax
+    assert jax.config.jax_compilation_cache_dir == cache_dir
     stored = store.load_test(str(tmp_path / "store" / "latest"))
     assert stored["results"]["valid?"] is True
     assert stored["results"].get("streamed") is True

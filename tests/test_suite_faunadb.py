@@ -92,7 +92,7 @@ def test_index_match_and_pagination(fake):
 
 
 def test_error_classification(fake):
-    """with-errors taxonomy (`client.clj:375-418`)."""
+    """with-errors classification (`client.clj:375-418`)."""
     op = {"f": "read", "process": 0}
     wop = {"f": "write", "process": 0}
     fake.fail_hook = lambda e: (503, "unavailable", "replica down")

@@ -318,24 +318,41 @@ def test_env_gate_flips_next_call(monkeypatch):
     assert a["valid?"] is b["valid?"] is True
 
 
-def test_tpu_compile_probe_gates_hash_dedup(monkeypatch):
-    """On a real TPU a failed one-time Mosaic compile probe downgrades
-    the hash dedup to the sort path instead of raising out of the
-    checker mid-run; interpret mode (off-TPU) never consults it."""
+def _pretend_tpu(monkeypatch):
+    import jax
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def test_hash_dedup_off_by_default_on_tpu(monkeypatch):
+    """On a TPU backend the sort dedup is the default (Mosaic refuses
+    the hash kernel today); the closure kernel stays on."""
+    monkeypatch.delenv(wgl.PALLAS_DEDUP_ENV, raising=False)
+    monkeypatch.delenv(wgl.PALLAS_CLOSURE_ENV, raising=False)
+    _pretend_tpu(monkeypatch)
+    assert wgl._pallas_enabled(wgl.PALLAS_DEDUP_ENV) == (False, True)
+    assert wgl._pallas_enabled(wgl.PALLAS_CLOSURE_ENV) == (True, True)
     pack = wgl._pack_params((-1, 3), SLOTS)
-    assert pack is not None
-    monkeypatch.setattr(wgl_dedup, "_PROBE", False)
-    assert not wgl._hash_gate(FRONTIER, SLOTS, pack, on_tpu=True)
-    assert wgl._hash_gate(FRONTIER, SLOTS, pack, on_tpu=False)
-    monkeypatch.setattr(wgl_dedup, "_PROBE", True)
-    assert wgl._hash_gate(FRONTIER, SLOTS, pack, on_tpu=True)
+    assert pack is not None and wgl._hash_gate(FRONTIER, SLOTS, pack)
+    assert wgl.dedup_engine(FRONTIER, SLOTS, pack) == wgl.DEDUP_SORT
 
 
-def test_compile_probe_is_cached_and_never_raises(monkeypatch):
-    monkeypatch.setattr(wgl_dedup, "_PROBE", None)
-    r = wgl_dedup.compiles()
-    assert isinstance(r, bool)
-    assert wgl_dedup._PROBE is r           # resolved once per process
+def test_hash_dedup_opt_in_raises_on_refused_compile(monkeypatch):
+    """JEPSEN_TPU_PALLAS_DEDUP=1 on a TPU builds the Mosaic kernel, and
+    a refused compile raises out of the kernel call instead of quietly
+    switching to the sort dedup. (Off the chip, the non-interpret
+    Pallas call is refused just the same.)"""
+    monkeypatch.setenv(wgl.PALLAS_DEDUP_ENV, "1")
+    _pretend_tpu(monkeypatch)
+    pack = wgl._pack_params((-1, 3), SLOTS)
+    assert wgl.dedup_engine(FRONTIER, SLOTS, pack) == wgl.DEDUP_PALLAS
+    k = wgl._kernel(MODEL.device_model, FRONTIER, SLOTS, 64, pack)
+    steps = wgl.build_steps(wgl.encode_ops_for_model(MODEL, _hist(n=20)),
+                            SLOTS).pad_to(64)
+    monkeypatch.undo()   # dispatch on the real (CPU) backend
+    with pytest.raises(Exception):
+        k.check(steps.x, np.int32(steps.n),
+                np.int32(MODEL.device_state()))
+    wgl._kernel.cache_clear()
 
 
 # -- broader sweep: excluded from tier-1 ------------------------------------

@@ -22,10 +22,6 @@ from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
-from jepsen_tpu._platform import honor_platform_env  # noqa: E402
-
-honor_platform_env()
-
 
 def main() -> int:
     ap = argparse.ArgumentParser()

@@ -26,7 +26,7 @@ from .retrace import RetraceAnalyzer
 from .style import StyleAnalyzer
 
 ROOTS = ["jepsen_tpu", "tests", "tools", "bench.py",
-         "__graft_entry__.py"]
+         "__graft_entry__.py", "chip_smoke.py"]
 ANALYZER_ORDER = ("style", "metrics", "device-sync", "locks",
                   "retrace")
 
