@@ -34,6 +34,9 @@ from __future__ import annotations
 import fnmatch
 import os
 import threading
+import time
+
+from . import telemetry as _telemetry
 
 # Fault buckets (classify_backend_error return values). Anything the
 # classifier recognizes as a backend failure but cannot place more
@@ -492,22 +495,38 @@ def sync_deadline_s() -> float | None:
     return v if v > 0 else None
 
 
+# every host sync of the device checkers passes through
+# guarded_device_get: its count is the host-sync count, its sum the
+# host's seconds blocked on the device
+_M_SYNC = _telemetry.histogram(
+    "jepsen_tpu_wgl_sync_seconds",
+    "Host seconds blocked in a guarded device fetch, by call site",
+    ("site",), buckets=_telemetry.FINE_BUCKETS)
+
+
 def guarded_device_get(x, deadline_s: float | None = None,
                        site: str = "device-sync"):
     """jax.device_get under a watchdog deadline: a wedged TPU call
     becomes a WedgedDeviceSync (a classified, recoverable fault)
     instead of blocking its caller forever. deadline_s=None defers to
     the env knob; with neither set this is a plain device_get with no
-    thread spawned."""
+    thread spawned. The wait is timed into jepsen_tpu_wgl_sync_seconds
+    under `site`, which callers pass as a fixed string (one series per
+    call site)."""
     import jax
 
     if deadline_s is None:
         deadline_s = sync_deadline_s()
-    if not deadline_s:
-        return jax.device_get(x)
-    from .util import TIMED_OUT, timeout
-    r = timeout(deadline_s, lambda: jax.device_get(x),
-                default=TIMED_OUT, name=f"jepsen-watchdog {site}")
+    obs = _M_SYNC.labels(site=site)
+    t0 = time.monotonic()
+    try:
+        if not deadline_s:
+            return jax.device_get(x)
+        from .util import TIMED_OUT, timeout
+        r = timeout(deadline_s, lambda: jax.device_get(x),
+                    default=TIMED_OUT, name=f"jepsen-watchdog {site}")
+    finally:
+        obs.observe(time.monotonic() - t0)
     if r is TIMED_OUT:
         raise WedgedDeviceSync(
             f"device sync at {site} still blocked after {deadline_s}s "

@@ -24,7 +24,7 @@ from __future__ import annotations
 
 import time as _time
 
-from .. import models as m
+from .. import models as m, telemetry as _telemetry
 from ..history import DeviceEncodingError, History, \
     history as as_history, is_fail, is_info, is_invoke
 from . import Checker, UNKNOWN
@@ -396,12 +396,13 @@ class Linearizable(Checker):
         if a is None:
             a = analysis_host(self.model, hist,
                               budget_s=self.opts.get("budget_s"))
-        a = _truncate(a)
-        try:
-            from .explain import write_failure_svg
-            write_failure_svg(test or {}, opts, a, hist)
-        except OSError:  # unwritable store is not a checking failure
-            pass
+        with _telemetry.section("wgl.assemble"):
+            a = _truncate(a)
+            try:
+                from .explain import write_failure_svg
+                write_failure_svg(test or {}, opts, a, hist)
+            except OSError:  # unwritable store is not a checking failure
+                pass
         return a
 
     def _streamed_result(self, test, hist) -> dict | None:
@@ -466,7 +467,9 @@ class Linearizable(Checker):
                 r["competition-winner"] = name
                 if r["valid?"] is False and not r.get("final-paths") \
                         and "op-index" in r:
-                    ex = explain_failure(self.model, hist, r["op-index"])
+                    with _telemetry.section("wgl.explain"):
+                        ex = explain_failure(self.model, hist,
+                                             r["op-index"])
                     if ex is not None:
                         r["configs"] = ex["configs"]
                         r["final-paths"] = ex["final-paths"]

@@ -669,7 +669,7 @@ class WglStream:
             self._drain_attest()
             host, hd = guarded_device_get(
                 (self._carry, self._k.digest(self._carry)),
-                site="stream checkpoint")
+                site="stream attested checkpoint")
             abft.verify_carry(self.fault_site, hd, host)
             self._att_carry += 1
         else:
@@ -960,7 +960,7 @@ class WglStream:
             sp.tags["chunk"] = str(self._chunks)
             sp.tags["rows"] = str(len(arr))
         try:
-            with _telemetry.profile_section("wgl.stream.chunk"):
+            with _telemetry.section("wgl.stream.chunk"):
                 maybe_inject_fault(self.fault_site)
                 buf = self._bufs[self._chunks % 2]
                 n = len(arr)

@@ -1702,10 +1702,19 @@ def _analysis_tpu_once(model, hist, frontier: int = 256,
 
     t0 = _time.monotonic()
     name = model.device_model
-    ops = encode_ops_for_model(model, hist)
-    p_exact = required_slots(ops)
-    if slots is None or p_exact > slots:
-        slots = _bucket(p_exact, lo=8)
+    section = _telemetry.section
+    with section("wgl.encode"):
+        ops = encode_ops_for_model(model, hist)
+        p_exact = required_slots(ops)
+        if slots is None or p_exact > slots:
+            slots = _bucket(p_exact, lo=8)
+        if slots <= 256:
+            srange = _state_range(name, model, [ops])
+            decision = select_engine(srange, p_exact, event_count(ops),
+                                     slots=slots, frontier=frontier,
+                                     engine=engine,
+                                     dense_slot_cap=dense_slot_cap,
+                                     pallas=pallas)
     if slots > 256:
         if not slot_overflow_fallback:
             # competition racing: a parallel host thread is already
@@ -1716,35 +1725,32 @@ def _analysis_tpu_once(model, hist, frontier: int = 256,
         a = analysis_host(model, hist, budget_s=budget_s, cancel=cancel)
         a["analyzer"] = "host-jit-linear (slot overflow)"
         return a
-    srange = _state_range(name, model, [ops])
-    decision = select_engine(srange, p_exact, event_count(ops),
-                             slots=slots, frontier=frontier,
-                             engine=engine,
-                             dense_slot_cap=dense_slot_cap,
-                             pallas=pallas)
     dense = decision.dense
     if dense is not None:
         slots = dense[2]   # exact-P: the dense table is 2^P wide
-    steps = build_steps(ops, slots)
-    # capacity covers the unmerged stream so the blame re-run below
-    # shares this compiled kernel
-    E = _bucket(max(event_count(ops), 1))
-    steps = steps.pad_to(E)
-    # ABFT staged-buffer attestation: ship (possibly bitflip-injected)
-    # data, then compare a device-side digest of the shipped buffer
-    # with the host digest of the canonical one — corruption on the
-    # staging/DMA path raises CorruptDeviceResult, which the recovery
-    # ladder absorbs by re-staging from the canonical host copy.
-    attest_on = attest_enabled()
-    x = jnp.asarray(maybe_corrupt("offline", steps.x))
-    att_info = None
-    if attest_on:
-        from . import abft
-        abft.verify_steps("offline", guarded_device_get(
-            abft.digest_device(x), site="offline attest"),
-            abft.digest_host(steps.x))
-        att_info = {"steps": 1, "carry": 0}
-    init_state = jnp.int32(model.device_state())
+    with section("wgl.steps"):
+        steps = build_steps(ops, slots)
+        # capacity covers the unmerged stream so the blame re-run below
+        # shares this compiled kernel
+        E = _bucket(max(event_count(ops), 1))
+        steps = steps.pad_to(E)
+    with section("wgl.stage"):
+        # ABFT staged-buffer attestation: ship (possibly
+        # bitflip-injected) data, then compare a device-side digest of
+        # the shipped buffer with the host digest of the canonical one
+        # — corruption on the staging/DMA path raises
+        # CorruptDeviceResult, which the recovery ladder absorbs by
+        # re-staging from the canonical host copy.
+        attest_on = attest_enabled()
+        x = jnp.asarray(maybe_corrupt("offline", steps.x))
+        att_info = None
+        if attest_on:
+            from . import abft
+            abft.verify_steps("offline", guarded_device_get(
+                abft.digest_device(x), site="offline steps attest"),
+                abft.digest_host(steps.x))
+            att_info = {"steps": 1, "carry": 0}
+        init_state = jnp.int32(model.device_state())
     F = frontier
     timed_out = cancelled = False
     while True:
@@ -1760,14 +1766,15 @@ def _analysis_tpu_once(model, hist, frontier: int = 256,
             # single fused call: init + full search + verdict
             maybe_inject_fault("offline")
             with chunk_obs.time(), \
-                    _telemetry.profile_section("wgl.offline.check"):
+                    section("wgl.offline.check"):
                 ok, death, overflow, max_count, att = \
                     guarded_device_get(
                         k.check(x, jnp.int32(steps.n), init_state),
                         site="offline check")
             _check_att(att, "offline")
         else:
-            carry = k.init_carry(init_state)
+            with section("wgl.stage"):
+                carry = k.init_carry(init_state)
             # Pipelined chunk loop: enqueue chunk i (dispatch is async),
             # THEN read chunk i-1's liveness flag — the device computes
             # chunk i while the host waits on the already-finished
@@ -1787,7 +1794,7 @@ def _analysis_tpu_once(model, hist, frontier: int = 256,
                 stop = min(e + chunk_entries, steps.n)
                 maybe_inject_fault("offline")
                 t_chunk = _time.monotonic()
-                with _telemetry.profile_section("wgl.offline.chunk"):
+                with section("wgl.offline.chunk"):
                     nxt = k.check_chunk(x, jnp.int32(stop), carry)
                     prev, carry = carry, nxt
                     e = stop
@@ -1819,25 +1826,29 @@ def _analysis_tpu_once(model, hist, frontier: int = 256,
                         # block on its flag before downgrading a
                         # definite death to 'unknown'
                         if int(guarded_device_get(
-                                carry[-2], site="offline liveness")) == 0:
+                                carry[-2],
+                                site="offline budget liveness")) == 0:
                             break
                         timed_out = True
                         cancelled = stop_req and not over
                         break
-            if attest_on:
-                # chunk-boundary carry attestation: fetch the carry
-                # with its device-computed digest, recompute on host,
-                # and check the structural invariants (att == 0) —
-                # silent corruption of the frontier in HBM or on the
-                # fetch path surfaces here instead of in the verdict
-                from . import abft
-                hc, hd = guarded_device_get(
-                    (carry, k.digest(carry)), site="offline attest")
-                abft.verify_carry("offline", hd, hc)
-                att_info["carry"] += 1
-            ok, death, overflow, max_count, att = guarded_device_get(
-                k.summarize(carry), site="offline summarize")
-            _check_att(att, "offline")
+            with section("wgl.summarize"):
+                if attest_on:
+                    # chunk-boundary carry attestation: fetch the carry
+                    # with its device-computed digest, recompute on
+                    # host, and check the structural invariants
+                    # (att == 0) — silent corruption of the frontier in
+                    # HBM or on the fetch path surfaces here instead of
+                    # in the verdict
+                    from . import abft
+                    hc, hd = guarded_device_get(
+                        (carry, k.digest(carry)),
+                        site="offline carry attest")
+                    abft.verify_carry("offline", hd, hc)
+                    att_info["carry"] += 1
+                ok, death, overflow, max_count, att = guarded_device_get(
+                    k.summarize(carry), site="offline summarize")
+                _check_att(att, "offline")
         ok = bool(ok) and not timed_out
         overflow = bool(overflow) or timed_out
         if ok or not overflow or F >= max_frontier or timed_out:
@@ -1885,19 +1896,21 @@ def _analysis_tpu_once(model, hist, frontier: int = 256,
             # the merged stream can't name a single culprit op: re-run
             # the unmerged stream (same T capacity -> same compiled
             # kernel); it dies at the same event, cheaply
-            row = _death_row(k, ops, slots, E, init_state)
-            if row >= 0:
-                src_index = int(ops.index[row])
-                out["op"] = _find_op(hist, src_index)
-                out["op-index"] = src_index
-                if explain:
-                    from .linear import explain_failure
+            with section("wgl.blame"):
+                row = _death_row(k, ops, slots, E, init_state)
+                if row >= 0:
+                    src_index = int(ops.index[row])
+                    out["op"] = _find_op(hist, src_index)
+                    out["op-index"] = src_index
+            if row >= 0 and explain:
+                from .linear import explain_failure
+                with section("wgl.explain"):
                     ex = explain_failure(model, hist, src_index)
-                    if ex is not None:
-                        out["configs"] = ex["configs"]
-                        out["final-paths"] = ex["final-paths"]
-                        if ex.get("previous-ok") is not None:
-                            out["previous-ok"] = ex["previous-ok"]
+                if ex is not None:
+                    out["configs"] = ex["configs"]
+                    out["final-paths"] = ex["final-paths"]
+                    if ex.get("previous-ok") is not None:
+                        out["previous-ok"] = ex["previous-ok"]
     return out
 
 
@@ -2164,146 +2177,163 @@ def _analysis_tpu_batch_once(model, hists: list, frontier: int = 1024,
         return max(0.0, budget_s - (_time.monotonic() - t0))
 
     name = model.device_model
-    pre = (_pre if _pre is not None
-           else [encode_ops_for_model(model, h) for h in hists])
-    _srange = _p_needs = None   # pre-pass reuse for the one-bucket case
-    if engine in ("auto", "dense") and len(hists) > 1 and _pre is None:
-        # Slot-bucketed dispatch groups (see _dispatch_groups): recurse
-        # per group — each group is then bucket-uniform and runs the
-        # plain batched path below. Dense groups run cheapest-first and
-        # the sort group last, so a pathological dense-ineligible key
-        # can only starve itself of budget, not the cheap keys.
-        p_req = [required_slots(ops) for ops in pre]
-        srange_all = _state_range(name, model, pre)
-        dense_groups, sort_idx = _dispatch_groups(
-            srange_all, p_req, engine,
-            n_events=max((event_count(o) for o in pre), default=1),
-            frontier=frontier, dense_slot_cap=dense_slot_cap,
-            pallas=pallas)
-        group_list = [dense_groups[pg] for pg in sorted(dense_groups)]
-        if sort_idx:
-            group_list.append((False, sort_idx))
-        if len(group_list) > 1:
-            grouped: list[dict | None] = [None] * len(hists)
-            for d, idx in group_list:
-                rem = _remaining()
-                if (rem == 0.0) or (cancel is not None and cancel()):
-                    # budget gone: report the remaining groups without
-                    # dispatching even one chunk for them
-                    for i in idx:
-                        grouped[i] = _unknown_result(
-                            pre[i], "batch budget exhausted/cancelled "
-                            "before this key's search started", t0)
-                    continue
-                sub = analysis_tpu_batch(
-                    model, [hists[i] for i in idx], frontier=frontier,
-                    slots=slots, chunk_entries=chunk_entries,
-                    budget_s=rem, cancel=cancel, engine=engine,
-                    max_frontier=max_frontier,
-                    dense_slot_cap=dense_slot_cap, pallas=pallas,
-                    _pre=[pre[i] for i in idx], _dense=d,
-                    _preq=[p_req[i] for i in idx])
-                for t, i in enumerate(idx):
-                    grouped[i] = sub[t]
-            return grouped
-        # one bucket: fall through to the plain path, reusing the
-        # pre-pass instead of rescanning every history
-        if group_list and group_list[0][0] is not False:
-            _dense = group_list[0][0]
-        else:
-            _srange, _p_needs = srange_all, dict(enumerate(p_req))
+    section = _telemetry.section
+    group_list = None
+    with section("wgl.encode"):
+        pre = (_pre if _pre is not None
+               else [encode_ops_for_model(model, h) for h in hists])
+        _srange = _p_needs = None   # pre-pass reuse, one-bucket case
+        if engine in ("auto", "dense") and len(hists) > 1 \
+                and _pre is None:
+            # Slot-bucketed dispatch groups (see _dispatch_groups):
+            # recurse per group — each group is then bucket-uniform and
+            # runs the plain batched path below. Dense groups run
+            # cheapest-first and the sort group last, so a pathological
+            # dense-ineligible key can only starve itself of budget,
+            # not the cheap keys.
+            p_req = [required_slots(ops) for ops in pre]
+            srange_all = _state_range(name, model, pre)
+            dense_groups, sort_idx = _dispatch_groups(
+                srange_all, p_req, engine,
+                n_events=max((event_count(o) for o in pre), default=1),
+                frontier=frontier, dense_slot_cap=dense_slot_cap,
+                pallas=pallas)
+            group_list = [dense_groups[pg]
+                          for pg in sorted(dense_groups)]
+            if sort_idx:
+                group_list.append((False, sort_idx))
+            if len(group_list) <= 1:
+                # one bucket: fall through to the plain path, reusing
+                # the pre-pass instead of rescanning every history
+                if group_list and group_list[0][0] is not False:
+                    _dense = group_list[0][0]
+                else:
+                    _srange, _p_needs = srange_all, dict(enumerate(p_req))
+                group_list = None
+        if group_list is None and pre:
+            if _dense is not False:
+                # the bucket's shape, shared group-wide; the group-local
+                # state range and slot needs would be dead recomputation
+                # (the dense kernel's shape carries both)
+                dense, srange, p_needs = _dense, None, None
+            else:
+                srange = (_srange if _srange is not None else
+                          _state_range(name, model, pre))
+                if _p_needs is not None:
+                    p_needs = _p_needs
+                elif _preq is not None:
+                    p_needs = dict(enumerate(_preq))
+                else:
+                    p_needs = {i: required_slots(o)
+                               for i, o in enumerate(pre)}
+                dense = None
+                if engine in ("auto", "dense"):
+                    # same contract as the scalar path and the multi-key
+                    # grouped split: a forced dense engine never
+                    # silently degrades to the sort kernel
+                    # (select_engine raises). Decided BEFORE the budget
+                    # early-exit below so the contract violation
+                    # surfaces identically for zero-budget calls.
+                    dense = select_engine(
+                        srange, max(p_needs.values()),
+                        max((event_count(o) for o in pre), default=1),
+                        frontier=frontier, engine=engine,
+                        dense_slot_cap=dense_slot_cap,
+                        pallas=pallas).dense
+            if dense is not None:
+                slots = dense[2]
+    if group_list is not None:
+        grouped: list[dict | None] = [None] * len(hists)
+        for d, idx in group_list:
+            rem = _remaining()
+            if (rem == 0.0) or (cancel is not None and cancel()):
+                # budget gone: report the remaining groups without
+                # dispatching even one chunk for them
+                for i in idx:
+                    grouped[i] = _unknown_result(
+                        pre[i], "batch budget exhausted/cancelled "
+                        "before this key's search started", t0)
+                continue
+            sub = analysis_tpu_batch(
+                model, [hists[i] for i in idx], frontier=frontier,
+                slots=slots, chunk_entries=chunk_entries,
+                budget_s=rem, cancel=cancel, engine=engine,
+                max_frontier=max_frontier,
+                dense_slot_cap=dense_slot_cap, pallas=pallas,
+                _pre=[pre[i] for i in idx], _dense=d,
+                _preq=[p_req[i] for i in idx])
+            for t, i in enumerate(idx):
+                grouped[i] = sub[t]
+        return grouped
 
     results: list[dict | None] = [None] * len(hists)
     encoded = list(enumerate(pre))
-    items = []           # (orig index, ops, steps)
-    if encoded:
-        if _dense is not False:
-            # the bucket's shape, shared group-wide; the group-local
-            # state range and slot needs would be dead recomputation
-            # (the dense kernel's shape carries both)
-            dense, srange, p_needs = _dense, None, None
-        else:
-            srange = (_srange if _srange is not None else
-                      _state_range(name, model, [o for _, o in encoded]))
-            if _p_needs is not None:
-                p_needs = _p_needs
-            elif _preq is not None:
-                p_needs = dict(enumerate(_preq))
-            else:
-                p_needs = {i: required_slots(o) for i, o in encoded}
-            dense = None
-            if engine in ("auto", "dense"):
-                # same contract as the scalar path and the multi-key
-                # grouped split: a forced dense engine never silently
-                # degrades to the sort kernel (select_engine raises).
-                # Decided BEFORE the budget early-exit below so the
-                # contract violation surfaces identically for
-                # zero-budget calls.
-                dense = select_engine(
-                    srange, max(p_needs.values()),
-                    max((event_count(o) for _, o in encoded),
-                        default=1),
-                    frontier=frontier, engine=engine,
-                    dense_slot_cap=dense_slot_cap,
-                    pallas=pallas).dense
-        if dense is not None:
-            slots = dense[2]
-        if ((_remaining() == 0.0) or (cancel is not None and cancel())):
-            # budget already gone: report unknown before the per-key
-            # scalar fallback below can dispatch full searches
-            for i, ops in encoded:
-                results[i] = _unknown_result(
-                    ops, "batch budget exhausted/cancelled before "
-                    "this key's search started", t0)
-            encoded = []
+    if encoded and ((_remaining() == 0.0)
+                    or (cancel is not None and cancel())):
+        # budget already gone: report unknown before the per-key
+        # scalar fallback below can dispatch full searches
         for i, ops in encoded:
-            if dense is None and p_needs[i] > slots:
-                # this key alone exceeds the batch's slot budget:
-                # scalar path re-sizes (and host-falls-back past 256)
-                results[i] = analysis_tpu(
-                    model, hists[i], frontier, budget_s=_remaining(),
-                    cancel=cancel, engine=engine,
-                    dense_slot_cap=dense_slot_cap, pallas=pallas)
-            else:
-                items.append((i, ops, build_steps(ops, slots)))
-    if items and ((_remaining() == 0.0)
-                  or (cancel is not None and cancel())):
-        # budget already gone: report unknown without dispatching even
-        # the first chunk (the chunk loop below always runs one)
-        for i, ops, _st in items:
             results[i] = _unknown_result(
                 ops, "batch budget exhausted/cancelled before "
                 "this key's search started", t0)
-        items = []
-    if items:
-        E = _bucket(max(max(event_count(ops) for _, ops, _ in items), 1))
-        padded = [st.pad_to(E) for _, _, st in items]
-        # bucket the batch axis like E: the vmapped kernels are jitted
-        # per (B, E) shape, so an exact B would recompile the whole
-        # family for every distinct key count — pad with zero-step
-        # entries (n=0: never consumed, frontier stays at the initial
-        # config), skipped by the per-item j < len(items) reads below
-        padded += [Steps.empty(padded[0].w, E)] * (
-            _bucket(len(padded), lo=1) - len(padded))
-        if dense is not None:
-            k = _dense_kernel(name, dense[0], dense[1], dense[2], E,
-                              pallas=pallas)
+        encoded = []
+    batched = []
+    for i, ops in encoded:
+        if dense is None and p_needs[i] > slots:
+            # this key alone exceeds the batch's slot budget: scalar
+            # path re-sizes (and host-falls-back past 256)
+            results[i] = analysis_tpu(
+                model, hists[i], frontier, budget_s=_remaining(),
+                cancel=cancel, engine=engine,
+                dense_slot_cap=dense_slot_cap, pallas=pallas)
         else:
-            k = _kernel(name, frontier, slots, E,
-                        _pack_params(srange, slots), pallas=pallas)
-        x_np = np.stack([st.x for st in padded])
-        attest_on = attest_enabled()
-        x = jnp.asarray(maybe_corrupt("batch", x_np))
-        if attest_on:
-            # staged-buffer attestation (see the offline twin): the
-            # whole vmapped stack ships as one buffer, one digest
-            from . import abft
-            abft.verify_steps("batch", guarded_device_get(
-                abft.digest_device(x), site="batch attest"),
-                abft.digest_host(x_np))
-        ns = np.asarray([st.n for st in padded], np.int32)
-        s0 = jnp.full(len(padded), model.device_state(), jnp.int32)
-        carry = jax.vmap(k.init_carry)(s0)
+            batched.append((i, ops))
+    if batched and ((_remaining() == 0.0)
+                    or (cancel is not None and cancel())):
+        # budget already gone: report unknown without dispatching even
+        # the first chunk (the chunk loop below always runs one)
+        for i, ops in batched:
+            results[i] = _unknown_result(
+                ops, "batch budget exhausted/cancelled before "
+                "this key's search started", t0)
+        batched = []
+    items = []           # (orig index, ops, steps)
+    if batched:
+        with section("wgl.steps"):
+            items = [(i, ops, build_steps(ops, slots))
+                     for i, ops in batched]
+            E = _bucket(max(max(event_count(ops) for _, ops, _ in items),
+                            1))
+            padded = [st.pad_to(E) for _, _, st in items]
+            # bucket the batch axis like E: the vmapped kernels are
+            # jitted per (B, E) shape, so an exact B would recompile
+            # the whole family for every distinct key count — pad with
+            # zero-step entries (n=0: never consumed, frontier stays at
+            # the initial config), skipped by the per-item
+            # j < len(items) reads below
+            padded += [Steps.empty(padded[0].w, E)] * (
+                _bucket(len(padded), lo=1) - len(padded))
+            x_np = np.stack([st.x for st in padded])
+            ns = np.asarray([st.n for st in padded], np.int32)
+    if items:
+        with section("wgl.stage"):
+            if dense is not None:
+                k = _dense_kernel(name, dense[0], dense[1], dense[2], E,
+                                  pallas=pallas)
+            else:
+                k = _kernel(name, frontier, slots, E,
+                            _pack_params(srange, slots), pallas=pallas)
+            attest_on = attest_enabled()
+            x = jnp.asarray(maybe_corrupt("batch", x_np))
+            if attest_on:
+                # staged-buffer attestation (see the offline twin): the
+                # whole vmapped stack ships as one buffer, one digest
+                from . import abft
+                abft.verify_steps("batch", guarded_device_get(
+                    abft.digest_device(x), site="batch steps attest"),
+                    abft.digest_host(x_np))
+            s0 = jnp.full(len(padded), model.device_state(), jnp.int32)
+            carry = jax.vmap(k.init_carry)(s0)
         e = 0
         n_max = int(ns.max())
         # pipelined like the scalar loop: enqueue the next vmapped
@@ -2318,7 +2348,7 @@ def _analysis_tpu_batch_once(model, hists: list, frontier: int = 1024,
             stop = min(e + chunk_entries, n_max)
             maybe_inject_fault("batch")
             t_chunk = _time.monotonic()
-            with _telemetry.profile_section("wgl.batch.chunk"):
+            with section("wgl.batch.chunk"):
                 nxt = k.check_chunk_batch(
                     x, jnp.asarray(np.minimum(ns, stop)), carry)
                 prev, carry = carry, nxt
@@ -2337,78 +2367,87 @@ def _analysis_tpu_batch_once(model, hists: list, frontier: int = 1024,
                         and _time.monotonic() - t0 > budget_s) \
                         or (cancel is not None and cancel()):
                     break
-        if attest_on:
-            # per-key carry attestation at the batch's final boundary
-            from . import abft
-            hc, hd = guarded_device_get(
-                (carry, jax.vmap(k.digest)(carry)), site="batch attest")
-            for bi in range(len(np.asarray(hd))):
-                abft.verify_carry(
-                    "batch", np.asarray(hd)[bi],
-                    tuple(np.asarray(a)[bi] for a in hc))
-        # ONE guarded fetch for the verdicts AND the carry components
-        # the decided-mask below needs: the consumed/count buffers were
-        # previously pulled via raw np.asarray — an unguarded implicit
-        # sync (JTS103) and a second device round-trip
-        (ok, death, overflow, max_count, att), consumed, counts = \
-            guarded_device_get(
-                (jax.vmap(k.summarize)(carry), carry[0], carry[-2]),
-                site="batch summarize")
-        _check_att(np.asarray(att).sum(), "batch")
-        _M_OPS.labels(site="batch").inc(
-            sum(len(o) for _, o, _ in items))
-        batch_dedup = (DEDUP_NONE if dense is not None else
-                       dedup_engine(frontier, slots,
-                                    _pack_params(srange, slots),
-                                    pallas))
-        # a key is decided if it consumed all entries or its frontier
-        # died (death is definitive no matter how many entries remain)
-        decided = (np.asarray(consumed) >= ns) | (counts == 0)
-        suspects = []    # overflow + invalid: escalate together
-        invalids = []    # definite invalid: blame together
-        for j, (i, ops, st) in enumerate(items):
-            if not bool(decided[j]):
-                results[i] = _unknown_result(
-                    ops, "batch budget exhausted/cancelled before "
-                    "this key's search finished", t0)
-            elif bool(ok[j]):
-                results[i] = {
-                    "valid?": True, "analyzer": "tpu-wgl-batch",
-                    "dedup": batch_dedup,
-                    "op-count": len(ops),
-                    "max-frontier": int(max_count[j]),
-                    "configs": [], "final-paths": []}
-            elif bool(overflow[j]):
-                suspects.append((i, ops))
-            else:
-                invalids.append((j, i, ops))
+        with section("wgl.summarize"):
+            if attest_on:
+                # per-key carry attestation at the batch's final
+                # boundary
+                from . import abft
+                hc, hd = guarded_device_get(
+                    (carry, jax.vmap(k.digest)(carry)),
+                    site="batch carry attest")
+                for bi in range(len(np.asarray(hd))):
+                    abft.verify_carry(
+                        "batch", np.asarray(hd)[bi],
+                        tuple(np.asarray(a)[bi] for a in hc))
+            # ONE guarded fetch for the verdicts AND the carry
+            # components the decided-mask below needs: the
+            # consumed/count buffers were previously pulled via raw
+            # np.asarray — an unguarded implicit sync (JTS103) and a
+            # second device round-trip
+            (ok, death, overflow, max_count, att), consumed, counts = \
+                guarded_device_get(
+                    (jax.vmap(k.summarize)(carry), carry[0], carry[-2]),
+                    site="batch summarize")
+            _check_att(np.asarray(att).sum(), "batch")
+            _M_OPS.labels(site="batch").inc(
+                sum(len(o) for _, o, _ in items))
+            batch_dedup = (DEDUP_NONE if dense is not None else
+                           dedup_engine(frontier, slots,
+                                        _pack_params(srange, slots),
+                                        pallas))
+            # a key is decided if it consumed all entries or its
+            # frontier died (death is definitive no matter how many
+            # entries remain)
+            decided = (np.asarray(consumed) >= ns) | (counts == 0)
+            suspects = []    # overflow + invalid: escalate together
+            invalids = []    # definite invalid: blame together
+            for j, (i, ops, st) in enumerate(items):
+                if not bool(decided[j]):
+                    results[i] = _unknown_result(
+                        ops, "batch budget exhausted/cancelled before "
+                        "this key's search finished", t0)
+                elif bool(ok[j]):
+                    results[i] = {
+                        "valid?": True, "analyzer": "tpu-wgl-batch",
+                        "dedup": batch_dedup,
+                        "op-count": len(ops),
+                        "max-frontier": int(max_count[j]),
+                        "configs": [], "final-paths": []}
+                elif bool(overflow[j]):
+                    suspects.append((i, ops))
+                else:
+                    invalids.append((j, i, ops))
         if invalids:
-            # one vmapped unmerged pass names every culprit op (the
-            # unmerged streams fit E by construction)
-            st2s = [build_steps(ops, slots, merge=False).pad_to(E)
-                    for _, _, ops in invalids]
-            st2s += [Steps.empty(st2s[0].w, E)] * (
-                _bucket(len(st2s), lo=1) - len(st2s))
-            okb, deathb, *_ = guarded_device_get(k.check_batch(
-                jnp.asarray(np.stack([s.x for s in st2s])),
-                jnp.asarray(np.asarray([s.n for s in st2s], np.int32)),
-                jnp.full(len(st2s), model.device_state(), jnp.int32)))
-            for t, (j, i, ops) in enumerate(invalids):
-                r = {"valid?": False, "analyzer": "tpu-wgl-batch",
-                     "dedup": batch_dedup,
-                     "op-count": len(ops),
-                     "max-frontier": int(max_count[j]),
-                     "configs": [], "final-paths": []}
-                d = int(deathb[t])
-                if not bool(okb[t]) and d >= 0:
-                    row = int(st2s[t].inv_row[d])
-                    if row < 0:
-                        row = int(st2s[t].ret_row[d])
-                    if row >= 0:
-                        src = int(ops.index[row])
-                        r["op"] = _find_op(hists[i], src)
-                        r["op-index"] = src
-                results[i] = r
+            with section("wgl.blame"):
+                # one vmapped unmerged pass names every culprit op (the
+                # unmerged streams fit E by construction)
+                st2s = [build_steps(ops, slots, merge=False).pad_to(E)
+                        for _, _, ops in invalids]
+                st2s += [Steps.empty(st2s[0].w, E)] * (
+                    _bucket(len(st2s), lo=1) - len(st2s))
+                okb, deathb, *_ = guarded_device_get(k.check_batch(
+                    jnp.asarray(np.stack([s.x for s in st2s])),
+                    jnp.asarray(np.asarray([s.n for s in st2s],
+                                           np.int32)),
+                    jnp.full(len(st2s), model.device_state(),
+                             jnp.int32)),
+                    site="batch blame")
+                for t, (j, i, ops) in enumerate(invalids):
+                    r = {"valid?": False, "analyzer": "tpu-wgl-batch",
+                         "dedup": batch_dedup,
+                         "op-count": len(ops),
+                         "max-frontier": int(max_count[j]),
+                         "configs": [], "final-paths": []}
+                    d = int(deathb[t])
+                    if not bool(okb[t]) and d >= 0:
+                        row = int(st2s[t].inv_row[d])
+                        if row < 0:
+                            row = int(st2s[t].ret_row[d])
+                        if row >= 0:
+                            src = int(ops.index[row])
+                            r["op"] = _find_op(hists[i], src)
+                            r["op-index"] = src
+                    results[i] = r
         if suspects:
             if frontier < max_frontier:
                 sub = analysis_tpu_batch(

@@ -320,6 +320,34 @@ def _enable_compile_cache() -> None:
              use_compilation_cache())
 
 
+PROFILE_ENV = "JEPSEN_TPU_PROFILE"
+_profile_dir: str | None = None     # the trace this process started
+
+
+def _start_profiler() -> None:
+    """``JEPSEN_TPU_PROFILE=<dir>``: one ``jax.profiler`` trace of the
+    whole command into <dir>, started once, next to the compile cache,
+    and stopped at exit. The checking path's sections
+    (``telemetry.section``) name their stretches in it; nothing on that
+    path reads the variable or starts a trace."""
+    global _profile_dir
+    d = os.environ.get(PROFILE_ENV)
+    if not d or _profile_dir is not None:
+        return
+    import atexit
+
+    import jax
+    try:
+        jax.profiler.start_trace(d)
+    except RuntimeError as e:   # a trace is already running here
+        log.warning("%s=%s: no profiler trace started: %s",
+                    PROFILE_ENV, d, e)
+        return
+    atexit.register(jax.profiler.stop_trace)
+    _profile_dir = d
+    log.info("JAX profiler trace: %s", d)
+
+
 def single_test_cmd(opts: dict) -> dict:
     """Builds the `test` and `analyze` commands around a test_fn
     (`cli.clj:355-430`). Options: opt_spec (extra spec entries),
@@ -337,6 +365,7 @@ def single_test_cmd(opts: dict) -> dict:
     def run_test(options):
         log.info("Test options:\n%s", _pprint.pformat(options))
         _enable_compile_cache()
+        _start_profiler()
         # test_count fallback: an opt_fn_ override replaces the pipeline
         # that remaps argparse's test_count to test-count
         for _ in range(options.get("test-count",
@@ -351,6 +380,7 @@ def single_test_cmd(opts: dict) -> dict:
         from . import store
         log.info("Test options:\n%s", _pprint.pformat(options))
         _enable_compile_cache()
+        _start_profiler()
         cli_test = test_fn(options)
         latest = store.latest(cli_test.get("store-dir", "store"))
         if latest is None:
@@ -583,6 +613,7 @@ def service_cmd() -> dict:
         # fleet prices work in measured device-seconds from its
         # first chunk (jepsen_tpu/calibrate.py)
         _enable_compile_cache()
+        _start_profiler()
         cal = _calibrate.Calibration.load()
         if cal.coefficients():
             log.info("calibration loaded: %s", cal.coefficients())

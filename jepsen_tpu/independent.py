@@ -20,7 +20,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Callable, Iterable
 
-from . import generator as gen
+from . import generator as gen, telemetry as _telemetry
 from .checker import Checker, check_safe, coerce, merge_valid
 from .generator import Gen, PENDING
 from .history import History, history as as_history
@@ -328,23 +328,23 @@ class IndependentChecker(Checker):
         self.subchecker = coerce(subchecker)
         self.strict_device = strict_device
 
-    def _batched_tpu(self, test, hist, opts, ks):
-        """Batched per-key device check; None if not applicable, the
-        exception if the device batch failed (non-strict)."""
+    def _batchable(self) -> bool:
+        """Does the subchecker take the batched device path?"""
         from .checker.linear import Linearizable
         c = self.subchecker
-        if not isinstance(c, Linearizable):
-            return None
-        if c.model is None or c.model.device_model is None:
-            return None
-        if c.algorithm not in ("auto", "tpu", "linear", "wgl",
-                               "competition", "tpu-wgl"):
-            return None
+        return (isinstance(c, Linearizable) and c.model is not None
+                and c.model.device_model is not None
+                and c.algorithm in ("auto", "tpu", "linear", "wgl",
+                                    "competition", "tpu-wgl"))
+
+    def _batched_tpu(self, subs: dict):
+        """Batched per-key device check of the subhistories; the
+        exception if the device batch failed (non-strict)."""
         from .checker.wgl import analysis_tpu_batch
-        subs = [subhistory(k, hist) for k in ks]
+        c = self.subchecker
         try:
-            return dict(zip(ks, analysis_tpu_batch(c.model, subs,
-                                                   **c.opts)))
+            return dict(zip(subs, analysis_tpu_batch(
+                c.model, list(subs.values()), **c.opts)))
         except Exception as e:
             if self.strict_device:
                 raise
@@ -356,9 +356,10 @@ class IndependentChecker(Checker):
             return e
 
     def check(self, test, hist, opts):
-        hist = as_history(hist).index()
-        ks = history_keys(hist)
-        results = self._batched_tpu(test, hist, opts, ks)
+        with _telemetry.section("wgl.split"):
+            hist = as_history(hist).index()
+            subs = {k: subhistory(k, hist) for k in history_keys(hist)}
+        results = self._batched_tpu(subs) if self._batchable() else None
         fallback = None
         if isinstance(results, Exception):
             fallback = {"error": f"{type(results).__name__}: {results}"}
@@ -367,19 +368,20 @@ class IndependentChecker(Checker):
             def one(k):
                 sub_opts = dict(opts)
                 sub_opts["history-key"] = k
-                return k, check_safe(self.subchecker, test,
-                                     subhistory(k, hist), sub_opts)
-            results = dict(bounded_pmap(one, ks, max_workers=8))
-        valids = {k: (r or {}).get("valid?", True)
-                  for k, r in results.items()}
-        failures = [k for k, v in valids.items() if v is False]
-        out = {
-            "valid?": merge_valid(valids.values()) if valids else True,
-            "results": results,
-            "failures": failures,
-        }
-        if fallback is not None:
-            out["device-fallback"] = fallback
+                return k, check_safe(self.subchecker, test, subs[k],
+                                     sub_opts)
+            results = dict(bounded_pmap(one, list(subs), max_workers=8))
+        with _telemetry.section("wgl.assemble"):
+            valids = {k: (r or {}).get("valid?", True)
+                      for k, r in results.items()}
+            failures = [k for k, v in valids.items() if v is False]
+            out = {
+                "valid?": merge_valid(valids.values()) if valids else True,
+                "results": results,
+                "failures": failures,
+            }
+            if fallback is not None:
+                out["device-fallback"] = fallback
         return out
 
 

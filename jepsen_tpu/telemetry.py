@@ -24,12 +24,11 @@ visible only as log lines. This module is the observability substrate:
     ``jepsen_tpu_<layer>_<name>_<unit>`` with layer
     in :data:`LAYERS` and unit in :data:`UNITS`; counters end in
     ``_total``.
-  * **Profiler hooks.** ``JEPSEN_TPU_PROFILE=<dir>`` makes
-    :func:`profile_section` start one ``jax.profiler`` trace into
-    that directory (stopped atexit) and wrap each device section in a
-    ``TraceAnnotation`` so chunk dispatches are named in the TPU
-    profile. Without the env var every call is a no-op (pinned by
-    tests/test_telemetry.py).
+  * **Sections.** :func:`section` names a stretch of the checking
+    path in any active ``jax.profiler`` trace (a ``TraceAnnotation``,
+    imported lazily) and, for the checker's phases, times it into
+    ``jepsen_tpu_wgl_phase_seconds``. It never starts a trace: the CLI
+    starts one under ``JEPSEN_TPU_PROFILE=<dir>``.
 
 Instrumentation sites live with the code they observe (wgl dispatch,
 streaming chunks/checkpoints, screens, attestation, the service);
@@ -55,7 +54,6 @@ UNITS = ("total", "seconds", "rows", "ops", "chunks", "elementops",
          "bytes", "ratio", "streams", "info", "bits", "genomes")
 
 METRICS_ENV = "JEPSEN_TPU_METRICS"
-PROFILE_ENV = "JEPSEN_TPU_PROFILE"
 
 # latency buckets (seconds): device chunks span ~100us (warm CPU sort
 # chunk) to minutes (a cold compile of a large kernel)
@@ -496,69 +494,52 @@ def serve_metrics(port: int, host: str = "127.0.0.1",
 
 
 # ---------------------------------------------------------------------------
-# JAX profiler hooks (JEPSEN_TPU_PROFILE=<dir>)
+# Sections: named spans of the checking path
 # ---------------------------------------------------------------------------
 
-_profiler_lock = threading.Lock()
-_profiler_started = False       # guarded-by: _profiler_lock
+# The checker's phases. Each is one section() around a stretch of a
+# check, never one per key or op, and no phase nests in another or in a
+# chunk site, so a check's phase seconds and its
+# jepsen_tpu_wgl_chunk_seconds add up to its host time.
+PHASES = frozenset(("wgl.split", "wgl.encode", "wgl.steps", "wgl.stage",
+                    "wgl.summarize", "wgl.blame", "wgl.explain",
+                    "wgl.assemble"))
+
+# host waits run from microseconds (a ready flag) to seconds (a verdict
+# behind a long chunk); the default buckets start at 0.5 ms
+FINE_BUCKETS = (0.00001, 0.00005, 0.0001, 0.00025) + DEFAULT_BUCKETS
+
+_M_PHASE = histogram(
+    "jepsen_tpu_wgl_phase_seconds",
+    "Host seconds in each checker phase (split, encode, steps, stage, "
+    "summarize, blame, explain, assemble)",
+    ("phase",), buckets=FINE_BUCKETS)
 
 
-def profile_dir() -> str | None:
-    return os.environ.get(PROFILE_ENV) or None
-
-
-def _ensure_profiler() -> bool:
-    """Start the one process-wide jax.profiler trace on first use
-    (stopped atexit). False when the env var is unset or the profiler
-    is unavailable — callers then skip annotations too."""
-    global _profiler_started
-    d = profile_dir()
-    if not d:
-        return False
-    if _profiler_started:  # noqa: JTS201 — double-checked fast path
-        return True
-    with _profiler_lock:
-        if _profiler_started:
-            return True
-        try:
-            import atexit
-
-            import jax
-            jax.profiler.start_trace(d)
-            atexit.register(stop_profiler)
-            _profiler_started = True
-        except Exception:  # noqa: BLE001 — profiling is best-effort
-            return False
-    return True
-
-
-def stop_profiler() -> None:
-    global _profiler_started
-    with _profiler_lock:
-        if not _profiler_started:
-            return
-        try:
-            import jax
-            jax.profiler.stop_trace()
-        except Exception:  # noqa: BLE001 — already stopped / torn down
-            pass
-        _profiler_started = False
+def _annotation(name: str):
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:   # a host-only install has no profiler to name it in
+        return contextlib.nullcontext()
+    return TraceAnnotation(name)
 
 
 @contextlib.contextmanager
-def profile_section(name: str):
-    """Wrap a device section in a named ``jax.profiler``
-    TraceAnnotation when JEPSEN_TPU_PROFILE is set; a strict no-op
-    otherwise (no jax import, no profiler start — pinned by
-    tests/test_telemetry.py)."""
-    if not _ensure_profiler():
-        yield
-        return
-    try:
-        import jax
-        ann = jax.profiler.TraceAnnotation(name)
-    except Exception:  # noqa: BLE001 — profiling is best-effort
-        yield
-        return
-    with ann:
-        yield
+def section(name: str):
+    """A named section of the checking path: a ``jax.profiler``
+    ``TraceAnnotation``, so the section shows on the host plane of any
+    active trace (the benchmark's, or the one ``JEPSEN_TPU_PROFILE``
+    makes the CLI start) on the device's clock; with no trace active
+    that costs one check. A phase (a name in :data:`PHASES`) also
+    observes its host seconds into ``jepsen_tpu_wgl_phase_seconds``;
+    the chunk sites (``wgl.offline.chunk``, ...) are timed by
+    ``jepsen_tpu_wgl_chunk_seconds`` alone. Reads no environment and
+    never starts or stops a trace."""
+    series = _M_PHASE.labels(phase=name) if name in PHASES else None
+    t0 = time.monotonic()
+    with _annotation(name):
+        try:
+            yield
+        finally:
+            if series is not None:
+                series.observe(time.monotonic() - t0)

@@ -2,8 +2,8 @@
 exporter rework): registry semantics, Prometheus exposition, the
 /metrics + /healthz HTTP endpoints against a live verification
 service, chunk-level span threading (one trace id run -> stream ->
-chunk), the async trace flusher, and the profiler hooks' no-op
-contract."""
+chunk), the async trace flusher, the checker's phase sections and
+sync timer, and the CLI-started profiler."""
 
 from __future__ import annotations
 
@@ -378,30 +378,190 @@ def test_file_exporter_still_synchronous(tmp_path):
     del trace_id
 
 
-# -- profiler hooks ----------------------------------------------------------
+# -- sections, phases and the profiler ---------------------------------------
 
-def test_profile_section_is_noop_without_env(monkeypatch):
-    monkeypatch.delenv(telemetry.PROFILE_ENV, raising=False)
-    assert telemetry.profile_dir() is None
-    with telemetry.profile_section("wgl.test.chunk"):
+class _Annotations:
+    """Stands in for jax.profiler.TraceAnnotation: records each
+    section's name as it is entered and left."""
+
+    def __init__(self):
+        self.events = []
+
+    def __call__(self, name):
+        rec = self
+
+        class _Ann:
+            def __enter__(self):
+                rec.events.append(("enter", name))
+
+            def __exit__(self, *exc):
+                rec.events.append(("exit", name))
+
+        return _Ann()
+
+
+def _phases():
+    return {k.split("=", 1)[1]: v["count"] for k, v in telemetry.snapshot()
+            .get("jepsen_tpu_wgl_phase_seconds", {}).items()}
+
+
+def _syncs():
+    return {k.split("=", 1)[1]: v["count"] for k, v in telemetry.snapshot()
+            .get("jepsen_tpu_wgl_sync_seconds", {}).items()}
+
+
+def test_section_observes_phase_and_opens_annotation(monkeypatch):
+    import jax.profiler
+    ann = _Annotations()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", ann)
+    with telemetry.section("wgl.encode"):
+        assert ann.events == [("enter", "wgl.encode")]
+    with telemetry.section("wgl.offline.chunk"):
         pass
-    assert telemetry._profiler_started is False
+    assert ann.events == [("enter", "wgl.encode"), ("exit", "wgl.encode"),
+                          ("enter", "wgl.offline.chunk"),
+                          ("exit", "wgl.offline.chunk")]
+    # a phase is timed; a chunk site is left to the chunk histogram
+    assert _phases() == {"wgl.encode": 1}
+    snap = telemetry.snapshot()["jepsen_tpu_wgl_phase_seconds"]
+    assert snap["phase=wgl.encode"]["sum"] >= 0.0
+    with pytest.raises(RuntimeError):
+        with telemetry.section("wgl.blame"):
+            raise RuntimeError("the block's error passes through")
+    assert _phases() == {"wgl.encode": 1, "wgl.blame": 1}
+    assert ann.events[-1] == ("exit", "wgl.blame")
 
 
-def test_profile_section_starts_trace_with_env(tmp_path, monkeypatch):
-    monkeypatch.setenv(telemetry.PROFILE_ENV, str(tmp_path))
-    try:
-        with telemetry.profile_section("wgl.test.chunk"):
+def test_section_reads_no_env_and_starts_no_trace(tmp_path, monkeypatch):
+    import os
+
+    import jax.profiler
+    from jepsen_tpu import models
+    from jepsen_tpu.checker.linear import linearizable
+
+    started = []
+    monkeypatch.setattr(jax.profiler, "start_trace",
+                        lambda *a, **kw: started.append(a))
+    monkeypatch.setenv("JEPSEN_TPU_PROFILE", str(tmp_path))
+
+    class _Env(dict):
+        reads = []
+
+        def __getitem__(self, k):
+            self.reads.append(k)
+            return super().__getitem__(k)
+
+        def get(self, k, default=None):
+            self.reads.append(k)
+            return super().get(k, default)
+
+    env = _Env(os.environ)
+    monkeypatch.setattr(os, "environ", env)
+    for name in ("wgl.encode", "wgl.batch.chunk"):
+        with telemetry.section(name):
             pass
-        started = telemetry._profiler_started
-    finally:
-        telemetry.stop_profiler()
-    assert telemetry._profiler_started is False
-    # best-effort: when jax's profiler is available the trace started
-    # and stop_trace wrote the artifact dir; otherwise the no-op path
-    # ran (still a pass — profiling must never be load-bearing)
-    if started:
-        assert any(tmp_path.iterdir())
+    assert env.reads == []
+    monkeypatch.undo()
+    monkeypatch.setattr(jax.profiler, "start_trace",
+                        lambda *a, **kw: started.append(a))
+    monkeypatch.setenv("JEPSEN_TPU_PROFILE", str(tmp_path))
+    a = linearizable(models.cas_register(), chunk_entries=64).check(
+        {}, _hist(5, n=300), {})
+    assert a["valid?"] is True
+    assert started == []            # only the CLI starts a trace
+
+
+def test_cli_starts_and_stops_the_profiler_once(tmp_path, monkeypatch):
+    import atexit
+
+    import jax.profiler
+    from jepsen_tpu import cli
+
+    calls, at_exit = [], []
+    monkeypatch.setattr(jax.profiler, "start_trace",
+                        lambda d, **kw: calls.append(("start", d)))
+    monkeypatch.setattr(jax.profiler, "stop_trace",
+                        lambda: calls.append(("stop",)))
+    monkeypatch.setattr(atexit, "register", at_exit.append)
+    monkeypatch.setattr(cli, "_profile_dir", None)
+    monkeypatch.delenv(cli.PROFILE_ENV, raising=False)
+    cli._start_profiler()
+    assert calls == [] and at_exit == []
+    monkeypatch.setenv(cli.PROFILE_ENV, str(tmp_path))
+    cli._start_profiler()
+    cli._start_profiler()
+    assert calls == [("start", str(tmp_path))]
+    assert len(at_exit) == 1
+    at_exit[0]()
+    assert calls == [("start", str(tmp_path)), ("stop",)]
+
+
+def test_invalid_history_fills_phases_and_named_syncs():
+    from jepsen_tpu import models
+    from jepsen_tpu.checker.linear import linearizable
+
+    a = linearizable(models.cas_register(), chunk_entries=64).check(
+        {}, _hist(13, n=300, corrupt=True), {})
+    assert a["valid?"] is False and "op-index" in a
+    phases = _phases()
+    for p in ("wgl.encode", "wgl.steps", "wgl.stage", "wgl.summarize",
+              "wgl.blame", "wgl.explain", "wgl.assemble"):
+        assert phases.get(p, 0) >= 1, (p, phases)
+    assert "wgl.split" not in phases
+    syncs = _syncs()
+    for site in ("offline steps attest", "offline liveness",
+                 "offline carry attest", "offline summarize",
+                 "offline blame"):
+        assert syncs.get(site, 0) >= 1, (site, syncs)
+    assert "device-sync" not in syncs   # every checker site is named
+
+
+def test_keyed_check_splits_once_per_check_not_per_key():
+    from jepsen_tpu import independent, models
+    from jepsen_tpu.checker.linear import linearizable
+    from jepsen_tpu.history import History
+    from jepsen_tpu.independent import KV
+
+    ops = []
+    for k in range(5):
+        sub = _hist(20 + k, n=60, corrupt=k == 3)
+        ops += [{**o, "value": KV(k, o["value"]),
+                 "process": o["process"] + 10 * k} for o in sub.ops]
+    c = independent.checker(linearizable(models.cas_register()))
+    for n in (1, 2):
+        r = c.check({}, History(ops), {})
+        assert r["valid?"] is False and r["failures"] == [3]
+        phases = _phases()
+        assert phases["wgl.split"] == n
+        assert phases["wgl.assemble"] == n
+        assert phases["wgl.blame"] >= n
+    syncs = _syncs()
+    assert syncs.get("batch blame", 0) >= 2
+    assert "device-sync" not in syncs
+
+
+def test_every_device_sync_names_its_own_fixed_site():
+    """jepsen_tpu_wgl_sync_seconds is labelled by site: each
+    guarded_device_get call in the package passes a string literal of
+    its own, so the label set stays bounded and each series is one
+    place in the code."""
+    import ast
+    from pathlib import Path
+
+    pkg = Path(telemetry.__file__).resolve().parent
+    sites = []
+    for path in sorted(pkg.rglob("*.py")):
+        for node in ast.walk(ast.parse(path.read_text())):
+            func = getattr(node, "func", None)
+            if getattr(func, "id", getattr(func, "attr", None)) \
+                    != "guarded_device_get":
+                continue
+            site = {k.arg: k.value for k in node.keywords}.get("site")
+            assert isinstance(site, ast.Constant) \
+                and isinstance(site.value, str), (path, node.lineno)
+            sites.append(site.value)
+    assert len(sites) >= 20
+    assert len(set(sites)) == len(sites), sorted(sites)
 
 
 # -- /metrics + /healthz e2e against a live service -------------------------

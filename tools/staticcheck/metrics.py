@@ -27,6 +27,7 @@ HISTOGRAM_UNITS = ("seconds", "rows", "bytes", "ops", "elementops")
 # the instrumented modules — importing them registers their metrics
 MODULES = (
     "jepsen_tpu.telemetry",
+    "jepsen_tpu._platform",
     "jepsen_tpu.trace",
     "jepsen_tpu.checker.wgl",
     "jepsen_tpu.checker.streaming",
