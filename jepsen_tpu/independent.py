@@ -307,6 +307,32 @@ def subhistory(k, hist) -> History:
     return History(out)
 
 
+def subhistories(hist) -> dict:
+    """`{k: subhistory(k, hist) for k in history_keys(hist)}` in one pass
+    over the history instead of one pass per key.
+
+    Shared (nemesis) ops go into every bucket that exists when they are
+    seen, and a new key's bucket starts with the shared ops before it, so
+    each subhistory keeps journal order and shares the nemesis op objects,
+    as the per-key scan does."""
+    buckets: dict = {}
+    shared: list = []
+    for o in as_history(hist):
+        v = o.get("value")
+        if isinstance(v, KV):
+            ops = buckets.get(v.key)
+            if ops is None:
+                ops = buckets[v.key] = list(shared)
+            o = dict(o)
+            o["value"] = v.value
+            ops.append(o)
+        elif not isinstance(o.get("process"), int):
+            shared.append(o)
+            for ops in buckets.values():
+                ops.append(o)
+    return {k: History(ops) for k, ops in buckets.items()}
+
+
 # ---------------------------------------------------------------------------
 # Checker
 # ---------------------------------------------------------------------------
@@ -358,7 +384,7 @@ class IndependentChecker(Checker):
     def check(self, test, hist, opts):
         with _telemetry.section("wgl.split"):
             hist = as_history(hist).index()
-            subs = {k: subhistory(k, hist) for k in history_keys(hist)}
+            subs = subhistories(hist)
         results = self._batched_tpu(subs) if self._batchable() else None
         fallback = None
         if isinstance(results, Exception):
