@@ -65,8 +65,9 @@ from .._platform import (FAULT_COMPILE, FAULT_DEVICE_LOST,
                          classify_backend_error, guarded_device_get,
                          maybe_corrupt, maybe_inject_fault)
 from ..history import (DeviceEncodingError, F_CAS, F_READ, F_WRITE,
-                       KIND_OK, NIL, OpArray, default_register_codec,
-                       encode_ops, history as as_history)
+                       KIND_INFO, KIND_OK, NIL, PENDING_RET, OpArray,
+                       default_register_codec, encode_ops,
+                       history as as_history)
 
 log = logging.getLogger(__name__)
 
@@ -98,10 +99,38 @@ _M_OPS = _telemetry.counter(
     "jepsen_tpu_wgl_checked_ops_total",
     "History ops decided by device-checking entries",
     ("site",))
+# What a batch's vmapped dispatch ran against what its keys needed: every
+# lane (padding included) is staged with E rows and runs every step the
+# chunk loop advances, a real key needs only its own steps. 1 - useful /
+# dispatched is the stepping's padding, 1 - useful / staged the staging's.
+_M_LANE_STEPS = _telemetry.counter(
+    "jepsen_tpu_wgl_batch_lane_steps_total",
+    "Lane-steps of batch dispatches: staged (lanes x E rows), dispatched "
+    "(lanes x steps advanced) and useful (each real key's steps, up to "
+    "those advanced)",
+    ("family", "kind"))
+_M_GROUPS = _telemetry.counter(
+    "jepsen_tpu_wgl_batch_groups_total",
+    "Batch dispatch groups run (one vmapped kernel shape each)",
+    ("family",))
+_M_RECHECKED = _telemetry.counter(
+    "jepsen_tpu_wgl_batch_rechecked_keys_total",
+    "Keys a batch checked again, by prefix or with later deadlines, "
+    "after a crash deadline may have cost their verdict (see "
+    "release_crashed)")
 
 # Event kinds (host-side stream construction)
 E_INVOKE = 0
 E_RETURN = 1
+E_RELEASE = 2
+
+# A crashed op given a deadline by release_crashed: it may take effect
+# only before its ret, where its slot is released (OpArray.kind)
+KIND_RELEASED = 2
+# f of a step that releases the slots in its mask instead of completing
+# them: configurations that took those ops and those that did not both
+# live on (dense kernel only)
+F_RELEASE = -2
 
 
 class SlotOverflow(Exception):
@@ -415,29 +444,144 @@ class Steps:
 
 
 def event_count(ops: OpArray) -> int:
-    """Length of the unmerged event stream (invokes + ok returns) —
-    the T capacity that lets merged and unmerged streams share one
-    compiled kernel."""
-    return len(ops) + int((np.asarray(ops.kind) == KIND_OK).sum())
+    """Length of the unmerged event stream (invokes + ok returns +
+    releases) — the T capacity that lets merged and unmerged streams
+    share one compiled kernel."""
+    return len(ops) + int((np.asarray(ops.kind) != KIND_INFO).sum())
 
 
 def required_slots(ops: OpArray) -> int:
     """The peak number of simultaneously-pending ops (crashed ops pend
-    forever) — the minimum slot count the kernel needs. Computing it up
-    front avoids SlotOverflow escalation recompiles."""
-    # same (position, order) tie-break as build_steps: invokes sort
-    # before returns at equal positions
+    forever, or to their release_crashed deadline) — the minimum slot
+    count the kernel needs. Computing it up front avoids SlotOverflow
+    escalation recompiles."""
+    # same (position, order) tie-break as build_steps: releases sort
+    # before invokes, invokes before returns at equal positions
     events = []
     for r in range(len(ops)):
         events.append((int(ops.inv[r]), 0, 1))
         if ops.kind[r] == KIND_OK:
             events.append((int(ops.ret[r]), 1, -1))
+        elif ops.kind[r] == KIND_RELEASED:
+            events.append((int(ops.ret[r]), -1, -1))
     events.sort()
     cur = peak = 0
     for _, _, d in events:
         cur += d
         peak = max(peak, cur)
     return max(peak, 1)
+
+
+def release_crashed(ops: OpArray, m: int) -> tuple[OpArray, bool]:
+    """Give each crashed op a deadline: the invocation of the m-th later
+    crashed op of its kind (the same f, a, b), after which it may no
+    longer take effect and its slot is released. Returns the ops and
+    whether any got a deadline.
+
+    A deadline only narrows when an op may take effect, so a history
+    linearizable with deadlines is linearizable, and with none left it
+    is the history itself. A crashed op otherwise holds its slot
+    forever, so a key with many crashes would step a 2^P table too wide
+    to run; with deadlines at most m of a kind hold slots at once. With
+    m = 1 a crash that took effect before the next of its kind was
+    invoked, as most do, is still covered."""
+    info = np.flatnonzero(np.asarray(ops.kind) == KIND_INFO)
+    if len(info) <= m:
+        return ops, False
+    by_kind: dict[tuple[int, int, int], list[int]] = {}
+    for r in info:
+        by_kind.setdefault((int(ops.f[r]), int(ops.a[r]), int(ops.b[r])),
+                           []).append(int(r))
+    ret, kind = np.array(ops.ret), np.array(ops.kind)
+    for rows in by_kind.values():
+        for j, r in enumerate(rows[:-m]):
+            ret[r], kind[r] = ops.inv[rows[j + m]], KIND_RELEASED
+    if (kind == np.asarray(ops.kind)).all():
+        return ops, False
+    return dataclasses.replace(ops, ret=ret, kind=kind), True
+
+
+def _pending_forever(ops: OpArray) -> OpArray:
+    """The ops without release_crashed's deadlines (the sort kernel has
+    no release step)."""
+    released = np.asarray(ops.kind) == KIND_RELEASED
+    if not released.any():
+        return ops
+    return dataclasses.replace(
+        ops, kind=np.where(released, KIND_INFO, ops.kind),
+        ret=np.where(released, PENDING_RET, ops.ret))
+
+
+def _death_position(ops: OpArray, result: dict) -> int | None:
+    """Where the definite invalid `result` of `ops` died: the position
+    of its culprit's completion (None if it names no completed op)."""
+    rows = np.flatnonzero(np.asarray(ops.index) == result.get("op-index"))
+    if not len(rows) or ops.kind[rows[0]] != KIND_OK:
+        return None
+    return int(ops.ret[rows[0]])
+
+
+def _prefix(ops: OpArray, position: int, droppable) -> OpArray:
+    """The ops invoked by `position`, those that complete after it
+    pending forever (or dropped where pending they constrain nothing).
+    Any linearization of the whole history, cut at `position`, is one
+    of this prefix, so a prefix that is not linearizable convicts the
+    history."""
+    late = (np.asarray(ops.kind) == KIND_OK) & \
+        (np.asarray(ops.ret) > position)
+    keep = (np.asarray(ops.inv) <= position) & \
+        ~(late & np.isin(ops.f, list(droppable)))
+    cut = dataclasses.replace(
+        ops, kind=np.where(late, KIND_INFO, ops.kind),
+        ret=np.where(late, PENDING_RET, ops.ret))
+    return OpArray(**{f.name: getattr(cut, f.name)[keep]
+                      for f in dataclasses.fields(OpArray)})
+
+
+def _decide_in_rounds(pre: list, run, droppable, deadlines: bool = True):
+    """Decide encoded histories through rounds of `run(indices, ops)`.
+
+    A key is first checked with release_crashed's deadlines at m = 1.
+    An invalid verdict stands if no deadline was given or the key died
+    before the first, where its stream is the history's own. Otherwise
+    the history's prefix up to that death is checked with no deadlines:
+    an invalid prefix convicts the key, a valid one means the deadlines
+    cost the verdict, and the key goes round again at 2m. With no
+    deadline left a round checks the history itself, so every verdict
+    is exact."""
+    results: list[dict | None] = [None] * len(pre)
+    plan = {i: (1, None) for i in range(len(pre))}   # key -> (m, cut)
+    while plan:
+        keys = list(plan)
+        with _telemetry.section("wgl.encode"):
+            sent = []
+            for i in keys:
+                m, cut = plan[i]
+                if cut is not None:
+                    sent.append((_prefix(pre[i], cut, droppable), False))
+                elif deadlines:
+                    sent.append(release_crashed(pre[i], m))
+                else:
+                    sent.append((pre[i], False))
+        nxt = {}
+        for i, (ops, released), r in zip(keys, sent,
+                                          run(keys, [o for o, _ in sent])):
+            m, cut = plan[i]
+            if cut is not None and r["valid?"] is True:
+                nxt[i] = (2 * m, None)
+                continue
+            if released and r["valid?"] is False:
+                died = _death_position(ops, r)
+                first = int(np.asarray(ops.ret)[
+                    np.asarray(ops.kind) == KIND_RELEASED].min())
+                if died is None or died > first:
+                    nxt[i] = (2 * m, None) if died is None else (m, died)
+                    continue
+            r["op-count"] = len(pre[i])
+            results[i] = r
+        _M_RECHECKED.inc(len(nxt))
+        plan = nxt
+    return results
 
 
 def build_steps(ops: OpArray, p: int, merge: bool = True) -> Steps:
@@ -449,6 +593,8 @@ def build_steps(ops: OpArray, p: int, merge: bool = True) -> Steps:
         events.append((int(ops.inv[r]), 0, E_INVOKE, r))
         if ops.kind[r] == KIND_OK:
             events.append((int(ops.ret[r]), 1, E_RETURN, r))
+        elif ops.kind[r] == KIND_RELEASED:
+            events.append((int(ops.ret[r]), -1, E_RELEASE, r))
     events.sort()
     w = max(1, (p + 31) // 32)
     free = list(range(p))
@@ -480,6 +626,15 @@ def build_steps(ops: OpArray, p: int, merge: bool = True) -> Steps:
             s = heapq.heappop(free)
             slot_of_row[r] = s
             flush(s, int(ops.f[r]), int(ops.a[r]), int(ops.b[r]), r)
+        elif k == E_RELEASE:
+            # its own step: completions before it complete, this slot
+            # only lets go
+            if pend_rows:
+                flush(-1, 0, NIL, NIL, -1)
+            s = slot_of_row.pop(r)
+            heapq.heappush(free, s)
+            pend[s // 32] |= 1 << (s % 32)
+            flush(-1, F_RELEASE, NIL, NIL, -1)
         else:
             s = slot_of_row.pop(r)
             heapq.heappush(free, s)
@@ -1187,9 +1342,12 @@ def _dense_kernel_cached(model_name: str, s_lo: int, S: int, P: int,
             # new config is the same mask with them cleared (injective:
             # no dedup, and closure is preserved, so no re-expansion).
             # table'[c] = table[c | rm] iff c ∩ rm = ∅; rm = 0 is the
-            # identity gather.
-            table = jnp.take(table, COLS | rm, axis=1) \
-                & ((COLS & rm) == 0)[None, :]
+            # identity gather. A release step (F_RELEASE) keeps the
+            # configurations that did not take the released ops as well:
+            # table'[c] = table[c] | table[c | rm], still closed.
+            done = jnp.take(table, COLS | rm, axis=1)
+            table = jnp.where((s < 0) & (f == F_RELEASE), table | done,
+                              done) & ((COLS & rm) == 0)[None, :]
             slot_occ = slot_occ & \
                 ~((rm >> ARANGE_P) & 1).astype(jnp.bool_)
             table, slot_f, slot_a, slot_b, slot_occ = lax.cond(
@@ -2047,6 +2205,35 @@ def _dispatch_groups(srange, p_req: list[int], engine: str,
     return dense_groups, sort_idx
 
 
+SPLIT_ROWS = 1 << 15   # staged rows (lanes x E) a dispatch of its own costs
+
+
+def _length_parts(idx: list[int], pre: list) -> list[list[int]]:
+    """Split a dense dispatch group's keys by the E bucket of their event
+    streams. A vmapped group stages every lane with the longest key's E
+    rows and steps it to the longest key's end, so under skewed key
+    lengths one long key makes the many short ones mostly padding. From
+    the longest down, a bucket joins the longer keys unless that stages
+    SPLIT_ROWS more rows than two dispatches would (about what one more
+    dispatch costs the host), so keys of like length stay one group."""
+    parts: dict[int, list[int]] = {}
+    for i in idx:
+        parts.setdefault(_bucket(max(event_count(pre[i]), 1)), []).append(i)
+    out: list[tuple[int, list[int]]] = []
+    for e in sorted(parts, reverse=True):
+        keys = parts[e]
+        if out:
+            e_long, longer = out[-1]
+            lanes = _bucket(len(longer), lo=1)
+            joined = _bucket(len(longer) + len(keys), lo=1) * e_long
+            apart = lanes * e_long + _bucket(len(keys), lo=1) * e
+            if joined - apart < SPLIT_ROWS:
+                longer.extend(keys)
+                continue
+        out.append((e, list(keys)))
+    return [keys for _, keys in reversed(out)]
+
+
 def analysis_tpu_batch(model, hists: list, frontier: int = 1024,
                        slots: int = 32, chunk_entries: int = 4096,
                        budget_s: float | None = None,
@@ -2144,13 +2331,21 @@ def _analysis_tpu_batch_once(model, hists: list, frontier: int = 1024,
                              pallas=None,
                              _pre: list | None = None,
                              _dense=False,
-                             _preq: list | None = None) -> list[dict]:
+                             _preq: list | None = None,
+                             _staged: bool = False) -> list[dict]:
     """Check a batch of independent histories (e.g. per-key subhistories
     from the independent workload) in vmapped device calls. Long batches
     run as bounded-duration chunks with the vmapped frontier carried
     between calls, polling budget_s / cancel like the scalar path —
     a pathological key can no longer stall an independent batch
     unboundedly. Undecided keys at the budget report 'unknown'.
+
+    Crashed ops are checked in rounds (see release_crashed): first each
+    may take effect only until the next of its kind is invoked, and only
+    a key that then fails, past its first deadline, is checked again
+    with twice as many of a kind open at once, until none has a
+    deadline. Every verdict is exact, and a key whose crashes took
+    effect soon or never pays no slot for each crash it ever saw.
 
     Escalation is batched: every overflow-suspect key re-runs together
     in one vmapped call at 4x the frontier (recursively), instead of
@@ -2159,6 +2354,7 @@ def _analysis_tpu_batch_once(model, hists: list, frontier: int = 1024,
 
     _pre: internal — pre-encoded OpArrays (one per history), passed by
     the group-split recursion so each history is encoded exactly once.
+    _staged: internal — _pre is one round's histories with deadlines.
     _dense: internal — the group's dense shape from _dispatch_groups
     (False = derive it here), so bucketed groups share the bucket's
     compiled kernel instead of re-deriving a data-dependent shape from
@@ -2178,13 +2374,29 @@ def _analysis_tpu_batch_once(model, hists: list, frontier: int = 1024,
 
     name = model.device_model
     section = _telemetry.section
+    if _preq is None and not _staged:
+        with section("wgl.encode"):
+            pre = (_pre if _pre is not None
+                   else [encode_ops_for_model(model, h) for h in hists])
+
+        def run(idx, ops):
+            return _analysis_tpu_batch_once(
+                model, [hists[i] for i in idx], frontier=frontier,
+                slots=slots, chunk_entries=chunk_entries,
+                budget_s=_remaining(), cancel=cancel, engine=engine,
+                max_frontier=max_frontier, dense_slot_cap=dense_slot_cap,
+                pallas=pallas, _pre=ops, _staged=True)
+
+        return _decide_in_rounds(  # type: ignore[return-value]
+            pre, run, DEVICE_MODELS[name].droppable,
+            deadlines=engine != "sort")
     group_list = None
     with section("wgl.encode"):
         pre = (_pre if _pre is not None
                else [encode_ops_for_model(model, h) for h in hists])
         _srange = _p_needs = None   # pre-pass reuse, one-bucket case
         if engine in ("auto", "dense") and len(hists) > 1 \
-                and _pre is None:
+                and _preq is None:
             # Slot-bucketed dispatch groups (see _dispatch_groups):
             # recurse per group — each group is then bucket-uniform and
             # runs the plain batched path below. Dense groups run
@@ -2198,8 +2410,9 @@ def _analysis_tpu_batch_once(model, hists: list, frontier: int = 1024,
                 n_events=max((event_count(o) for o in pre), default=1),
                 frontier=frontier, dense_slot_cap=dense_slot_cap,
                 pallas=pallas)
-            group_list = [dense_groups[pg]
-                          for pg in sorted(dense_groups)]
+            group_list = [(dense_groups[pg][0], part)
+                          for pg in sorted(dense_groups)
+                          for part in _length_parts(dense_groups[pg][1], pre)]
             if sort_idx:
                 group_list.append((False, sort_idx))
             if len(group_list) <= 1:
@@ -2299,6 +2512,8 @@ def _analysis_tpu_batch_once(model, hists: list, frontier: int = 1024,
         batched = []
     items = []           # (orig index, ops, steps)
     if batched:
+        if dense is None:
+            batched = [(i, _pending_forever(ops)) for i, ops in batched]
         with section("wgl.steps"):
             items = [(i, ops, build_steps(ops, slots))
                      for i, ops in batched]
@@ -2315,7 +2530,11 @@ def _analysis_tpu_batch_once(model, hists: list, frontier: int = 1024,
                 _bucket(len(padded), lo=1) - len(padded))
             x_np = np.stack([st.x for st in padded])
             ns = np.asarray([st.n for st in padded], np.int32)
-    if items:
+
+    def dispatch():
+        """Run the batch as one vmapped group: stage, step, summarize,
+        and blame its definite invalids in one pass. Returns the
+        overflow suspects and whether attestation was on."""
         with section("wgl.stage"):
             if dense is not None:
                 k = _dense_kernel(name, dense[0], dense[1], dense[2], E,
@@ -2367,6 +2586,15 @@ def _analysis_tpu_batch_once(model, hists: list, frontier: int = 1024,
                         and _time.monotonic() - t0 > budget_s) \
                         or (cancel is not None and cancel()):
                     break
+        # every lane runs every step advanced, over the E rows staged for
+        # it; a real key needs only its own steps
+        family = "dense" if dense is not None else "sort"
+        _M_LANE_STEPS.labels(family=family, kind="staged").inc(
+            len(padded) * E)
+        _M_LANE_STEPS.labels(family=family, kind="dispatched").inc(
+            len(padded) * e)
+        _M_LANE_STEPS.labels(family=family, kind="useful").inc(
+            int(np.minimum(ns[:len(items)], e).sum()))
         with section("wgl.summarize"):
             if attest_on:
                 # per-key carry attestation at the batch's final
@@ -2448,6 +2676,16 @@ def _analysis_tpu_batch_once(model, hists: list, frontier: int = 1024,
                             r["op"] = _find_op(hists[i], src)
                             r["op-index"] = src
                     results[i] = r
+        return suspects, attest_on
+
+    if items:
+        _M_GROUPS.labels(
+            family="dense" if dense is not None else "sort").inc()
+        # annotation only: the phases and chunk sites inside are timed
+        # on their own
+        with section("wgl.batch.group", P=slots, lanes=len(padded),
+                     keys=len(items), steps=int(ns.max())):
+            suspects, attest_on = dispatch()
         if suspects:
             if frontier < max_frontier:
                 sub = analysis_tpu_batch(

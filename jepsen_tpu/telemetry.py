@@ -516,16 +516,16 @@ _M_PHASE = histogram(
     ("phase",), buckets=FINE_BUCKETS)
 
 
-def _annotation(name: str):
+def _annotation(name: str, **args):
     try:
         from jax.profiler import TraceAnnotation
     except ImportError:   # a host-only install has no profiler to name it in
         return contextlib.nullcontext()
-    return TraceAnnotation(name)
+    return TraceAnnotation(name, **args)
 
 
 @contextlib.contextmanager
-def section(name: str):
+def section(name: str, **args):
     """A named section of the checking path: a ``jax.profiler``
     ``TraceAnnotation``, so the section shows on the host plane of any
     active trace (the benchmark's, or the one ``JEPSEN_TPU_PROFILE``
@@ -533,11 +533,12 @@ def section(name: str):
     that costs one check. A phase (a name in :data:`PHASES`) also
     observes its host seconds into ``jepsen_tpu_wgl_phase_seconds``;
     the chunk sites (``wgl.offline.chunk``, ...) are timed by
-    ``jepsen_tpu_wgl_chunk_seconds`` alone. Reads no environment and
-    never starts or stops a trace."""
+    ``jepsen_tpu_wgl_chunk_seconds`` alone. ``args`` ride on the
+    annotation as its arguments. Reads no environment and never starts
+    or stops a trace."""
     series = _M_PHASE.labels(phase=name) if name in PHASES else None
     t0 = time.monotonic()
-    with _annotation(name):
+    with _annotation(name, **args):
         try:
             yield
         finally:

@@ -382,13 +382,15 @@ def test_file_exporter_still_synchronous(tmp_path):
 
 class _Annotations:
     """Stands in for jax.profiler.TraceAnnotation: records each
-    section's name as it is entered and left."""
+    section's name as it is entered and left, and its arguments."""
 
     def __init__(self):
         self.events = []
+        self.args = []
 
-    def __call__(self, name):
+    def __call__(self, name, **args):
         rec = self
+        rec.args.append((name, args))
 
         class _Ann:
             def __enter__(self):
@@ -562,6 +564,109 @@ def test_every_device_sync_names_its_own_fixed_site():
             sites.append(site.value)
     assert len(sites) >= 20
     assert len(set(sites)) == len(sites), sorted(sites)
+
+
+# -- the batch engine's dispatch groups and their padding --------------------
+
+def _sequential(n_ops, first_read=None):
+    """One key's history of n_ops writes, one at a time: slot need 1 and
+    n_ops + 1 merged steps (one per invoke, one for the last return). A
+    first read of `first_read` from the nil register kills the key's
+    frontier at its first step."""
+    ops = []
+    if first_read is not None:
+        ops += [{"type": "invoke", "f": "read", "value": None, "process": 0},
+                {"type": "ok", "f": "read", "value": first_read,
+                 "process": 0}]
+    for i in range(n_ops - len(ops) // 2):
+        ops += [{"type": "invoke", "f": "write", "value": i % 5,
+                 "process": 0},
+                {"type": "ok", "f": "write", "value": i % 5, "process": 0}]
+    return ops
+
+
+def _concurrent(n_ops):
+    """n_ops writes all in flight at once: slot need n_ops, n_ops + 1
+    merged steps."""
+    return ([{"type": "invoke", "f": "write", "value": i % 5, "process": i}
+             for i in range(n_ops)] +
+            [{"type": "ok", "f": "write", "value": i % 5, "process": i}
+             for i in range(n_ops)])
+
+
+def _lane_steps():
+    snap = telemetry.snapshot().get("jepsen_tpu_wgl_batch_lane_steps_total",
+                                    {})
+    out = {"staged": 0, "dispatched": 0, "useful": 0}
+    for labels, v in snap.items():
+        out[labels.rsplit("kind=", 1)[1]] += v
+    return out
+
+
+def _groups():
+    return telemetry.snapshot().get("jepsen_tpu_wgl_batch_groups_total", {})
+
+
+def _batch(hists, **kw):
+    from jepsen_tpu import models
+    from jepsen_tpu.checker.wgl import analysis_tpu_batch
+
+    return analysis_tpu_batch(models.cas_register(), hists, engine="dense",
+                              **kw)
+
+
+def test_batch_lane_steps_count_each_groups_lanes_and_keys():
+    """Slot needs 1, 1, 1 and 6 bucket into a P=4 group of three keys
+    (4 lanes, 4/6/10 steps) and a P=6 group of one key (1 lane, 7
+    steps): dispatched = lanes x longest key, useful = the keys' steps."""
+    rs = _batch([_sequential(3), _sequential(9), _sequential(5),
+                 _concurrent(6)])
+    assert [r["valid?"] for r in rs] == [True] * 4
+    assert _lane_steps() == {"staged": 4 * 64 + 1 * 64,
+                             "dispatched": 4 * 10 + 1 * 7,
+                             "useful": (4 + 10 + 6) + 7}
+    assert _groups() == {"family=dense": 2}
+
+
+def test_batch_of_equal_lengths_pads_only_by_lane_rounding():
+    rs = _batch([_sequential(5)] * 3)
+    assert all(r["valid?"] is True for r in rs)
+    steps = _lane_steps()
+    assert steps == {"staged": 4 * 64, "dispatched": 4 * 6, "useful": 3 * 6}
+    assert 1 - steps["useful"] / steps["dispatched"] == 1 - 3 / 4
+    assert _groups() == {"family=dense": 1}
+
+
+def test_an_all_dead_break_counts_only_the_steps_advanced():
+    """Both keys die at their first step; the loop sees it one chunk
+    late and stops after its second chunk of 4, far short of 41; both
+    lanes were staged with all 128 rows."""
+    rs = _batch([_sequential(40, first_read=3)] * 2, chunk_entries=4)
+    assert [r["valid?"] for r in rs] == [False, False]
+    assert _lane_steps() == {"staged": 2 * 128, "dispatched": 2 * 8,
+                             "useful": 2 * 8}
+
+
+def test_batch_group_span_wraps_each_group_under_a_trace(monkeypatch):
+    import jax.profiler
+
+    ann = _Annotations()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", ann)
+    _batch([_sequential(3), _sequential(9), _sequential(5), _concurrent(6)])
+    groups = [a for n, a in ann.args if n == "wgl.batch.group"]
+    assert groups == [{"P": 4, "lanes": 4, "keys": 3, "steps": 10},
+                      {"P": 6, "lanes": 1, "keys": 1, "steps": 7}]
+    # each group span holds its own stage, chunks and summarize
+    inside, depth = set(), 0
+    for kind, name in ann.events:
+        if name == "wgl.batch.group":
+            depth += 1 if kind == "enter" else -1
+        elif depth and kind == "enter":
+            inside.add(name)
+    assert depth == 0
+    assert {"wgl.stage", "wgl.batch.chunk", "wgl.summarize"} <= inside
+    # annotation only: no phase seconds of its own
+    assert "wgl.batch.group" not in _phases()
 
 
 # -- /metrics + /healthz e2e against a live service -------------------------
