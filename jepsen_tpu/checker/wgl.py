@@ -118,6 +118,13 @@ _M_RECHECKED = _telemetry.counter(
     "Keys a batch checked again, by prefix or with later deadlines, "
     "after a crash deadline may have cost their verdict (see "
     "release_crashed)")
+_M_DEADLINE_CHECKS = _telemetry.counter(
+    "jepsen_tpu_wgl_deadline_checks_total",
+    "Checks in _decide_in_rounds by site (offline: analysis_tpu, batch: "
+    "analysis_tpu_batch) and outcome: none (no op had a crash deadline), "
+    "stood (the verdict under deadlines was final), again (the history "
+    "went another round)",
+    ("site", "outcome"))
 
 # Event kinds (host-side stream construction)
 E_INVOKE = 0
@@ -538,7 +545,8 @@ def _prefix(ops: OpArray, position: int, droppable) -> OpArray:
                       for f in dataclasses.fields(OpArray)})
 
 
-def _decide_in_rounds(pre: list, run, droppable, deadlines: bool = True):
+def _decide_in_rounds(pre: list, run, droppable, deadlines: bool = True,
+                      site: str = "batch"):
     """Decide encoded histories through rounds of `run(indices, ops)`.
 
     A key is first checked with release_crashed's deadlines at m = 1.
@@ -548,7 +556,8 @@ def _decide_in_rounds(pre: list, run, droppable, deadlines: bool = True):
     an invalid prefix convicts the key, a valid one means the deadlines
     cost the verdict, and the key goes round again at 2m. With no
     deadline left a round checks the history itself, so every verdict
-    is exact."""
+    is exact. `site` labels the checks in jepsen_tpu_wgl_deadline_checks
+    (offline: analysis_tpu, batch: analysis_tpu_batch)."""
     results: list[dict | None] = [None] * len(pre)
     plan = {i: (1, None) for i in range(len(pre))}   # key -> (m, cut)
     while plan:
@@ -564,6 +573,7 @@ def _decide_in_rounds(pre: list, run, droppable, deadlines: bool = True):
                 else:
                     sent.append((pre[i], False))
         nxt = {}
+        stood = 0
         for i, (ops, released), r in zip(keys, sent,
                                           run(keys, [o for o, _ in sent])):
             m, cut = plan[i]
@@ -579,7 +589,12 @@ def _decide_in_rounds(pre: list, run, droppable, deadlines: bool = True):
                     continue
             r["op-count"] = len(pre[i])
             results[i] = r
-        _M_RECHECKED.inc(len(nxt))
+            stood += released
+        for outcome, n in (("none", len(keys) - stood - len(nxt)),
+                           ("stood", stood), ("again", len(nxt))):
+            _M_DEADLINE_CHECKS.labels(site=site, outcome=outcome).inc(n)
+        if site == "batch":
+            _M_RECHECKED.inc(len(nxt))
         plan = nxt
     return results
 
@@ -1777,51 +1792,115 @@ def analysis_tpu(model, hist, frontier: int = 256, slots: int | None = None,
                  engine: str = "auto",
                  dense_slot_cap: int | None = None,
                  pallas=None,
-                 max_recovery_retries: int | None = None) -> dict:
-    """Check one history on the device, under the device-fault recovery
-    ladder (see the ladder comment above): a classified backend fault
-    (oom / device-lost / compile / wedged) re-runs the search down the
+                 max_recovery_retries: int | None = None,
+                 _ops: OpArray | None = None) -> dict:
+    """Check one history on the device. Its crashed ops are checked in
+    rounds of deadlines, as the batch path checks a key's (see
+    _decide_in_rounds): first each may take effect only until the next
+    crash of its kind is invoked, which keeps the dense table as narrow
+    as the crashes that can still land, and only a history that then
+    fails past its first deadline is checked again. Every verdict is
+    exact. budget_s and cancel cover all rounds together.
+
+    Each round's search runs under the device-fault recovery ladder
+    (see the ladder comment above): a classified backend fault (oom /
+    device-lost / compile / wedged) re-runs the search down the
     appropriate rung instead of surfacing as a degraded 'unknown', and
     a decided result that went through the ladder reports its
     'recovered' trail. max_recovery_retries bounds the ladder (None =
-    MAX_RECOVERY_RETRIES); past it, histories under
-    HOST_FALLBACK_MAX_OPS are decided on the host mirror.
+    MAX_RECOVERY_RETRIES) over all rounds; past it, histories under
+    HOST_FALLBACK_MAX_OPS are decided on the host mirror, whose verdict
+    is the history's own and ends the rounds.
 
-    See _analysis_tpu_once for the search itself and the remaining
-    knobs."""
+    explain: on a definite invalid verdict, re-run the host oracle on
+    the prefix ending at the culprit op to reconstruct configs and
+    final-paths (the reference renders these via knossos.linear.report,
+    `checker.clj:205-216`). See _analysis_tpu_once for the search itself
+    and the remaining knobs.
+
+    _ops: internal — one batch round's encoded ops for this history,
+    checked as given in one search: the batch runs the rounds."""
+    t0 = _time.monotonic()
     kw = dict(frontier=frontier, slots=slots, max_frontier=max_frontier,
-              chunk_entries=chunk_entries, budget_s=budget_s,
-              cancel=cancel, explain=explain,
+              chunk_entries=chunk_entries, cancel=cancel,
               slot_overflow_fallback=slot_overflow_fallback,
               engine=engine, dense_slot_cap=dense_slot_cap,
               pallas=pallas)
     trail = _RecoveryTrail(max_recovery_retries)
-    while True:
-        try:
-            a = _analysis_tpu_once(model, hist, **kw)
-        except RuntimeError as e:
-            if not trail.absorb(e, "offline"):
-                return _final_rung(model, hist, trail, e,
-                                   budget_s=budget_s, cancel=cancel)
-            _apply_recovery_rung(trail.faults[-1], kw)
-            continue
-        trail.stamp(a)
-        return a
+    final = None   # the final rung's verdict, the history's own
+
+    def remaining():
+        if budget_s is None:
+            return None
+        return max(0.0, budget_s - (_time.monotonic() - t0))
+
+    def search(ops):
+        nonlocal final
+        while final is None:
+            try:
+                a = _analysis_tpu_once(model, hist, ops,
+                                       budget_s=remaining(), **kw)
+            except RuntimeError as e:
+                if trail.absorb(e, "offline"):
+                    _apply_recovery_rung(trail.faults[-1], kw)
+                else:
+                    final = _final_rung(model, hist, trail, e,
+                                        budget_s=remaining(),
+                                        cancel=cancel)
+                continue
+            trail.stamp(a)
+            return a
+        return final
+
+    if _ops is not None:
+        a = search(_ops)
+    else:
+        with _telemetry.section("wgl.encode"):
+            pre = encode_ops_for_model(model, hist)
+        rounds = 0
+
+        def run(_keys, sent):
+            nonlocal rounds
+            rounds += 1
+            if rounds > 1 and (remaining() == 0.0 or
+                               (cancel is not None and cancel())):
+                return [{"valid?": "unknown", "analyzer": "tpu-wgl",
+                         "error": "budget exhausted/cancelled before "
+                                  "the history's next round",
+                         "configs": [], "final-paths": []}]
+            return [search(sent[0])]
+
+        a, = _decide_in_rounds([pre], run,
+                               DEVICE_MODELS[model.device_model].droppable,
+                               deadlines=engine != "sort", site="offline")
+        a["duration-ms"] = (_time.monotonic() - t0) * 1e3
+    if explain and a["valid?"] is False and "op-index" in a \
+            and a["analyzer"] in ("tpu-wgl", "tpu-wgl-dense"):
+        from .linear import explain_failure
+        with _telemetry.section("wgl.explain"):
+            ex = explain_failure(model, hist, a["op-index"])
+        if ex is not None:
+            a["configs"] = ex["configs"]
+            a["final-paths"] = ex["final-paths"]
+            if ex.get("previous-ok") is not None:
+                a["previous-ok"] = ex["previous-ok"]
+    return a
 
 
-def _analysis_tpu_once(model, hist, frontier: int = 256,
+def _analysis_tpu_once(model, hist, ops: OpArray, frontier: int = 256,
                        slots: int | None = None,
                        max_frontier: int = 65536,
                        chunk_entries: int = 4096,
                        budget_s: float | None = None,
                        cancel=None,
-                       explain: bool = True,
                        slot_overflow_fallback: bool = True,
                        engine: str = "auto",
                        dense_slot_cap: int | None = None,
                        pallas=None) -> dict:
-    """Check one history on the device. The slot count is sized to the
-    history's actual peak concurrency; long histories run as a sequence
+    """Check `ops`, one round's encoding of `hist`, on the device. The
+    slot count is sized to the ops' actual peak concurrency (crashed ops
+    pend to their deadline, or forever on the sort family, which has no
+    release step); long histories run as a sequence
     of bounded-duration chunked kernel calls with the frontier carried
     (and checkpointable) between them, so a 100k-op search never holds
     the device in one multi-minute call. Escalates the frontier on
@@ -1834,10 +1913,7 @@ def _analysis_tpu_once(model, hist, frontier: int = 256,
     checker hits the same wall as an OOM or its 1 h timeout).
 
     cancel: zero-arg callable polled between chunks — truthy stops the
-    search with 'unknown' (competition racing). explain: on a definite
-    invalid verdict, re-run the host oracle on the prefix ending at the
-    culprit op to reconstruct configs and final-paths (the reference
-    renders these via knossos.linear.report, `checker.clj:205-216`).
+    search with 'unknown' (competition racing).
 
     engine: 'auto' picks by the cost model (see select_engine) over
     the dense reachable-set kernel (exact verdicts, no frontier,
@@ -1862,7 +1938,6 @@ def _analysis_tpu_once(model, hist, frontier: int = 256,
     name = model.device_model
     section = _telemetry.section
     with section("wgl.encode"):
-        ops = encode_ops_for_model(model, hist)
         p_exact = required_slots(ops)
         if slots is None or p_exact > slots:
             slots = _bucket(p_exact, lo=8)
@@ -1873,6 +1948,12 @@ def _analysis_tpu_once(model, hist, frontier: int = 256,
                                      engine=engine,
                                      dense_slot_cap=dense_slot_cap,
                                      pallas=pallas)
+            if decision.dense is None:
+                pending = _pending_forever(ops)
+                if pending is not ops:
+                    # the sort family has no release step
+                    ops = pending
+                    slots = max(slots, _bucket(required_slots(ops), lo=8))
     if slots > 256:
         if not slot_overflow_fallback:
             # competition racing: a parallel host thread is already
@@ -2060,15 +2141,6 @@ def _analysis_tpu_once(model, hist, frontier: int = 256,
                     src_index = int(ops.index[row])
                     out["op"] = _find_op(hist, src_index)
                     out["op-index"] = src_index
-            if row >= 0 and explain:
-                from .linear import explain_failure
-                with section("wgl.explain"):
-                    ex = explain_failure(model, hist, src_index)
-                if ex is not None:
-                    out["configs"] = ex["configs"]
-                    out["final-paths"] = ex["final-paths"]
-                    if ex.get("previous-ok") is not None:
-                        out["previous-ok"] = ex["previous-ok"]
     return out
 
 
@@ -2492,13 +2564,19 @@ def _analysis_tpu_batch_once(model, hists: list, frontier: int = 1024,
         encoded = []
     batched = []
     for i, ops in encoded:
+        if dense is None:
+            # the sort family has no release step
+            pending = _pending_forever(ops)
+            if pending is not ops:
+                ops, p_needs[i] = pending, required_slots(pending)
         if dense is None and p_needs[i] > slots:
-            # this key alone exceeds the batch's slot budget: scalar
-            # path re-sizes (and host-falls-back past 256)
+            # this key alone exceeds the batch's slot budget: the scalar
+            # path re-sizes (and host-falls-back past 256), checking this
+            # round's ops once, since this batch runs the rounds
             results[i] = analysis_tpu(
                 model, hists[i], frontier, budget_s=_remaining(),
                 cancel=cancel, engine=engine,
-                dense_slot_cap=dense_slot_cap, pallas=pallas)
+                dense_slot_cap=dense_slot_cap, pallas=pallas, _ops=ops)
         else:
             batched.append((i, ops))
     if batched and ((_remaining() == 0.0)
@@ -2512,8 +2590,6 @@ def _analysis_tpu_batch_once(model, hists: list, frontier: int = 1024,
         batched = []
     items = []           # (orig index, ops, steps)
     if batched:
-        if dense is None:
-            batched = [(i, _pending_forever(ops)) for i, ops in batched]
         with section("wgl.steps"):
             items = [(i, ops, build_steps(ops, slots))
                      for i, ops in batched]
@@ -2688,13 +2764,17 @@ def _analysis_tpu_batch_once(model, hists: list, frontier: int = 1024,
             suspects, attest_on = dispatch()
         if suspects:
             if frontier < max_frontier:
+                # this round's ops again (only the sort family
+                # overflows), so the escalation runs no rounds of its own
                 sub = analysis_tpu_batch(
                     model, [hists[i] for i, _ in suspects],
                     frontier=frontier * 4, slots=slots,
                     chunk_entries=chunk_entries, budget_s=_remaining(),
                     cancel=cancel, engine=engine,
                     max_frontier=max_frontier,
-                    dense_slot_cap=dense_slot_cap, pallas=pallas)
+                    dense_slot_cap=dense_slot_cap, pallas=pallas,
+                    _pre=[ops for _, ops in suspects],
+                    _preq=[p_needs[i] for i, _ in suspects])
                 for t, (i, _ops) in enumerate(suspects):
                     results[i] = sub[t]
             else:
