@@ -375,7 +375,8 @@ def maybe_inject_fault(site: str) -> None:
     """Called immediately before each recovery-aware device dispatch.
 
     Sites in use: 'offline' (wgl.analysis_tpu), 'batch'
-    (wgl.analysis_tpu_batch), 'sharded' (wgl.check_batch_sharded),
+    (wgl.analysis_tpu_batch: each chunk of a dispatch group, whose
+    fault retries that group), 'sharded' (wgl.check_batch_sharded),
     'stream-chunk' (streaming.WglStream), 'elle'
     (elle.kernels._classify_batches). The env spec is a
     comma-separated list of ``kind@site:n`` clauses; the n-th dispatch

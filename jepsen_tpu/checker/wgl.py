@@ -41,9 +41,13 @@ Soundness under resource caps: frontier overflow (> F live configs) only
 Slot overflow (> P concurrent+crashed pending ops) is detected host-side
 before launch.
 
-Batching: `vmap` over independent per-key histories;
-`check_batch_sharded` shards the key axis over a `jax.sharding.Mesh` and
-reduces verdicts with a psum-OR over ICI.
+Batching: `vmap` over independent per-key histories. `_plan_groups`
+plans a batch's dispatch groups once (keys bucketed by slot need, then
+by length; dense groups cheapest-first, the sort group last), and both
+batch entries dispatch that plan: `analysis_tpu_batch` checks each group in
+one `_check_group` call under the recovery ladder, and
+`check_batch_sharded` shards each group's key axis over a
+`jax.sharding.Mesh` and reduces verdicts with a psum-OR over ICI.
 """
 
 from __future__ import annotations
@@ -55,7 +59,7 @@ import heapq
 import logging
 import os
 import time as _time
-from typing import Callable
+from typing import Callable, NamedTuple
 
 import numpy as np
 
@@ -557,7 +561,9 @@ def _decide_in_rounds(pre: list, run, droppable, deadlines: bool = True,
     cost the verdict, and the key goes round again at 2m. With no
     deadline left a round checks the history itself, so every verdict
     is exact. `site` labels the checks in jepsen_tpu_wgl_deadline_checks
-    (offline: analysis_tpu, batch: analysis_tpu_batch)."""
+    (offline: analysis_tpu, whose `run` is one _search a round; batch:
+    analysis_tpu_batch, whose `run` plans the round's dispatch groups
+    and checks each once)."""
     results: list[dict | None] = [None] * len(pre)
     plan = {i: (1, None) for i in range(len(pre))}   # key -> (m, cut)
     while plan:
@@ -1632,8 +1638,10 @@ def select_engine(srange: tuple[int, int], p_exact: int, n_events: int,
 #   oom          shrink the device working set (halve chunk_entries;
 #                under 'auto', re-select the engine with dense_slot_cap
 #                0, i.e. the sort family — the dense table is the
-#                memory hog) — batch entries additionally SPLIT the
-#                batch in half and recover each half independently
+#                memory hog) — batch entries instead SPLIT a dispatch
+#                of more than one key in half (the batch entry its
+#                group, the sharded entry its batch) and recover each
+#                half independently
 #   device-lost  one backend re-init (jax.clear_caches + drop this
 #                module's kernel LRUs, whose jitted fns hold
 #                executables bound to the lost device), then retry
@@ -1792,8 +1800,7 @@ def analysis_tpu(model, hist, frontier: int = 256, slots: int | None = None,
                  engine: str = "auto",
                  dense_slot_cap: int | None = None,
                  pallas=None,
-                 max_recovery_retries: int | None = None,
-                 _ops: OpArray | None = None) -> dict:
+                 max_recovery_retries: int | None = None) -> dict:
     """Check one history on the device. Its crashed ops are checked in
     rounds of deadlines, as the batch path checks a key's (see
     _decide_in_rounds): first each may take effect only until the next
@@ -1803,23 +1810,20 @@ def analysis_tpu(model, hist, frontier: int = 256, slots: int | None = None,
     exact. budget_s and cancel cover all rounds together.
 
     Each round's search runs under the device-fault recovery ladder
-    (see the ladder comment above): a classified backend fault (oom /
-    device-lost / compile / wedged) re-runs the search down the
-    appropriate rung instead of surfacing as a degraded 'unknown', and
-    a decided result that went through the ladder reports its
-    'recovered' trail. max_recovery_retries bounds the ladder (None =
-    MAX_RECOVERY_RETRIES) over all rounds; past it, histories under
-    HOST_FALLBACK_MAX_OPS are decided on the host mirror, whose verdict
-    is the history's own and ends the rounds.
+    (see _search): a classified backend fault (oom / device-lost /
+    compile / wedged) re-runs the search down the appropriate rung
+    instead of surfacing as a degraded 'unknown', and a decided result
+    that went through the ladder reports its 'recovered' trail.
+    max_recovery_retries bounds the ladder (None = MAX_RECOVERY_RETRIES)
+    over all rounds; past it, histories under HOST_FALLBACK_MAX_OPS are
+    decided on the host mirror, whose verdict is the history's own and
+    ends the rounds.
 
     explain: on a definite invalid verdict, re-run the host oracle on
     the prefix ending at the culprit op to reconstruct configs and
     final-paths (the reference renders these via knossos.linear.report,
     `checker.clj:205-216`). See _analysis_tpu_once for the search itself
-    and the remaining knobs.
-
-    _ops: internal — one batch round's encoded ops for this history,
-    checked as given in one search: the batch runs the rounds."""
+    and the remaining knobs."""
     t0 = _time.monotonic()
     kw = dict(frontier=frontier, slots=slots, max_frontier=max_frontier,
               chunk_entries=chunk_entries, cancel=cancel,
@@ -1834,47 +1838,61 @@ def analysis_tpu(model, hist, frontier: int = 256, slots: int | None = None,
             return None
         return max(0.0, budget_s - (_time.monotonic() - t0))
 
-    def search(ops):
-        nonlocal final
-        while final is None:
-            try:
-                a = _analysis_tpu_once(model, hist, ops,
-                                       budget_s=remaining(), **kw)
-            except RuntimeError as e:
-                if trail.absorb(e, "offline"):
-                    _apply_recovery_rung(trail.faults[-1], kw)
-                else:
-                    final = _final_rung(model, hist, trail, e,
-                                        budget_s=remaining(),
-                                        cancel=cancel)
+    with _telemetry.section("wgl.encode"):
+        pre = encode_ops_for_model(model, hist)
+    rounds = 0
+
+    def run(_keys, sent):
+        nonlocal rounds, final
+        rounds += 1
+        if rounds > 1 and (remaining() == 0.0 or
+                           (cancel is not None and cancel())):
+            return [{"valid?": "unknown", "analyzer": "tpu-wgl",
+                     "error": "budget exhausted/cancelled before "
+                              "the history's next round",
+                     "configs": [], "final-paths": []}]
+        if final is None:
+            a, last = _search(model, hist, sent[0], trail, kw, remaining)
+            if not last:
+                return [a]
+            final = a
+        return [final]
+
+    a, = _decide_in_rounds([pre], run,
+                           DEVICE_MODELS[model.device_model].droppable,
+                           deadlines=engine != "sort", site="offline")
+    a["duration-ms"] = (_time.monotonic() - t0) * 1e3
+    if explain:
+        _explain(model, hist, a)
+    return a
+
+
+def _search(model, hist, ops: OpArray, trail: _RecoveryTrail, kw: dict,
+            remaining) -> tuple[dict, bool]:
+    """One round's search: check `ops`, a round's encoding of `hist`,
+    in one _analysis_tpu_once call under the recovery ladder (see the
+    ladder comment above). A classified backend fault re-runs the
+    search down its rung, mutating `kw`; once `trail` is spent the
+    final rung decides `hist` itself. remaining() is the budget left.
+    Returns the result and whether the final rung gave it."""
+    while True:
+        try:
+            a = _analysis_tpu_once(model, hist, ops, budget_s=remaining(),
+                                   **kw)
+        except RuntimeError as e:
+            if trail.absorb(e, "offline"):
+                _apply_recovery_rung(trail.faults[-1], kw)
                 continue
-            trail.stamp(a)
-            return a
-        return final
+            return _final_rung(model, hist, trail, e, budget_s=remaining(),
+                               cancel=kw["cancel"]), True
+        trail.stamp(a)
+        return a, False
 
-    if _ops is not None:
-        a = search(_ops)
-    else:
-        with _telemetry.section("wgl.encode"):
-            pre = encode_ops_for_model(model, hist)
-        rounds = 0
 
-        def run(_keys, sent):
-            nonlocal rounds
-            rounds += 1
-            if rounds > 1 and (remaining() == 0.0 or
-                               (cancel is not None and cancel())):
-                return [{"valid?": "unknown", "analyzer": "tpu-wgl",
-                         "error": "budget exhausted/cancelled before "
-                                  "the history's next round",
-                         "configs": [], "final-paths": []}]
-            return [search(sent[0])]
-
-        a, = _decide_in_rounds([pre], run,
-                               DEVICE_MODELS[model.device_model].droppable,
-                               deadlines=engine != "sort", site="offline")
-        a["duration-ms"] = (_time.monotonic() - t0) * 1e3
-    if explain and a["valid?"] is False and "op-index" in a \
+def _explain(model, hist, a: dict) -> None:
+    """Give a definite invalid device verdict the host oracle's configs
+    and final-paths, from the prefix ending at its culprit op."""
+    if a["valid?"] is False and "op-index" in a \
             and a["analyzer"] in ("tpu-wgl", "tpu-wgl-dense"):
         from .linear import explain_failure
         with _telemetry.section("wgl.explain"):
@@ -1884,7 +1902,6 @@ def analysis_tpu(model, hist, frontier: int = 256, slots: int | None = None,
             a["final-paths"] = ex["final-paths"]
             if ex.get("previous-ok") is not None:
                 a["previous-ok"] = ex["previous-ok"]
-    return a
 
 
 def _analysis_tpu_once(model, hist, ops: OpArray, frontier: int = 256,
@@ -2196,8 +2213,8 @@ def _slot_bucket(p: int, p_max: int | None = None) -> int:
 
 
 def _dense_caps_error(srange, p: int, key=None) -> ValueError:
-    """The forced-dense contract violation (one message, three raise
-    sites: scalar, batch plain path, batch group split)."""
+    """The forced-dense contract violation (one message, two raise
+    sites: select_engine for one history, _plan_groups for a batch)."""
     who = f"key {key}'s" if key is not None else "the"
     return ValueError(
         f"dense engine requested but {who} {srange} state range x "
@@ -2221,60 +2238,79 @@ def _check_att(att, site: str) -> None:
 
 
 def _unknown_result(ops, error: str, t0: float) -> dict:
-    """The batch paths' 'unknown' verdict shape (one definition so the
-    grouped and plain paths can't drift)."""
+    """The batch path's 'unknown' verdict shape (one definition for its
+    budget, cancel and escalation-cap exits)."""
     return {"valid?": "unknown", "analyzer": "tpu-wgl-batch",
             "op-count": len(ops), "error": error,
             "configs": [], "final-paths": [],
             "duration-ms": (_time.monotonic() - t0) * 1e3}
 
 
-def _dispatch_groups(srange, p_req: list[int], engine: str,
-                     n_events: int = 1, frontier: int = 1024,
-                     dense_slot_cap: int | None = None, pallas=None):
-    """Partition a batch's key indices into slot-bucketed dense dispatch
-    groups plus one shared sort-frontier group.
+class _Group(NamedTuple):
+    """One dispatch group: the keys (indices into the round's batch)
+    that run together in one vmapped kernel shape, its dense shape
+    (s_lo, S, P), or None for the sort family, and its slot count."""
+    keys: list[int]
+    dense: tuple | None
+    slots: int
+
+
+def _plan_groups(model, ops: list, p_req: list[int], engine: str,
+                 frontier: int = 1024, dense_slot_cap: int | None = None,
+                 pallas=None, slots: int = 32,
+                 srange=None) -> list[_Group]:
+    """Plan a batch's dispatch groups: which keys run together, at which
+    shape, in which order. `ops` are the keys' encoded histories and
+    `p_req` their required_slots; the sort group takes `slots`. `srange`
+    is their _state_range when the caller has it.
 
     The dense table is S * 2^P wide, so padding every key to the worst
     key's slot count multiplies the whole batch's device work by
-    2^(Pmax - P_key); bucketing nearby keys into one compiled kernel
-    each recovers that while adding only a few sub-ms dispatches.
-    Dense-ineligible keys gain nothing from grouping (the sort frontier
-    isn't 2^P-sized), so they spill into a single sort group instead of
-    paying one sort-kernel compile per bucket — or, under a forced
-    dense engine, raise. Under 'auto' the cost model (select_engine)
-    can also route a dense-*eligible* bucket to the sort family when
-    its table work is modeled slower; n_events is the batch's largest
-    event stream (per-key streams share the verdict of the comparison,
-    which is length-invariant except for the one-off table init).
+    2^(Pmax - P_key): keys bucket by slot need (_slot_bucket), one
+    compiled kernel a bucket, for only a few more sub-ms dispatches.
+    Under 'auto' the cost model (select_engine) can route a
+    dense-eligible bucket to the sort family when its table work is
+    modeled slower; the event count it weighs is the batch's largest
+    (per-key streams share the verdict of the comparison, which is
+    length-invariant except for the one-off table init). Dense-
+    ineligible keys gain nothing from grouping (the sort frontier isn't
+    2^P-sized), so they spill into a single sort group instead of
+    paying one sort-kernel compile per bucket — or, under a forced dense
+    engine, raise. Each dense bucket then splits by its keys' lengths
+    (_length_parts).
 
-    Returns (dense_groups: {P: (dense_shape, [key indices])},
-    sort_idx: [key indices])."""
+    Dense groups come cheapest-first and the sort group last, so a
+    pathological dense-ineligible key can only starve itself of budget,
+    not the cheap keys. A batch of one key or one bucket plans to one
+    group."""
     if engine == "sort":
-        return {}, list(range(len(p_req)))
+        return [_Group(list(range(len(ops))), None, slots)]
+    if srange is None:
+        srange = _state_range(model.device_model, model, ops)
+    n_events = max(event_count(o) for o in ops)
+    buckets: dict[int, tuple[tuple, list[int]]] = {}
     sort_idx: list[int] = []
-    dense_groups: dict[int, tuple[tuple, list[int]]] = {}
     p_max = max(p_req)
     for i, p in enumerate(p_req):
-        pg = _slot_bucket(p, p_max)
-        d = _dense_shape(srange, pg) or _dense_shape(srange, p)
-        if d is not None and engine == "auto":
-            dec = select_engine(srange, d[2], n_events,
-                                frontier=frontier,
-                                dense_slot_cap=dense_slot_cap,
-                                pallas=pallas)
-            if dec.family != "dense":
-                d = None
-        if d is None:
-            if engine == "dense":
-                raise _dense_caps_error(srange, p, key=i)
-            sort_idx.append(i)
+        d = (_dense_shape(srange, _slot_bucket(p, p_max))
+             or _dense_shape(srange, p))
+        if d is not None and engine == "auto" and select_engine(
+                srange, d[2], n_events, frontier=frontier,
+                dense_slot_cap=dense_slot_cap,
+                pallas=pallas).family != "dense":
+            d = None
+        if d is not None:
+            buckets.setdefault(d[2], (d, []))[1].append(i)
+        elif engine == "dense":
+            raise _dense_caps_error(srange, p, key=i)
         else:
-            if d[2] in dense_groups:
-                dense_groups[d[2]][1].append(i)
-            else:
-                dense_groups[d[2]] = (d, [i])
-    return dense_groups, sort_idx
+            sort_idx.append(i)
+    groups = [_Group(part, d, d[2])
+              for d, idx in (buckets[pg] for pg in sorted(buckets))
+              for part in _length_parts(idx, ops)]
+    if sort_idx:
+        groups.append(_Group(sort_idx, None, slots))
+    return groups
 
 
 SPLIT_ROWS = 1 << 15   # staged rows (lanes x E) a dispatch of its own costs
@@ -2313,304 +2349,233 @@ def analysis_tpu_batch(model, hists: list, frontier: int = 1024,
                        max_frontier: int = 65536,
                        dense_slot_cap: int | None = None,
                        pallas=None,
-                       max_recovery_retries: int | None = None,
-                       _pre: list | None = None,
-                       _dense=False,
-                       _preq: list | None = None) -> list[dict]:
-    """Recovery wrapper around _analysis_tpu_batch_once (which holds
-    the batching contract — see its docstring): a classified backend
-    fault re-runs the batch down the standard ladder, except the OOM
-    rung SPLITS the batch in half (halving the vmapped working set)
-    and recovers each half independently; the final rung decides each
-    history via _final_rung (host mirror under the size cap). Results
-    that went through the ladder carry a 'recovered' trail."""
-    kw = dict(frontier=frontier, slots=slots,
-              chunk_entries=chunk_entries, budget_s=budget_s,
-              cancel=cancel, engine=engine, max_frontier=max_frontier,
-              dense_slot_cap=dense_slot_cap, pallas=pallas,
-              _pre=_pre, _dense=_dense, _preq=_preq)
-    trail = _RecoveryTrail(max_recovery_retries)
-    while True:
-        try:
-            rs = _analysis_tpu_batch_once(model, hists, **kw)
-        except RuntimeError as e:
-            if not trail.absorb(e, "batch"):
-                return [_final_rung(model, h, trail, e,
-                                    budget_s=budget_s, cancel=cancel)
-                        for h in hists]
-            kind = trail.faults[-1]
-            if kind == FAULT_OOM and len(hists) > 1:
-                # split/retry: each half re-enters the wrapped entry
-                # with the full ladder (and half the device working
-                # set); their own recovery trails merge with this one
-                mid = len(hists) // 2
-                log.warning("batch: splitting %d histories into "
-                            "%d + %d after OOM", len(hists), mid,
-                            len(hists) - mid)
-
-                def sub(lo, hi):
-                    return analysis_tpu_batch(
-                        model, hists[lo:hi], frontier=frontier,
-                        slots=slots, chunk_entries=kw["chunk_entries"],
-                        budget_s=budget_s, cancel=cancel,
-                        engine=kw["engine"], max_frontier=max_frontier,
-                        dense_slot_cap=kw["dense_slot_cap"],
-                        pallas=kw["pallas"],
-                        max_recovery_retries=max_recovery_retries,
-                        _pre=_pre[lo:hi] if _pre is not None else None,
-                        _dense=_dense,
-                        _preq=_preq[lo:hi] if _preq is not None
-                        else None)
-
-                rs = sub(0, mid) + sub(mid, len(hists))
-                for r in rs:
-                    # merge this level's trail into each sub-result —
-                    # but never stamp 'recovered' on a half that fell
-                    # off its own ladder (degraded + recovered is a
-                    # contradiction; its fault list lives under
-                    # 'recovery-failed'), and keep sub-trail markers
-                    # like {'fallback': 'host'}
-                    if not isinstance(r, dict):
-                        continue
-                    if r.get("degraded"):
-                        rf = r.get("recovery-failed")
-                        if isinstance(rf, dict):
-                            rf["faults"] = list(trail.faults) \
-                                + list(rf.get("faults", []))
-                        continue
-                    inner = r.get("recovered")
-                    inner = dict(inner) if isinstance(inner, dict) \
-                        else {}
-                    faults = list(trail.faults) \
-                        + list(inner.get("faults", []))
-                    inner.update(faults=faults, retries=len(faults),
-                                 split=True)
-                    r["recovered"] = inner
-                return rs
-            _apply_recovery_rung(kind, kw)
-            continue
-        for r in rs:
-            trail.stamp(r)
-        return rs
-
-
-def _analysis_tpu_batch_once(model, hists: list, frontier: int = 1024,
-                             slots: int = 32, chunk_entries: int = 4096,
-                             budget_s: float | None = None,
-                             cancel=None, engine: str = "auto",
-                             max_frontier: int = 65536,
-                             dense_slot_cap: int | None = None,
-                             pallas=None,
-                             _pre: list | None = None,
-                             _dense=False,
-                             _preq: list | None = None,
-                             _staged: bool = False) -> list[dict]:
+                       max_recovery_retries: int | None = None
+                       ) -> list[dict]:
     """Check a batch of independent histories (e.g. per-key subhistories
-    from the independent workload) in vmapped device calls. Long batches
-    run as bounded-duration chunks with the vmapped frontier carried
-    between calls, polling budget_s / cancel like the scalar path —
-    a pathological key can no longer stall an independent batch
-    unboundedly. Undecided keys at the budget report 'unknown'.
-
-    Crashed ops are checked in rounds (see release_crashed): first each
-    may take effect only until the next of its kind is invoked, and only
-    a key that then fails, past its first deadline, is checked again
-    with twice as many of a kind open at once, until none has a
-    deadline. Every verdict is exact, and a key whose crashes took
+    from the independent workload) in vmapped device calls. Each history
+    is encoded once; then the batch is decided in rounds
+    (_decide_in_rounds): crashed ops first get release_crashed's
+    deadlines, and only a key that then fails past its first deadline is
+    checked again, with twice as many of a kind open at once, until none
+    has a deadline. Every verdict is exact, and a key whose crashes took
     effect soon or never pays no slot for each crash it ever saw.
 
-    Escalation is batched: every overflow-suspect key re-runs together
-    in one vmapped call at 4x the frontier (recursively), instead of
-    degrading to serial per-key searches; likewise culprit-op blame for
-    definite invalids runs as one vmapped unmerged pass.
+    Each round plans its dispatch groups once (_plan_groups) and checks
+    them in that order, each in one _check_group call under the
+    recovery ladder: a classified backend fault re-runs that group down
+    the standard ladder, except that the OOM rung SPLITS the group in
+    half (halving the vmapped working set) and recovers each half, and
+    moves a lone dense key to the sort family (_search under
+    dense_slot_cap 0, as the single-history path does); the final rung
+    decides each of the group's keys via _final_rung (host mirror under
+    the size cap). Results that went through the ladder carry a
+    'recovered' trail.
 
-    _pre: internal — pre-encoded OpArrays (one per history), passed by
-    the group-split recursion so each history is encoded exactly once.
-    _staged: internal — _pre is one round's histories with deadlines.
-    _dense: internal — the group's dense shape from _dispatch_groups
-    (False = derive it here), so bucketed groups share the bucket's
-    compiled kernel instead of re-deriving a data-dependent shape from
-    the group-local state range. _preq: internal — the group's
-    required_slots values, already scanned by the parent (the
-    group-local state range is deliberately NOT passed: recomputing it
-    over a narrower group can make a spilled sort group dense-eligible)."""
-    import jax
-    import jax.numpy as jnp
-
+    Escalation is batched: a group's overflow-suspect keys run again
+    together at 4x the frontier, up to max_frontier, instead of
+    degrading to serial per-key searches; culprit-op blame for definite
+    invalids runs as one vmapped unmerged pass. A sort-family key past
+    the batch's `slots` takes the single-history search (_search) with
+    the round's ops. budget_s and cancel are polled before each group
+    and between chunks, so a pathological key cannot stall the batch;
+    undecided keys at the budget report 'unknown'."""
     t0 = _time.monotonic()
+    section = _telemetry.section
 
-    def _remaining():
+    def remaining():
         if budget_s is None:
             return None
         return max(0.0, budget_s - (_time.monotonic() - t0))
 
+    def spent():
+        return remaining() == 0.0 or (cancel is not None and cancel())
+
+    def alone(hs, ops, i, F, trail, kw):
+        """Key i by itself on the single-history search (_search),
+        continuing `trail`; an invalid verdict gets its explanation."""
+        r, _ = _search(model, hs[i], ops[i], trail,
+                       dict(kw, frontier=F, slots=None,
+                            max_frontier=max_frontier, cancel=cancel,
+                            slot_overflow_fallback=True, engine=engine),
+                       remaining)
+        _explain(model, hs[i], r)
+        return r
+
+    def ladder(hs, ops, group, F, kw):
+        """_check_group under the recovery ladder; returns its
+        verdicts and overflow suspects."""
+        kw = dict(kw)
+        trail = _RecoveryTrail(max_recovery_retries)
+        while True:
+            try:
+                rs, suspects = _check_group(
+                    model, hs, ops, group, F, budget_s=remaining(),
+                    cancel=cancel, chunk_entries=kw["chunk_entries"],
+                    pallas=kw["pallas"])
+            except RuntimeError as e:
+                if not trail.absorb(e, "batch"):
+                    return {i: _final_rung(model, hs[i], trail, e,
+                                           budget_s=remaining(),
+                                           cancel=cancel)
+                            for i in group.keys}, []
+                kind = trail.faults[-1]
+                if kind == FAULT_OOM and len(group.keys) > 1:
+                    # each half gets the full ladder (and half the
+                    # device working set); its trail merges with this
+                    mid = len(group.keys) // 2
+                    log.warning("batch: splitting %d keys into %d + %d "
+                                "after OOM", len(group.keys), mid,
+                                len(group.keys) - mid)
+                    rs, suspects = {}, []
+                    for half in (group.keys[:mid], group.keys[mid:]):
+                        r2, s2 = ladder(hs, ops, group._replace(keys=half),
+                                        F, kw)
+                        rs.update(r2)
+                        suspects += s2
+                    for r in rs.values():
+                        # never stamp 'recovered' on a half that fell
+                        # off its own ladder (degraded + recovered is a
+                        # contradiction; its fault list lives under
+                        # 'recovery-failed'), and keep sub-trail markers
+                        # like {'fallback': 'host'}
+                        if r.get("degraded"):
+                            rf = r.get("recovery-failed")
+                            if isinstance(rf, dict):
+                                rf["faults"] = list(trail.faults) \
+                                    + list(rf.get("faults", []))
+                            continue
+                        inner = dict(r.get("recovered") or {})
+                        faults = list(trail.faults) \
+                            + list(inner.get("faults", []))
+                        inner.update(faults=faults, retries=len(faults),
+                                     split=True)
+                        r["recovered"] = inner
+                    return rs, suspects
+                _apply_recovery_rung(kind, kw)
+                if kind == FAULT_OOM and group.dense is not None \
+                        and engine == "auto":
+                    # one key cannot split: as on the single-history
+                    # path, the rung's dense_slot_cap 0 moves it to the
+                    # sort family
+                    (i,) = group.keys
+                    return {i: alone(hs, ops, i, F, trail, kw)}, []
+                continue
+            for r in rs.values():
+                trail.stamp(r)
+            return rs, suspects
+
+    def run_round(keys, ops):
+        hs = [hists[k] for k in keys]
+        ops = list(ops)
+        with section("wgl.encode"):
+            p_req = [required_slots(o) for o in ops]
+            groups = _plan_groups(model, ops, p_req, engine,
+                                  frontier=frontier,
+                                  dense_slot_cap=dense_slot_cap,
+                                  pallas=pallas, slots=slots)
+        results: list[dict | None] = [None] * len(ops)
+        kw = dict(chunk_entries=chunk_entries, pallas=pallas,
+                  dense_slot_cap=dense_slot_cap)
+        for g in groups:
+            if g.dense is None and not spent():
+                batched = []
+                for i in g.keys:
+                    # the sort family has no release step
+                    pending = _pending_forever(ops[i])
+                    if pending is not ops[i]:
+                        ops[i], p_req[i] = pending, required_slots(pending)
+                    if p_req[i] <= g.slots:
+                        batched.append(i)
+                        continue
+                    # this key alone exceeds the batch's slot budget: the
+                    # single-history search re-sizes (and host-falls-back
+                    # past 256), checking this round's ops once
+                    results[i] = alone(hs, ops, i, frontier,
+                                       _RecoveryTrail(max_recovery_retries),
+                                       kw)
+                g = g._replace(keys=batched)
+            todo, F = g.keys, frontier
+            while todo:
+                if spent():
+                    # budget gone: report the rest without dispatching
+                    # even one chunk for them
+                    for i in todo:
+                        results[i] = _unknown_result(
+                            ops[i], "batch budget exhausted/cancelled "
+                            "before this key's search started", t0)
+                    break
+                rs, todo = ladder(hs, ops, g._replace(keys=todo), F, kw)
+                for i, r in rs.items():
+                    results[i] = r
+                if todo and F >= max_frontier:
+                    for i in todo:
+                        results[i] = _unknown_result(
+                            ops[i], f"frontier overflowed at {F}; "
+                            f"escalation cap {max_frontier} reached — "
+                            "verdict unknown", t0)
+                    break
+                # invalid + overflow: the witness may have been dropped
+                F *= 4
+        return results
+
+    with section("wgl.encode"):
+        pre = [encode_ops_for_model(model, h) for h in hists]
+    results = _decide_in_rounds(pre, run_round,
+                                DEVICE_MODELS[model.device_model].droppable,
+                                deadlines=engine != "sort", site="batch")
+    dur = (_time.monotonic() - t0) * 1e3
+    for r in results:
+        r.setdefault("duration-ms", dur)
+    return results
+
+
+def _check_group(model, hists: list, ops: list, group: _Group,
+                 frontier: int, chunk_entries: int = 4096,
+                 budget_s: float | None = None, cancel=None,
+                 pallas=None) -> tuple[dict, list[int]]:
+    """Check one dispatch group's keys (`ops[i]`, the encoding of
+    `hists[i]`, for i in group.keys) in vmapped device calls: stage
+    their steps, run the pipelined chunk loop, summarize, and blame the
+    definite invalids in one pass. Long groups run as bounded-duration
+    chunks with the vmapped frontier carried between calls, polling
+    budget_s / cancel like the scalar path; keys still undecided report
+    'unknown'. Returns {key: result} and the keys whose frontier
+    overflowed on an invalid verdict, for the caller to check again at
+    a larger frontier."""
+    import jax
+    import jax.numpy as jnp
+
+    t0 = _time.monotonic()
     name = model.device_model
     section = _telemetry.section
-    if _preq is None and not _staged:
+    dense, slots = group.dense, group.slots
+    srange = None   # the dense kernel's shape carries it
+    if dense is None:
         with section("wgl.encode"):
-            pre = (_pre if _pre is not None
-                   else [encode_ops_for_model(model, h) for h in hists])
+            srange = _state_range(name, model,
+                                  [ops[i] for i in group.keys])
+    results: dict[int, dict] = {}
+    with section("wgl.steps"):
+        items = [(i, ops[i], build_steps(ops[i], slots))
+                 for i in group.keys]   # (key, ops, steps)
+        E = _bucket(max(max(event_count(o) for _, o, _ in items), 1))
+        padded = [st.pad_to(E) for _, _, st in items]
+        # bucket the batch axis like E: the vmapped kernels are jitted
+        # per (B, E) shape, so an exact B would recompile the whole
+        # family for every distinct key count — pad with zero-step
+        # entries (n=0: never consumed, frontier stays at the initial
+        # config), skipped by the per-item j < len(items) reads below
+        padded += [Steps.empty(padded[0].w, E)] * (
+            _bucket(len(padded), lo=1) - len(padded))
+        x_np = np.stack([st.x for st in padded])
+        ns = np.asarray([st.n for st in padded], np.int32)
 
-        def run(idx, ops):
-            return _analysis_tpu_batch_once(
-                model, [hists[i] for i in idx], frontier=frontier,
-                slots=slots, chunk_entries=chunk_entries,
-                budget_s=_remaining(), cancel=cancel, engine=engine,
-                max_frontier=max_frontier, dense_slot_cap=dense_slot_cap,
-                pallas=pallas, _pre=ops, _staged=True)
-
-        return _decide_in_rounds(  # type: ignore[return-value]
-            pre, run, DEVICE_MODELS[name].droppable,
-            deadlines=engine != "sort")
-    group_list = None
-    with section("wgl.encode"):
-        pre = (_pre if _pre is not None
-               else [encode_ops_for_model(model, h) for h in hists])
-        _srange = _p_needs = None   # pre-pass reuse, one-bucket case
-        if engine in ("auto", "dense") and len(hists) > 1 \
-                and _preq is None:
-            # Slot-bucketed dispatch groups (see _dispatch_groups):
-            # recurse per group — each group is then bucket-uniform and
-            # runs the plain batched path below. Dense groups run
-            # cheapest-first and the sort group last, so a pathological
-            # dense-ineligible key can only starve itself of budget,
-            # not the cheap keys.
-            p_req = [required_slots(ops) for ops in pre]
-            srange_all = _state_range(name, model, pre)
-            dense_groups, sort_idx = _dispatch_groups(
-                srange_all, p_req, engine,
-                n_events=max((event_count(o) for o in pre), default=1),
-                frontier=frontier, dense_slot_cap=dense_slot_cap,
-                pallas=pallas)
-            group_list = [(dense_groups[pg][0], part)
-                          for pg in sorted(dense_groups)
-                          for part in _length_parts(dense_groups[pg][1], pre)]
-            if sort_idx:
-                group_list.append((False, sort_idx))
-            if len(group_list) <= 1:
-                # one bucket: fall through to the plain path, reusing
-                # the pre-pass instead of rescanning every history
-                if group_list and group_list[0][0] is not False:
-                    _dense = group_list[0][0]
-                else:
-                    _srange, _p_needs = srange_all, dict(enumerate(p_req))
-                group_list = None
-        if group_list is None and pre:
-            if _dense is not False:
-                # the bucket's shape, shared group-wide; the group-local
-                # state range and slot needs would be dead recomputation
-                # (the dense kernel's shape carries both)
-                dense, srange, p_needs = _dense, None, None
-            else:
-                srange = (_srange if _srange is not None else
-                          _state_range(name, model, pre))
-                if _p_needs is not None:
-                    p_needs = _p_needs
-                elif _preq is not None:
-                    p_needs = dict(enumerate(_preq))
-                else:
-                    p_needs = {i: required_slots(o)
-                               for i, o in enumerate(pre)}
-                dense = None
-                if engine in ("auto", "dense"):
-                    # same contract as the scalar path and the multi-key
-                    # grouped split: a forced dense engine never
-                    # silently degrades to the sort kernel
-                    # (select_engine raises). Decided BEFORE the budget
-                    # early-exit below so the contract violation
-                    # surfaces identically for zero-budget calls.
-                    dense = select_engine(
-                        srange, max(p_needs.values()),
-                        max((event_count(o) for o in pre), default=1),
-                        frontier=frontier, engine=engine,
-                        dense_slot_cap=dense_slot_cap,
-                        pallas=pallas).dense
-            if dense is not None:
-                slots = dense[2]
-    if group_list is not None:
-        grouped: list[dict | None] = [None] * len(hists)
-        for d, idx in group_list:
-            rem = _remaining()
-            if (rem == 0.0) or (cancel is not None and cancel()):
-                # budget gone: report the remaining groups without
-                # dispatching even one chunk for them
-                for i in idx:
-                    grouped[i] = _unknown_result(
-                        pre[i], "batch budget exhausted/cancelled "
-                        "before this key's search started", t0)
-                continue
-            sub = analysis_tpu_batch(
-                model, [hists[i] for i in idx], frontier=frontier,
-                slots=slots, chunk_entries=chunk_entries,
-                budget_s=rem, cancel=cancel, engine=engine,
-                max_frontier=max_frontier,
-                dense_slot_cap=dense_slot_cap, pallas=pallas,
-                _pre=[pre[i] for i in idx], _dense=d,
-                _preq=[p_req[i] for i in idx])
-            for t, i in enumerate(idx):
-                grouped[i] = sub[t]
-        return grouped
-
-    results: list[dict | None] = [None] * len(hists)
-    encoded = list(enumerate(pre))
-    if encoded and ((_remaining() == 0.0)
-                    or (cancel is not None and cancel())):
-        # budget already gone: report unknown before the per-key
-        # scalar fallback below can dispatch full searches
-        for i, ops in encoded:
-            results[i] = _unknown_result(
-                ops, "batch budget exhausted/cancelled before "
-                "this key's search started", t0)
-        encoded = []
-    batched = []
-    for i, ops in encoded:
-        if dense is None:
-            # the sort family has no release step
-            pending = _pending_forever(ops)
-            if pending is not ops:
-                ops, p_needs[i] = pending, required_slots(pending)
-        if dense is None and p_needs[i] > slots:
-            # this key alone exceeds the batch's slot budget: the scalar
-            # path re-sizes (and host-falls-back past 256), checking this
-            # round's ops once, since this batch runs the rounds
-            results[i] = analysis_tpu(
-                model, hists[i], frontier, budget_s=_remaining(),
-                cancel=cancel, engine=engine,
-                dense_slot_cap=dense_slot_cap, pallas=pallas, _ops=ops)
-        else:
-            batched.append((i, ops))
-    if batched and ((_remaining() == 0.0)
-                    or (cancel is not None and cancel())):
-        # budget already gone: report unknown without dispatching even
-        # the first chunk (the chunk loop below always runs one)
-        for i, ops in batched:
-            results[i] = _unknown_result(
-                ops, "batch budget exhausted/cancelled before "
-                "this key's search started", t0)
-        batched = []
-    items = []           # (orig index, ops, steps)
-    if batched:
-        with section("wgl.steps"):
-            items = [(i, ops, build_steps(ops, slots))
-                     for i, ops in batched]
-            E = _bucket(max(max(event_count(ops) for _, ops, _ in items),
-                            1))
-            padded = [st.pad_to(E) for _, _, st in items]
-            # bucket the batch axis like E: the vmapped kernels are
-            # jitted per (B, E) shape, so an exact B would recompile
-            # the whole family for every distinct key count — pad with
-            # zero-step entries (n=0: never consumed, frontier stays at
-            # the initial config), skipped by the per-item
-            # j < len(items) reads below
-            padded += [Steps.empty(padded[0].w, E)] * (
-                _bucket(len(padded), lo=1) - len(padded))
-            x_np = np.stack([st.x for st in padded])
-            ns = np.asarray([st.n for st in padded], np.int32)
-
-    def dispatch():
-        """Run the batch as one vmapped group: stage, step, summarize,
-        and blame its definite invalids in one pass. Returns the
-        overflow suspects and whether attestation was on."""
+    family = "dense" if dense is not None else "sort"
+    _M_GROUPS.labels(family=family).inc()
+    # annotation only: the phases and chunk sites inside are timed on
+    # their own
+    with section("wgl.batch.group", P=slots, lanes=len(padded),
+                 keys=len(items), steps=int(ns.max())):
         with section("wgl.stage"):
             if dense is not None:
                 k = _dense_kernel(name, dense[0], dense[1], dense[2], E,
@@ -2636,9 +2601,7 @@ def _analysis_tpu_batch_once(model, hists: list, frontier: int = 1024,
         # the device computes — all-dead detection lags one chunk
         # (safe: dead frontiers stay dead) in exchange for overlapping
         # the per-chunk sync with compute
-        chunk_obs = _M_CHUNK.labels(
-            site="batch", family="dense" if dense is not None
-            else "sort")
+        chunk_obs = _M_CHUNK.labels(site="batch", family=family)
         while e < n_max:
             stop = min(e + chunk_entries, n_max)
             maybe_inject_fault("batch")
@@ -2664,7 +2627,6 @@ def _analysis_tpu_batch_once(model, hists: list, frontier: int = 1024,
                     break
         # every lane runs every step advanced, over the E rows staged for
         # it; a real key needs only its own steps
-        family = "dense" if dense is not None else "sort"
         _M_LANE_STEPS.labels(family=family, kind="staged").inc(
             len(padded) * E)
         _M_LANE_STEPS.labels(family=family, kind="dispatched").inc(
@@ -2718,7 +2680,7 @@ def _analysis_tpu_batch_once(model, hists: list, frontier: int = 1024,
                         "max-frontier": int(max_count[j]),
                         "configs": [], "final-paths": []}
                 elif bool(overflow[j]):
-                    suspects.append((i, ops))
+                    suspects.append(i)
                 else:
                     invalids.append((j, i, ops))
         if invalids:
@@ -2752,47 +2714,12 @@ def _analysis_tpu_batch_once(model, hists: list, frontier: int = 1024,
                             r["op"] = _find_op(hists[i], src)
                             r["op-index"] = src
                     results[i] = r
-        return suspects, attest_on
-
-    if items:
-        _M_GROUPS.labels(
-            family="dense" if dense is not None else "sort").inc()
-        # annotation only: the phases and chunk sites inside are timed
-        # on their own
-        with section("wgl.batch.group", P=slots, lanes=len(padded),
-                     keys=len(items), steps=int(ns.max())):
-            suspects, attest_on = dispatch()
-        if suspects:
-            if frontier < max_frontier:
-                # this round's ops again (only the sort family
-                # overflows), so the escalation runs no rounds of its own
-                sub = analysis_tpu_batch(
-                    model, [hists[i] for i, _ in suspects],
-                    frontier=frontier * 4, slots=slots,
-                    chunk_entries=chunk_entries, budget_s=_remaining(),
-                    cancel=cancel, engine=engine,
-                    max_frontier=max_frontier,
-                    dense_slot_cap=dense_slot_cap, pallas=pallas,
-                    _pre=[ops for _, ops in suspects],
-                    _preq=[p_needs[i] for i, _ in suspects])
-                for t, (i, _ops) in enumerate(suspects):
-                    results[i] = sub[t]
-            else:
-                for i, ops in suspects:
-                    results[i] = _unknown_result(
-                        ops, f"frontier overflowed at {frontier}; "
-                        f"escalation cap {max_frontier} reached — "
-                        "verdict unknown", t0)
-        if attest_on:
-            for i, _ops, _st in items:
-                r = results[i]
-                if isinstance(r, dict):
-                    r.setdefault("attested", {"steps": 1, "carry": 1})
     dur = (_time.monotonic() - t0) * 1e3
-    for r in results:
-        if r is not None:
-            r.setdefault("duration-ms", dur)
-    return results  # type: ignore[return-value]
+    for i, r in results.items():
+        if attest_on:
+            r.setdefault("attested", {"steps": 1, "carry": 1})
+        r.setdefault("duration-ms", dur)
+    return results, suspects
 
 
 def _sharded_runner(name, dense, frontier, slots, srange, E, mesh, axis,
@@ -3024,26 +2951,23 @@ def _check_batch_sharded_once(model, hists: list, mesh=None,
     srange = _state_range(name, model, all_ops)
     p_req = [required_slots(ops) for ops in all_ops]
 
-    # Slot-bucketed dispatch groups (see _dispatch_groups): on the
+    # analysis_tpu_batch's dispatch groups (see _plan_groups): on the
     # hazelcast bench shape (100 keys, ~2.5 crashes/key) the max-padded
     # table sums to 14x the per-key need; grouping recovers it for a
     # couple of extra sub-ms dispatches.
-    dense_groups, sort_idx = _dispatch_groups(
-        srange, p_req, engine,
-        n_events=max((event_count(o) for o in all_ops), default=1),
-        frontier=frontier, dense_slot_cap=dense_slot_cap, pallas=pallas)
+    groups = _plan_groups(model, all_ops, p_req, engine,
+                          frontier=frontier, dense_slot_cap=dense_slot_cap,
+                          pallas=pallas, slots=slots, srange=srange)
     group_info: list[dict] = []
 
-    def run_group(idx: list[int], dense):
+    def run_group(idx: list[int], dense, g_slots: int):
         """One vmapped + mesh-sharded dispatch over the keys in idx."""
-        if dense is not None:
-            g_slots = dense[2]
-        else:
+        if dense is None:
             # the sort group sizes itself to its own keys — never below
             # the caller's slots, never a SlotOverflow on a key the
             # dense caps rejected
-            g_slots = max(slots, _bucket(max(p_req[i] for i in idx),
-                                         lo=8))
+            g_slots = max(g_slots, _bucket(max(p_req[i] for i in idx),
+                                           lo=8))
         steps_list = [build_steps(all_ops[i], g_slots) for i in idx]
         E = _bucket(max(max(st.n for st in steps_list), 1))
         w = steps_list[0].w
@@ -3090,11 +3014,7 @@ def _check_batch_sharded_once(model, hists: list, mesh=None,
         return all_ok_g, ok_g, ov_g, att_g, att
 
     attest_on = attest_enabled()
-    pending = [(idx, run_group(idx, d))
-               for d, idx in (dense_groups[pg]
-                              for pg in sorted(dense_groups))]
-    if sort_idx:
-        pending.append((sort_idx, run_group(sort_idx, None)))
+    pending = [(g.keys, run_group(*g)) for g in groups]
     per_key = np.zeros(k, bool)
     overflow = np.zeros(k, bool)
     all_ok = True

@@ -2,11 +2,13 @@
 (reference `jepsen/src/jepsen/generator/interpreter.clj`).
 
 One worker thread per logical thread (concurrency clients + the nemesis),
-each fed through a 1-slot queue; a single-threaded scheduler loop owns the
+each fed through its own inbox; a single-threaded scheduler loop owns the
 context and the generator, polls completions at microsecond granularity
 (`max-pending-interval` 1000 us, `interpreter.clj:166-170`), asks the
 generator for ops, dispatches them, and journals invocations and
-completions into the history.
+completions into the history. A thread is only handed an op while it
+is free, so an inbox holds at most one op (and, at shutdown, the exit
+sentinel).
 
 Worker behavior (`interpreter.clj:99-164`):
   * any Throwable from a client invoke becomes an :info op (the op is
@@ -159,9 +161,10 @@ class _WorkerThread:
     scheduler can tell a live worker's answer from the late answer of
     an abandoned (timed-out) one and discard the latter."""
 
-    def __init__(self, test: dict, out: queue.Queue, worker: Worker, wid):
+    def __init__(self, test: dict, out: queue.SimpleQueue, worker: Worker,
+                 wid):
         self.id = wid
-        self.inbox: queue.Queue = queue.Queue(1)
+        self.inbox: queue.SimpleQueue = queue.SimpleQueue()
         self.test = test
         self.out = out
         self.worker = worker
@@ -224,7 +227,7 @@ def run(test: dict) -> History:
     and in-flight ops are bounded by 'op-timeout' / per-op 'deadline'
     so a wedged client can't hang the run — see the module docstring."""
     ctx = context(test)
-    completions: queue.Queue = queue.Queue()
+    completions: queue.SimpleQueue = queue.SimpleQueue()
     workers = {t: _WorkerThread(test, completions, ClientNemesisWorker(), t)
                for t in ctx.workers}
     gen = validate(friendly_exceptions(test.get("generator")))
@@ -339,17 +342,14 @@ def run(test: dict) -> History:
                         # replacement answers :info without touching it
                         old = workers[thread]
                         old.abandoned = True
-                        # displace any undelivered op first (the worker
-                        # may have wedged before dequeuing it) so the
-                        # exit sentinel always lands and close() runs
+                        # drop any op it never took (the worker may
+                        # have wedged before dequeuing it) so the exit
+                        # sentinel comes next and close() runs
                         try:
                             old.inbox.get_nowait()
                         except queue.Empty:
                             pass
-                        try:
-                            old.inbox.put_nowait({"type": "exit"})
-                        except queue.Full:
-                            pass
+                        old.inbox.put({"type": "exit"})
                         replacement = (RetiredNemesisWorker()
                                        if thread == NEMESIS
                                        else ClientNemesisWorker())
@@ -404,16 +404,13 @@ def run(test: dict) -> History:
     except BaseException:
         LOG.info("shutting down workers after abnormal exit")
         for w in workers.values():
-            # the 1-slot inbox may still hold an undelivered op; displace
-            # it so the exit sentinel always lands
+            # the inbox may still hold an op the worker never took;
+            # drop it so the worker exits without running it
             try:
                 w.inbox.get_nowait()
             except queue.Empty:
                 pass
-            try:
-                w.inbox.put_nowait({"type": "exit"})
-            except queue.Full:
-                pass
+            w.inbox.put({"type": "exit"})
         for w in workers.values():
             w.thread.join(timeout=5)
         raise

@@ -6,6 +6,7 @@ Thresholds sit well under the measured numbers (~31k pure-generator,
 ~15k full interpreter on an unloaded box) so a loaded CI box doesn't
 flake, while a 3x regression still fails."""
 
+import os
 import random
 import time
 
@@ -41,8 +42,14 @@ def test_pure_generator_throughput():
 
 @pytest.mark.perf
 def test_interpreter_throughput(tmp_path):
-    """Full round-trip: scheduler + worker threads + 1-slot queues +
-    atom client + history journaling."""
+    """Full round-trip: scheduler + worker threads + per-thread inboxes +
+    atom client + history journaling.
+
+    The run is held to one CPU. Every op hands the interpreter lock
+    between the scheduler and a worker thread; on a virtual machine a
+    thread asleep on another vCPU can take longer to wake than the op
+    takes, and that wait measures the machine's load, not this code
+    (on an 8-vCPU VM the run went 2-3x faster held to one CPU)."""
     state = testkit.AtomState()
     n = 20_000
     t = testkit.noop_test()
@@ -54,9 +61,14 @@ def test_interpreter_throughput(tmp_path):
         "generator": _mixed_gen(n),
         "checker": jepsen_tpu.checker.unbridled_optimism(),
     })
-    t0 = time.monotonic()
-    done = core.run(t)
-    rate = n / (time.monotonic() - t0)
+    cpus = sorted(os.sched_getaffinity(0))
+    os.sched_setaffinity(0, {cpus[os.getpid() % len(cpus)]})
+    try:
+        t0 = time.monotonic()
+        done = core.run(t)
+        rate = n / (time.monotonic() - t0)
+    finally:
+        os.sched_setaffinity(0, cpus)
     invokes = sum(1 for o in done["history"]
                   if o.get("type") == "invoke")
     assert invokes == n

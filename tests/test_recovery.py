@@ -21,7 +21,7 @@ import numpy as np
 import pytest
 
 import jepsen_tpu._platform as plat
-from jepsen_tpu import models
+from jepsen_tpu import models, telemetry
 from jepsen_tpu.checker import (Checker, Compose, UNKNOWN, check_safe,
                                 linear, streaming, synth, wgl)
 import jepsen_tpu.control.retry as retry
@@ -249,6 +249,77 @@ def test_batch_fault_recovers_with_identical_verdicts(
     assert [r["valid?"] for r in rs] == batch_baseline
     assert any(r.get("recovered") for r in rs)
     assert not any(r.get("degraded") for r in rs)
+
+
+def _batch_deadline_checks():
+    snap = telemetry.snapshot(prefix="jepsen_tpu_wgl_deadline_checks")
+    return sum(v for labels, v in snap.get(
+        "jepsen_tpu_wgl_deadline_checks_total", {}).items()
+        if "site=batch" in labels)
+
+
+@pytest.mark.parametrize("kind", plat.FAULT_KINDS)
+def test_a_single_group_batch_fault_retries_only_its_group(
+        kind, batch_baseline, monkeypatch):
+    """A fault inside a batch that plans to one dispatch group retries
+    that group alone: every history is encoded once and checked in one
+    round, and the verdicts are the uninterrupted run's."""
+    hists = _batch_hists()[1:]   # slot needs 5, 5, 5: one bucket
+    ops = [wgl.release_crashed(wgl.encode_ops_for_model(MODEL, h), 1)[0]
+           for h in hists]
+    assert len(wgl._plan_groups(MODEL, ops, [wgl.required_slots(o)
+                                             for o in ops], "auto")) == 1
+    encoded = []
+    real = wgl.encode_ops_for_model
+
+    def spy(model, hist):
+        encoded.append(hist)
+        return real(model, hist)
+
+    monkeypatch.setattr(wgl, "encode_ops_for_model", spy)
+    before = _batch_deadline_checks()
+    plat.fault_hook = _one_shot(kind, "batch")
+    rs = wgl.analysis_tpu_batch(MODEL, hists)
+    assert [r["valid?"] for r in rs] == batch_baseline[1:]
+    assert all(r["recovered"]["faults"] == [kind] for r in rs)
+    assert not any(r.get("degraded") for r in rs)
+    assert len(encoded) == len(hists)
+    assert _batch_deadline_checks() - before == len(hists)
+
+
+def _oom_at(*dispatches):
+    """fault_hook raising an OOM at the given batch dispatches."""
+    state = {"n": 0}
+
+    def hook(s):
+        if s == "batch":
+            state["n"] += 1
+            if state["n"] in dispatches:
+                raise plat.InjectedFault(plat.FAULT_OOM, s, state["n"])
+    return hook
+
+
+@pytest.mark.parametrize("seeds,dispatches", [
+    ((10,), (1,)),            # a one-key batch
+    ((11, 12), (1, 2)),       # the first half of a split group OOMs again
+])
+def test_a_lone_dense_key_that_ooms_moves_to_the_sort_family(
+        seeds, dispatches):
+    """An OOM on a dense group of one key, which cannot split, retries
+    that key on the sort family (dense_slot_cap 0), with the verdict of
+    the uninterrupted run; the other keys keep the dense kernel."""
+    hists = [_hist(seed=s, n=120, conc=3) for s in seeds]
+    r0 = wgl.analysis_tpu_batch(MODEL, hists)
+    assert all(r["dedup"] == "dense-table" for r in r0)
+    plat.fault_hook = _oom_at(*dispatches)
+    rs = wgl.analysis_tpu_batch(MODEL, hists)
+    assert [r["valid?"] for r in rs] == [r["valid?"] for r in r0]
+    assert not any(r.get("degraded") for r in rs)
+    lone, *rest = rs
+    assert lone["dedup"] == "xla-sort"
+    assert "dense_slot_cap=0" in lone["engine-reason"]
+    assert lone["recovered"]["faults"] == [plat.FAULT_OOM] * len(dispatches)
+    assert all(r["dedup"] == "dense-table" for r in rest)
 
 
 @pytest.fixture(scope="module")

@@ -333,6 +333,26 @@ def test_load_test_salvages_from_journal_without_test_json(tmp_path):
     assert [o["f"] for o in h.pending()] == ["write"]
 
 
+@pytest.mark.parametrize("n", [0, 1, 2 * 4096 + 3])
+def test_history_file_is_one_json_line_per_op(tmp_path, n):
+    """history.jsonl.gz holds exactly the lines json.dumps gives each op
+    (values JSON lacks converted as everywhere in the store), and reads
+    back as the same ops."""
+    import gzip
+    ops = [{"index": i, "type": "ok" if i % 2 else "invoke",
+            "process": i % 7, "f": "cas", "value": (i % 5, i % 3),
+            "error": {"set": {i}} if i % 11 == 0 else None}
+           for i in range(n)]
+    test = {"name": "h", "start-time": "t", "store-dir": str(tmp_path)}
+    p = store.write_history(test, ops)
+    with gzip.open(p, "rt") as fh:
+        assert fh.read() == "".join(
+            json.dumps(op, default=store._json_default) + "\n"
+            for op in ops)
+    back = store.read_history(p)
+    assert [o["value"] for o in back] == [list(o["value"]) for o in ops]
+
+
 def test_journal_flushes_info_ops_immediately(tmp_path):
     p = str(tmp_path / "j.jsonl")
     j = store.Journal(p, flush_interval_s=3600)

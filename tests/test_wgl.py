@@ -208,6 +208,26 @@ def test_sharded_mixed_slot_buckets():
     assert not all_ok and not per_key[-1] and per_key[:-1].all()
 
 
+def test_sharded_dense_groups_are_the_batch_plan():
+    """check_batch_sharded dispatches the dense groups _plan_groups
+    plans, the length split included: sixteen short keys and one long
+    one of the same slot bucket run as two groups."""
+    hists = [synth.register_history(1200 if i == 0 else 40, concurrency=3,
+                                    crash_rate=0.0, seed=300 + i)
+             for i in range(17)]
+    ops = [encode_ops_for_model(m.cas_register(), h) for h in hists]
+    plan = wgl._plan_groups(m.cas_register(), ops,
+                            [wgl.required_slots(o) for o in ops], "auto",
+                            frontier=128, slots=16)
+    dense = [(len(g.keys), g.slots) for g in plan if g.dense is not None]
+    assert len(dense) == 2
+    all_ok, per_key, info = check_batch_sharded(
+        m.cas_register(), hists, frontier=128, slots=16, return_info=True)
+    assert all_ok and per_key.all()
+    assert dense == [(g["keys"], g["slots"]) for g in info["groups"]
+                     if g["family"] == "dense"]
+
+
 def test_sharded_forced_sort_sizes_own_slots():
     # a key needing more slots than the caller passed must not blow up
     hists = [synth.register_history(40, concurrency=7, seed=s)
@@ -607,8 +627,7 @@ def test_forced_dense_engine_error_still_surfaces():
     with pytest.raises(ValueError, match="dense"):
         analysis_tpu(m.cas_register(), History(big), engine="dense")
     # the batch path honors the same contract for single- and multi-key
-    # batches (single-key skips the grouped split; multi-key raises
-    # inside _dispatch_groups)
+    # batches (both raise inside _plan_groups)
     with pytest.raises(ValueError, match="dense"):
         analysis_tpu_batch(m.cas_register(), [History(big)],
                            engine="dense")

@@ -1,9 +1,9 @@
 """Deadlines for crashed ops (wgl.release_crashed), checked in rounds by
 both the batch path and the single-history path (analysis_tpu), and
-the batch's split of a slot bucket by key length (wgl._length_parts):
-every verdict stays the host oracle's, a history is checked again only
-when a deadline may have cost it its verdict, and only skewed lengths
-split a group."""
+the batch plan's split of a slot bucket by key length
+(wgl._length_parts, applied by wgl._plan_groups): every verdict stays
+the host oracle's, a history is checked again only when a deadline may
+have cost it its verdict, and only skewed lengths split a group."""
 
 import random
 
@@ -328,16 +328,16 @@ def test_a_crashed_10k_shaped_history_runs_dense_at_p_10_or_less(
 
 def test_the_batch_scalar_fallback_checks_a_round_once(monkeypatch):
     """A key past the batch's slot budget on the sort family goes to the
-    scalar path with the round's ops: one search a round, no rounds of
-    its own, and the key's exact answer."""
+    single-history search with the round's ops: one search a round, no
+    rounds of its own, and the key's exact answer."""
     calls = []
-    real = wgl._analysis_tpu_once
+    real = wgl._search
 
-    def spy(model, hist, ops, **kw):
+    def spy(model, hist, ops, *a, **kw):
         calls.append(len(ops))
-        return real(model, hist, ops, **kw)
+        return real(model, hist, ops, *a, **kw)
 
-    monkeypatch.setattr(wgl, "_analysis_tpu_once", spy)
+    monkeypatch.setattr(wgl, "_search", spy)
     for key, valid, rounds in ((_two_after_a_write(2), True, 1),
                                (_read_past_a_deadline(), False, 2)):
         calls.clear()
@@ -383,13 +383,13 @@ def test_a_batch_frontier_escalation_runs_no_rounds_of_its_own(monkeypatch):
     the frontier with the round's ops, not as new histories with rounds
     of their own: one deadline check a key, and the host's verdicts."""
     frontiers = []
-    real = wgl._analysis_tpu_batch_once
+    real = wgl._check_group
 
-    def spy(model, hists, **kw):
-        frontiers.append(kw["frontier"])
-        return real(model, hists, **kw)
+    def spy(model, hists, ops, group, frontier, **kw):
+        frontiers.append(frontier)
+        return real(model, hists, ops, group, frontier, **kw)
 
-    monkeypatch.setattr(wgl, "_analysis_tpu_batch_once", spy)
+    monkeypatch.setattr(wgl, "_check_group", spy)
     hists = [_crashy(3), _crashy(5)]
     before = _deadline_checks()
     rs = wgl.analysis_tpu_batch(m.cas_register(), hists, frontier=2,
