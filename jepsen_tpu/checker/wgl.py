@@ -130,11 +130,6 @@ _M_DEADLINE_CHECKS = _telemetry.counter(
     "went another round)",
     ("site", "outcome"))
 
-# Event kinds (host-side stream construction)
-E_INVOKE = 0
-E_RETURN = 1
-E_RELEASE = 2
-
 # A crashed op given a deadline by release_crashed: it may take effect
 # only before its ret, where its slot is released (OpArray.kind)
 KIND_RELEASED = 2
@@ -427,6 +422,9 @@ class Steps:
     ret_row  int32[T] — op row of the step's sole completion (-1 if
              none, or ambiguous because several were merged)
     inv_row  int32[T] — op row of the step's invoke (-1 if none)
+
+    A batch's stack (build_steps_batch) has a leading key axis on x,
+    ret_row and inv_row, and n is int32[B].
     """
     x: np.ndarray        # (T, W+4) int32
     ret_row: np.ndarray
@@ -447,12 +445,6 @@ class Steps:
                      np.concatenate([self.ret_row, neg]),
                      np.concatenate([self.inv_row, neg]), self.w, self.n)
 
-    @classmethod
-    def empty(cls, w: int, t: int = 0) -> "Steps":
-        z = np.zeros((0, w + 4), np.int32)
-        zn = np.zeros(0, np.int32)
-        return cls(z, zn, zn, w, 0).pad_to(t)
-
 
 def event_count(ops: OpArray) -> int:
     """Length of the unmerged event stream (invokes + ok returns +
@@ -461,26 +453,59 @@ def event_count(ops: OpArray) -> int:
     return len(ops) + int((np.asarray(ops.kind) != KIND_INFO).sum())
 
 
+def _sorted_events(ops_list: list) -> tuple:
+    """Every key's events in the order the kernels step them: by key,
+    then position, a release before an invoke before a return at one
+    position, then op row. Returns each event's key, order (-1 release,
+    0 invoke, 1 return) and row, rows counted over the keys' ops laid
+    end to end, with the first such row of each key (and the total)."""
+    lens = np.fromiter(map(len, ops_list), np.int64, len(ops_list))
+    row0 = np.zeros(len(lens) + 1, np.int64)
+    np.cumsum(lens, out=row0[1:])
+    kind, inv, ret = (_concat(ops_list, c) for c in ("kind", "inv", "ret"))
+    ok = kind == KIND_OK
+    done = np.flatnonzero(ok | (kind == KIND_RELEASED))
+    # invokes in row order, then completions in row order: a stable sort
+    # keeps tied (key, position, order) events in row order
+    row = np.concatenate([np.arange(len(kind)), done])
+    pos = np.concatenate([inv, ret[done]]).astype(np.int64)
+    order = np.concatenate([np.zeros(len(kind), np.int64),
+                            np.where(ok[done], 1, -1)])
+    key = np.repeat(np.arange(len(lens)), lens)[row]
+    if len(pos):
+        pos -= pos.min()
+        span = int(pos.max()) + 1
+        idx = np.argsort((key * span + pos) * 3 + order + 1, kind="stable")
+        key, order, row = key[idx], order[idx], row[idx]
+    return key, order, row, row0
+
+
+def _concat(ops_list: list, field: str) -> np.ndarray:
+    return np.concatenate([np.asarray(getattr(o, field)) for o in ops_list]
+                          or [np.zeros(0, np.int32)])
+
+
+def required_slots_batch(ops_list: list) -> list[int]:
+    """required_slots of each OpArray in `ops_list`, in one pass: the
+    running count of pending ops over the sorted events, its peak taken
+    key by key."""
+    key, order, _, _ = _sorted_events(ops_list)
+    out = np.ones(len(ops_list), np.int64)
+    if len(key):
+        level = np.cumsum(np.where(order == 0, 1, -1))
+        first = np.flatnonzero(np.r_[True, key[1:] != key[:-1]])
+        base = np.where(first > 0, level[first - 1], 0)
+        out[key[first]] = np.maximum(
+            np.maximum.reduceat(level, first) - base, 1)
+    return out.tolist()
+
+
 def required_slots(ops: OpArray) -> int:
     """The peak number of simultaneously-pending ops (crashed ops pend
     forever, or to their release_crashed deadline) — the minimum slot
     count the kernel needs. Computing it up front avoids SlotOverflow
     escalation recompiles."""
-    # same (position, order) tie-break as build_steps: releases sort
-    # before invokes, invokes before returns at equal positions
-    events = []
-    for r in range(len(ops)):
-        events.append((int(ops.inv[r]), 0, 1))
-        if ops.kind[r] == KIND_OK:
-            events.append((int(ops.ret[r]), 1, -1))
-        elif ops.kind[r] == KIND_RELEASED:
-            events.append((int(ops.ret[r]), -1, -1))
-    events.sort()
-    cur = peak = 0
-    for _, _, d in events:
-        cur += d
-        peak = max(peak, cur)
-    return max(peak, 1)
+    return required_slots_batch([ops])[0]
 
 
 def release_crashed(ops: OpArray, m: int) -> tuple[OpArray, bool]:
@@ -609,69 +634,86 @@ def build_steps(ops: OpArray, p: int, merge: bool = True) -> Steps:
     """Lower an OpArray to packed event steps, assigning each op a slot
     in [0, p). Raises SlotOverflow if concurrency + crashed ops exceed
     p."""
-    events = []  # (position, order, kind, row)
-    for r in range(len(ops)):
-        events.append((int(ops.inv[r]), 0, E_INVOKE, r))
-        if ops.kind[r] == KIND_OK:
-            events.append((int(ops.ret[r]), 1, E_RETURN, r))
-        elif ops.kind[r] == KIND_RELEASED:
-            events.append((int(ops.ret[r]), -1, E_RELEASE, r))
-    events.sort()
+    st = build_steps_batch([ops], p, merge)
+    n = int(st.n[0])
+    return Steps(st.x[0, :n], st.ret_row[0, :n], st.inv_row[0, :n], st.w, n)
+
+
+def build_steps_batch(ops_list: list, p: int, merge: bool = True,
+                      t: int | None = None, lanes: int | None = None
+                      ) -> Steps:
+    """Lower every OpArray of `ops_list` to packed event steps at once,
+    straight into one (lanes, t, W+4) stack: key i's steps fill lane i
+    from row 0, and the rest of every lane, and every lane past the
+    keys, is padding (no completion, no invoke; n 0). `t`, the rows a
+    lane, defaults to the _bucket of the longest key's step count;
+    `lanes` defaults to one a key.
+
+    Each op takes the lowest free slot at its invoke and frees it at its
+    return or release (a crashed op never does); SlotOverflow names the
+    first invoke that finds none of p free. Steps end at every invoke
+    and release; merged (merge=True), a run of returns rides in the
+    step its next invoke ends, and gets a completion-only step of its
+    own before a release or at the key's end."""
+    key, order, row, row0 = _sorted_events(ops_list)
+    slot = _assign_slots(key, order, row, row0, p)
     w = max(1, (p + 31) // 32)
-    free = list(range(p))
-    heapq.heapify(free)
-    slot_of_row: dict[int, int] = {}
-    masks: list[list[int]] = []
-    rest: list[tuple[int, int, int, int]] = []
-    ret_row: list[int] = []
-    inv_row: list[int] = []
-    pend = [0] * w
-    pend_rows: list[int] = []
+    is_inv, is_ret = order == 0, order == 1
+    ends = np.ones(len(key), bool)
+    if merge and len(key):
+        ends[:-1] = ~is_ret[:-1] | (order[1:] == -1) | (key[1:] != key[:-1])
+    step = np.cumsum(ends) - ends
+    b = len(ops_list) if lanes is None else lanes
+    ns = np.bincount(key[ends], minlength=b)
+    n_max = int(ns.max(initial=0))
+    if t is None:
+        t = _bucket(max(n_max, 1))
+    assert n_max <= t, "cannot shrink steps"
+    at = key * t + step - (np.cumsum(ns) - ns)[key]   # flat row of each event
+    slot = slot[row]
+    done = ~is_inv
+    masks = np.bincount(at[done] * w + (slot[done] >> 5),
+                        weights=np.left_shift(1, slot[done] & 31),
+                        minlength=b * t * w)   # a step's bits are distinct
+    x = np.empty((b * t, w + 4), np.int32)
+    x[:, :w] = masks.astype(np.uint32).view(np.int32).reshape(b * t, w)
+    x[:, w:] = (-1, 0, NIL, NIL)   # no invoke
+    local = row - row0[key]
+    ri, ai = row[is_inv], at[is_inv]
+    x[ai, w] = slot[is_inv]
+    for c, field in enumerate(("f", "a", "b"), w + 1):
+        x[ai, c] = _concat(ops_list, field)[ri]
+    x[at[order == -1], w + 1] = F_RELEASE
+    inv_row = np.full(b * t, -1, np.int32)
+    inv_row[ai] = local[is_inv]
+    ret_row = np.full(b * t, -1, np.int32)
+    ret_row[at[is_ret]] = local[is_ret]
+    ret_row[np.bincount(at[is_ret], minlength=b * t) > 1] = -1
+    return Steps(x.reshape(b, t, w + 4), ret_row.reshape(b, t),
+                 inv_row.reshape(b, t), w, ns.astype(np.int32))
 
-    def flush(inv_slot: int, f: int, a: int, b: int, row: int) -> None:
-        nonlocal pend, pend_rows
-        masks.append(pend)
-        rest.append((inv_slot, f, a, b))
-        ret_row.append(pend_rows[0] if len(pend_rows) == 1 else -1)
-        inv_row.append(row)
-        pend = [0] * w
-        pend_rows = []
 
-    for _, _, k, r in events:
-        if k == E_INVOKE:
-            if not free:
+def _assign_slots(key, order, row, row0, p: int) -> np.ndarray:
+    """Each row's slot: the lowest free one at its invoke, freed at its
+    return or release, over each key's sorted events in turn."""
+    slot = [0] * int(row0[-1])
+    bounds = np.searchsorted(key, np.arange(len(row0))).tolist()
+    invokes, rows = (order == 0).tolist(), row.tolist()
+    pop, push = heapq.heappop, heapq.heappush
+    for k in range(len(row0) - 1):
+        lo, hi = bounds[k], bounds[k + 1]
+        free = list(range(p))   # a sorted list is a heap
+        for is_inv, r in zip(invokes[lo:hi], rows[lo:hi]):
+            if not is_inv:
+                push(free, slot[r])
+            elif free:
+                slot[r] = pop(free)
+            else:
                 raise SlotOverflow(
-                    f"more than {p} pending ops at op row {r} "
-                    f"(crashed ops hold slots forever); raise p or check "
-                    f"on the host")
-            s = heapq.heappop(free)
-            slot_of_row[r] = s
-            flush(s, int(ops.f[r]), int(ops.a[r]), int(ops.b[r]), r)
-        elif k == E_RELEASE:
-            # its own step: completions before it complete, this slot
-            # only lets go
-            if pend_rows:
-                flush(-1, 0, NIL, NIL, -1)
-            s = slot_of_row.pop(r)
-            heapq.heappush(free, s)
-            pend[s // 32] |= 1 << (s % 32)
-            flush(-1, F_RELEASE, NIL, NIL, -1)
-        else:
-            s = slot_of_row.pop(r)
-            heapq.heappush(free, s)
-            pend[s // 32] |= 1 << (s % 32)
-            pend_rows.append(r)
-            if not merge:
-                flush(-1, 0, NIL, NIL, -1)
-    if any(pend):
-        flush(-1, 0, NIL, NIL, -1)
-    n = len(masks)
-    mask_arr = np.asarray(masks, np.uint32).reshape(n, w)
-    rest_arr = np.asarray(rest, np.int32).reshape(n, 4)
-    return Steps(np.concatenate([mask_arr.view(np.int32), rest_arr],
-                                axis=1),
-                 np.asarray(ret_row, np.int32),
-                 np.asarray(inv_row, np.int32), w, n)
+                    f"more than {p} pending ops at op row "
+                    f"{r - int(row0[k])} (crashed ops hold slots forever); "
+                    f"raise p or check on the host")
+    return np.asarray(slot, np.int64)
 
 
 def _bucket(n: int, lo: int = 64) -> int:
@@ -2468,7 +2510,7 @@ def analysis_tpu_batch(model, hists: list, frontier: int = 1024,
         hs = [hists[k] for k in keys]
         ops = list(ops)
         with section("wgl.encode"):
-            p_req = [required_slots(o) for o in ops]
+            p_req = required_slots_batch(ops)
             groups = _plan_groups(model, ops, p_req, engine,
                                   frontier=frontier,
                                   dense_slot_cap=dense_slot_cap,
@@ -2556,25 +2598,23 @@ def _check_group(model, hists: list, ops: list, group: _Group,
                                   [ops[i] for i in group.keys])
     results: dict[int, dict] = {}
     with section("wgl.steps"):
-        items = [(i, ops[i], build_steps(ops[i], slots))
-                 for i in group.keys]   # (key, ops, steps)
-        E = _bucket(max(max(event_count(o) for _, o, _ in items), 1))
-        padded = [st.pad_to(E) for _, _, st in items]
+        items = [(i, ops[i]) for i in group.keys]
+        E = _bucket(max(max(event_count(o) for _, o in items), 1))
         # bucket the batch axis like E: the vmapped kernels are jitted
         # per (B, E) shape, so an exact B would recompile the whole
         # family for every distinct key count — pad with zero-step
-        # entries (n=0: never consumed, frontier stays at the initial
+        # lanes (n=0: never consumed, frontier stays at the initial
         # config), skipped by the per-item j < len(items) reads below
-        padded += [Steps.empty(padded[0].w, E)] * (
-            _bucket(len(padded), lo=1) - len(padded))
-        x_np = np.stack([st.x for st in padded])
-        ns = np.asarray([st.n for st in padded], np.int32)
+        lanes = _bucket(len(items), lo=1)
+        stack = build_steps_batch([o for _, o in items], slots, t=E,
+                                  lanes=lanes)
+        x_np, ns = stack.x, stack.n
 
     family = "dense" if dense is not None else "sort"
     _M_GROUPS.labels(family=family).inc()
     # annotation only: the phases and chunk sites inside are timed on
     # their own
-    with section("wgl.batch.group", P=slots, lanes=len(padded),
+    with section("wgl.batch.group", P=slots, lanes=lanes,
                  keys=len(items), steps=int(ns.max())):
         with section("wgl.stage"):
             if dense is not None:
@@ -2592,7 +2632,7 @@ def _check_group(model, hists: list, ops: list, group: _Group,
                 abft.verify_steps("batch", guarded_device_get(
                     abft.digest_device(x), site="batch steps attest"),
                     abft.digest_host(x_np))
-            s0 = jnp.full(len(padded), model.device_state(), jnp.int32)
+            s0 = jnp.full(lanes, model.device_state(), jnp.int32)
             carry = jax.vmap(k.init_carry)(s0)
         e = 0
         n_max = int(ns.max())
@@ -2628,9 +2668,9 @@ def _check_group(model, hists: list, ops: list, group: _Group,
         # every lane runs every step advanced, over the E rows staged for
         # it; a real key needs only its own steps
         _M_LANE_STEPS.labels(family=family, kind="staged").inc(
-            len(padded) * E)
+            lanes * E)
         _M_LANE_STEPS.labels(family=family, kind="dispatched").inc(
-            len(padded) * e)
+            lanes * e)
         _M_LANE_STEPS.labels(family=family, kind="useful").inc(
             int(np.minimum(ns[:len(items)], e).sum()))
         with section("wgl.summarize"):
@@ -2656,7 +2696,7 @@ def _check_group(model, hists: list, ops: list, group: _Group,
                     site="batch summarize")
             _check_att(np.asarray(att).sum(), "batch")
             _M_OPS.labels(site="batch").inc(
-                sum(len(o) for _, o, _ in items))
+                sum(len(o) for _, o in items))
             batch_dedup = (DEDUP_NONE if dense is not None else
                            dedup_engine(frontier, slots,
                                         _pack_params(srange, slots),
@@ -2667,7 +2707,7 @@ def _check_group(model, hists: list, ops: list, group: _Group,
             decided = (np.asarray(consumed) >= ns) | (counts == 0)
             suspects = []    # overflow + invalid: escalate together
             invalids = []    # definite invalid: blame together
-            for j, (i, ops, st) in enumerate(items):
+            for j, (i, ops) in enumerate(items):
                 if not bool(decided[j]):
                     results[i] = _unknown_result(
                         ops, "batch budget exhausted/cancelled before "
@@ -2687,15 +2727,12 @@ def _check_group(model, hists: list, ops: list, group: _Group,
             with section("wgl.blame"):
                 # one vmapped unmerged pass names every culprit op (the
                 # unmerged streams fit E by construction)
-                st2s = [build_steps(ops, slots, merge=False).pad_to(E)
-                        for _, _, ops in invalids]
-                st2s += [Steps.empty(st2s[0].w, E)] * (
-                    _bucket(len(st2s), lo=1) - len(st2s))
+                st2 = build_steps_batch([ops for _, _, ops in invalids],
+                                        slots, merge=False, t=E,
+                                        lanes=_bucket(len(invalids), lo=1))
                 okb, deathb, *_ = guarded_device_get(k.check_batch(
-                    jnp.asarray(np.stack([s.x for s in st2s])),
-                    jnp.asarray(np.asarray([s.n for s in st2s],
-                                           np.int32)),
-                    jnp.full(len(st2s), model.device_state(),
+                    jnp.asarray(st2.x), jnp.asarray(st2.n),
+                    jnp.full(len(st2.n), model.device_state(),
                              jnp.int32)),
                     site="batch blame")
                 for t, (j, i, ops) in enumerate(invalids):
@@ -2706,9 +2743,9 @@ def _check_group(model, hists: list, ops: list, group: _Group,
                          "configs": [], "final-paths": []}
                     d = int(deathb[t])
                     if not bool(okb[t]) and d >= 0:
-                        row = int(st2s[t].inv_row[d])
+                        row = int(st2.inv_row[t, d])
                         if row < 0:
-                            row = int(st2s[t].ret_row[d])
+                            row = int(st2.ret_row[t, d])
                         if row >= 0:
                             src = int(ops.index[row])
                             r["op"] = _find_op(hists[i], src)
@@ -2949,7 +2986,7 @@ def _check_batch_sharded_once(model, hists: list, mesh=None,
     # OpArray exposes the same f/a/b arrays _state_range reads, so
     # eligibility costs no extra stream builds
     srange = _state_range(name, model, all_ops)
-    p_req = [required_slots(ops) for ops in all_ops]
+    p_req = required_slots_batch(all_ops)
 
     # analysis_tpu_batch's dispatch groups (see _plan_groups): on the
     # hazelcast bench shape (100 keys, ~2.5 crashes/key) the max-padded
@@ -2968,13 +3005,11 @@ def _check_batch_sharded_once(model, hists: list, mesh=None,
             # dense caps rejected
             g_slots = max(g_slots, _bucket(max(p_req[i] for i in idx),
                                            lo=8))
-        steps_list = [build_steps(all_ops[i], g_slots) for i in idx]
-        E = _bucket(max(max(st.n for st in steps_list), 1))
-        w = steps_list[0].w
         gk = len(idx)
         g_pad = -(-gk // n_dev) * n_dev
-        padded = [st.pad_to(E) for st in steps_list]
-        padded += [Steps.empty(w, E)] * (g_pad - gk)
+        stack = build_steps_batch([all_ops[i] for i in idx], g_slots,
+                                  lanes=g_pad)
+        x_np = stack.x
 
         group_info.append({
             "family": "dense" if dense is not None else "sort",
@@ -2984,9 +3019,8 @@ def _check_batch_sharded_once(model, hists: list, mesh=None,
                                    pallas)),
             "keys": gk, "slots": g_slots})
         run = _sharded_runner(name, dense, frontier, g_slots, srange,
-                              E, mesh, axis, pallas=pallas)
+                              x_np.shape[1], mesh, axis, pallas=pallas)
         maybe_inject_fault("sharded")
-        x_np = np.stack([st.x for st in padded])
         # stage each key's rows straight onto the device that checks
         # them: a plain asarray lands the whole batch on one device
         # and leaves the reshard to the jitted shard_map
@@ -3007,8 +3041,7 @@ def _check_batch_sharded_once(model, hists: list, mesh=None,
         # latency the grouping saved
         all_ok_g, ok_g, ov_g, att_g = run(
             xj,
-            jax.device_put(np.asarray([st.n for st in padded], np.int32),
-                           keys_sharding),
+            jax.device_put(stack.n, keys_sharding),
             jax.device_put(np.full(g_pad, model.device_state(), np.int32),
                            keys_sharding))
         return all_ok_g, ok_g, ov_g, att_g, att

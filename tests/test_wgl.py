@@ -2,6 +2,11 @@
 oracle on every history (the SURVEY's 'golden tests for the TPU kernels:
 same history arrays in, same verdicts out')."""
 
+import heapq
+import random
+from pathlib import Path
+
+import numpy as np
 import pytest
 
 from jepsen_tpu import models
@@ -13,7 +18,8 @@ from jepsen_tpu.checker.wgl import (SlotOverflow, analysis_tpu,
                                     analysis_tpu_batch, build_steps,
                                     check_batch_sharded,
                                     encode_ops_for_model)
-from jepsen_tpu.history import History
+from jepsen_tpu.history import (KIND_INFO, KIND_OK, NIL, PENDING_RET,
+                                History, OpArray)
 
 
 def op(type, f, value, process=0, time=0):
@@ -245,6 +251,209 @@ def test_slot_overflow_detection():
     ops = encode_ops_for_model(m.cas_register(), hist)
     with pytest.raises(SlotOverflow):
         build_steps(ops, 4)
+
+
+# The per-event loops build_steps and required_slots replaced, kept as
+# the oracle the array passes must match bit for bit.
+
+def _oracle_required_slots(ops):
+    events = []
+    for r in range(len(ops)):
+        events.append((int(ops.inv[r]), 0, 1))
+        if ops.kind[r] == KIND_OK:
+            events.append((int(ops.ret[r]), 1, -1))
+        elif ops.kind[r] == wgl.KIND_RELEASED:
+            events.append((int(ops.ret[r]), -1, -1))
+    events.sort()
+    cur = peak = 0
+    for _, _, d in events:
+        cur += d
+        peak = max(peak, cur)
+    return max(peak, 1)
+
+
+def _oracle_build_steps(ops, p, merge=True):
+    events = []  # (position, order, kind, row)
+    for r in range(len(ops)):
+        events.append((int(ops.inv[r]), 0, 0, r))
+        if ops.kind[r] == KIND_OK:
+            events.append((int(ops.ret[r]), 1, 1, r))
+        elif ops.kind[r] == wgl.KIND_RELEASED:
+            events.append((int(ops.ret[r]), -1, 2, r))
+    events.sort()
+    w = max(1, (p + 31) // 32)
+    free = list(range(p))
+    heapq.heapify(free)
+    slot_of_row = {}
+    masks, rest, ret_row, inv_row = [], [], [], []
+    pend = [0] * w
+    pend_rows = []
+
+    def flush(inv_slot, f, a, b, row):
+        nonlocal pend, pend_rows
+        masks.append(pend)
+        rest.append((inv_slot, f, a, b))
+        ret_row.append(pend_rows[0] if len(pend_rows) == 1 else -1)
+        inv_row.append(row)
+        pend = [0] * w
+        pend_rows = []
+
+    for _, _, k, r in events:
+        if k == 0:
+            if not free:
+                raise SlotOverflow(
+                    f"more than {p} pending ops at op row {r} "
+                    f"(crashed ops hold slots forever); raise p or check "
+                    f"on the host")
+            s = heapq.heappop(free)
+            slot_of_row[r] = s
+            flush(s, int(ops.f[r]), int(ops.a[r]), int(ops.b[r]), r)
+        elif k == 2:
+            if pend_rows:
+                flush(-1, 0, NIL, NIL, -1)
+            s = slot_of_row.pop(r)
+            heapq.heappush(free, s)
+            pend[s // 32] |= 1 << (s % 32)
+            flush(-1, wgl.F_RELEASE, NIL, NIL, -1)
+        else:
+            s = slot_of_row.pop(r)
+            heapq.heappush(free, s)
+            pend[s // 32] |= 1 << (s % 32)
+            pend_rows.append(r)
+            if not merge:
+                flush(-1, 0, NIL, NIL, -1)
+    if any(pend):
+        flush(-1, 0, NIL, NIL, -1)
+    n = len(masks)
+    mask_arr = np.asarray(masks, np.uint32).reshape(n, w)
+    rest_arr = np.asarray(rest, np.int32).reshape(n, 4)
+    return wgl.Steps(np.concatenate([mask_arr.view(np.int32), rest_arr],
+                                    axis=1),
+                     np.asarray(ret_row, np.int32),
+                     np.asarray(inv_row, np.int32), w, n)
+
+
+def _random_ops(seed, n=300):
+    """Random rows: distinct invoke positions, returns tied with each
+    other and with invokes, a few long-running ops holding many slots,
+    and crashes of a few kinds pending forever."""
+    rng = np.random.default_rng(seed)
+    conc = (4, 24, 40)[seed % 3]
+    inv = np.cumsum(rng.integers(1, 3, n)).astype(np.int32)
+    ret = (inv + rng.integers(0, 2 * conc, n)).astype(np.int32)
+    kind = np.where(rng.random(n) < 0.06, KIND_INFO, KIND_OK)
+    f = rng.integers(1, 3, n)
+    a = rng.integers(0, 2, n)
+    b = np.where(f == 2, rng.integers(0, 2, n), NIL)
+    return OpArray(f=f.astype(np.int32), a=a.astype(np.int32),
+                   b=b.astype(np.int32), kind=kind.astype(np.int32),
+                   inv=inv, ret=np.where(kind == KIND_OK, ret, PENDING_RET),
+                   process=np.zeros(n, np.int32), index=inv.copy())
+
+
+def _empty_ops():
+    z = np.zeros(0, np.int32)
+    return OpArray(f=z, a=z, b=z, kind=z, inv=z, ret=z, process=z, index=z)
+
+
+def _assert_same_steps(got, want):
+    assert (got.w, got.n) == (want.w, want.n)
+    for field in ("x", "ret_row", "inv_row"):
+        g, e = getattr(got, field), getattr(want, field)
+        assert g.dtype == e.dtype and g.shape == e.shape
+        assert (g == e).all(), field
+
+
+@pytest.mark.parametrize("merge", [True, False], ids=["merged", "unmerged"])
+@pytest.mark.parametrize("p", [31, 33, 64])
+@pytest.mark.parametrize("m", [0, 1, 2], ids=["held", "m1", "m2"])
+@pytest.mark.parametrize("seed", range(3))
+def test_steps_and_slots_match_the_per_event_loops(seed, m, p, merge):
+    """The array passes give the per-event loops' steps bit for bit:
+    tied positions, crashes pending forever or to release_crashed's
+    deadlines, merged and unmerged, masks of one word and of two; the
+    same SlotOverflow where p is short, and at required_slots - 1."""
+    ops = _random_ops(seed)
+    if m:
+        ops, released = wgl.release_crashed(ops, m)
+        assert released
+    need = _oracle_required_slots(ops)
+    assert wgl.required_slots(ops) == need
+    for slots in (p, need - 1):
+        try:
+            want = _oracle_build_steps(ops, slots, merge)
+        except SlotOverflow as e:
+            with pytest.raises(SlotOverflow) as got:
+                build_steps(ops, slots, merge)
+            assert str(got.value) == str(e)
+            assert slots < need
+            continue
+        assert slots >= need
+        _assert_same_steps(build_steps(ops, slots, merge), want)
+
+
+@pytest.mark.parametrize("merge", [True, False], ids=["merged", "unmerged"])
+def test_steps_of_an_empty_history(merge):
+    ops = _empty_ops()
+    assert wgl.required_slots(ops) == _oracle_required_slots(ops) == 1
+    _assert_same_steps(build_steps(ops, 8, merge),
+                       _oracle_build_steps(ops, 8, merge))
+
+
+def _ycsb_keys(seed):
+    """The encoded keys of a small seeded YCSB-A history: a Zipfian key
+    choice, so a few long keys and many short ones, crashes by a coin
+    and release_crashed's deadlines."""
+    from benchmark import harness
+    from jepsen_tpu.independent import KV, subhistories
+
+    ycsb = harness.load_module(
+        Path(__file__).resolve().parent.parent
+        / "benchmark" / "generators" / "ycsb.py")
+    shape = {"threads_per_key": 20, "values": 5,
+             "mix": {"read": 1, "write": 1}, "records": 200,
+             "distribution": "scrambled-zipfian",
+             "zipfian_constant": 0.99}
+    ops, _ = ycsb.history(shape, {"ops": 3000, "crash": {
+        "rate": 0.05, "effect": "either"}}, random.Random(seed))
+    hist = History([{**o, "value": KV(*o["value"])} for o in ops])
+    return [wgl.release_crashed(
+        encode_ops_for_model(m.cas_register(), h), 1)[0]
+        for h in subhistories(hist).values()]
+
+
+@pytest.mark.parametrize("merge", [True, False], ids=["merged", "unmerged"])
+@pytest.mark.parametrize("seed", range(2))
+def test_a_groups_stack_is_its_keys_steps_stacked(seed, merge):
+    """build_steps_batch writes a group's keys of very unequal length,
+    an empty one among them, into one bucketed stack equal to each key's
+    steps padded to E, then empty lanes up to the bucketed B; the
+    batched required_slots is the per-key list."""
+    keys = _ycsb_keys(seed)
+    keys.insert(len(keys) // 2, _empty_ops())
+    lens = sorted(len(o) for o in keys)
+    assert lens[0] == 0 and lens[-1] > 8 * lens[len(lens) // 2]
+    need = [_oracle_required_slots(o) for o in keys]
+    assert wgl.required_slots_batch(keys) == need
+    p = max(need)
+    E = wgl._bucket(max(wgl.event_count(o) for o in keys))
+    lanes = wgl._bucket(len(keys), lo=1)
+    per_key = [_oracle_build_steps(o, p, merge).pad_to(E) for o in keys]
+    per_key += [_oracle_build_steps(_empty_ops(), p, merge).pad_to(E)] * (
+        lanes - len(keys))
+    got = wgl.build_steps_batch(keys, p, merge, t=E, lanes=lanes)
+    assert got.w == per_key[0].w
+    for field in ("x", "ret_row", "inv_row"):
+        want = np.stack([getattr(st, field) for st in per_key])
+        assert getattr(got, field).shape == want.shape
+        assert (getattr(got, field) == want).all(), field
+    assert got.n.dtype == np.int32
+    assert (got.n == [st.n for st in per_key]).all()
+    # by default a lane holds the longest key's steps, bucketed
+    assert wgl.build_steps_batch(keys, p, merge).x.shape[1] == \
+        wgl._bucket(max(st.n for st in per_key))
+    with pytest.raises(SlotOverflow):
+        wgl.build_steps_batch(keys, p - 1, merge)
 
 
 def test_slot_overflow_escalates_transparently():
