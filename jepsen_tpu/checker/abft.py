@@ -169,6 +169,11 @@ def carry_digest_host(carry) -> int:
     return _to_i32(h)
 
 
+# (e, table, slot_f, slot_a, slot_b, slot_occ, att, count, max_count):
+# the dense kernels' carry, whose table is bool or uint32 words
+DENSE_CARRY_LEN = 9
+
+
 def verify_carry(site: str, fetched_digest, carry_host,
                  att_index: int = -3) -> None:
     """Check a fetched carry against its device-computed digest plus
@@ -200,9 +205,12 @@ def verify_carry(site: str, fetched_digest, carry_host,
                   f"frontier/table invariant or dedup digest failed "
                   f"on device")
     count = int(np.asarray(carry_host[-2]))
-    live = carry_host[1]            # masks (sort) / table (dense)
+    # masks (sort) / table (dense), as fetched: maybe strided
+    live = np.ascontiguousarray(carry_host[1])
     if live.dtype == np.bool_:      # dense table: count == popcount
-        pop = int(np.asarray(live).sum())
+        pop = int(live.sum())
+    elif len(carry_host) == DENSE_CARRY_LEN:   # dense table as words
+        pop = int(np.unpackbits(live.view(np.uint8)).sum())
     else:                           # sort frontier: count == sum(valid)
         pop = int(np.asarray(carry_host[3]).sum())
     if count != pop:

@@ -160,7 +160,8 @@ def price_escalation(model, hist) -> dict | None:
         ops = wgl.encode_ops_for_model(model, hist)
         p = wgl.required_slots(ops)
         srange = wgl._state_range(model.device_model, model, [ops])
-        dec = wgl.select_engine(srange, p, wgl.event_count(ops))
+        dec = wgl.select_engine(srange, p, wgl.event_count(ops),
+                                model=model.device_model)
         return {"family": dec.family, "dedup": dec.dedup,
                 "reason": dec.reason, "cost": wgl.engine_cost(dec)}
     except Exception:  # noqa: BLE001 — pricing is advisory

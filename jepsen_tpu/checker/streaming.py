@@ -341,6 +341,11 @@ class WglStream:
         self.model = model
         self.name = name
         self.dm = _wgl.DEVICE_MODELS[name]
+        if self.dm.image is not None:
+            # hundreds of states, which the sort family's frontier would
+            # have to hold, and transactions whose (a, b) are not states,
+            # which the range-escape check reads as states
+            raise ValueError(f"model {name!r} has no stream yet")
         # service scheduling: auto_pump=False turns feed() into
         # encode-only — a scheduler calls pump() to dispatch chunks
         # under its own budget. fault_site names this stream's fault-

@@ -113,6 +113,13 @@ _M_LANE_STEPS = _telemetry.counter(
     "(lanes x steps advanced) and useful (each real key's steps, up to "
     "those advanced)",
     ("family", "kind"))
+# The dense table's cells those dispatched lane-steps carried: a dense
+# group's lanes x S x 2^P at every step its chunk loop advances.
+_M_TABLE_CELLS = _telemetry.counter(
+    "jepsen_tpu_wgl_batch_table_cells_total",
+    "Dense-table cells stepped by batch dispatches: lanes x S x 2^P x "
+    "steps advanced, at each chunk dispatch",
+    ("family",))
 _M_GROUPS = _telemetry.counter(
     "jepsen_tpu_wgl_batch_groups_total",
     "Batch dispatch groups run (one vmapped kernel shape each)",
@@ -345,6 +352,118 @@ def uqueue_codec(o: dict) -> tuple[int, int, int]:
     raise DeviceEncodingError(f"unknown queue op f={f!r}")
 
 
+# -- multi-register: f 0 = reads only, 1 = writes ---------------------------
+# A transaction lowers to what it asks of the state it starts from and
+# what it leaves: for subkey k, bit 9 + k of `a` constrains its digit to
+# bits [3k, 3k + 3) of `a` (code 7, never a digit, for a transaction that
+# reads back other than it wrote), and bit 9 + k of `b` sets it to bits
+# [3k, 3k + 3) of `b`. The state is models.MultiRegister's mixed-radix
+# packing: 216 states, where 3-bit fields would take 366 (and a table
+# bucket of 512, twice the rows to step and one slot fewer under
+# DENSE_TABLE_CAP).
+
+from ..models import (MR_RADIX, MR_SUBKEYS, mr_digit,  # noqa: E402
+                      mr_subkey)
+
+MR_READ, MR_WRITE = 0, 1
+_MR_NEVER = 7
+
+
+def _mr_digits(state):
+    """The subkeys' digits of packed states, least significant first."""
+    return [state // MR_RADIX ** k % MR_RADIX for k in range(MR_SUBKEYS)]
+
+
+def _mr_step(state, f, a, b):
+    import jax.numpy as jnp
+    state, a, b = jnp.broadcast_arrays(state, a, b)
+    legal = jnp.ones(state.shape, jnp.bool_)
+    new = jnp.zeros_like(state)
+    for k, d in enumerate(_mr_digits(state)):
+        legal = legal & ((((a >> (9 + k)) & 1) == 0)
+                         | (d == ((a >> (3 * k)) & 7)))
+        new = new + jnp.where(((b >> (9 + k)) & 1) == 1,
+                              (b >> (3 * k)) & 7, d) * MR_RADIX ** k
+    return legal, new
+
+
+def multi_register_codec(o: dict) -> tuple[int, int, int]:
+    """A transaction [[f, k, v], ...] as (f, a, b): the digits it needs
+    of the state it starts from, in micro-op order (a read after the
+    transaction's own write of that subkey is decided here), and the
+    digits it writes. A nil read constrains nothing."""
+    need: dict[int, int] = {}
+    wrote: dict[int, int] = {}
+    for mop in o["value"] or ():
+        f, k, v = mop
+        k = mr_subkey(k)
+        if f in ("w", "write"):
+            wrote[k] = mr_digit(v)
+        elif f in ("r", "read"):
+            if v is None:
+                continue
+            d = mr_digit(v)
+            if k in wrote:
+                if wrote[k] != d:
+                    need[k] = _MR_NEVER
+            elif need.setdefault(k, d) != d:
+                need[k] = _MR_NEVER
+        else:
+            raise DeviceEncodingError(
+                f"unknown multi-register micro-op f={f!r}")
+    a = sum((d << (3 * k)) | (1 << (9 + k)) for k, d in need.items())
+    b = sum((d << (3 * k)) | (1 << (9 + k)) for k, d in wrote.items())
+    return (MR_WRITE if wrote else MR_READ), a, b
+
+
+def _mr_range(init, f, a, b):
+    """States over the subkeys the history touches: all digits of every
+    subkey up to the highest touched, so a relabelled history keeps its
+    shape."""
+    a, b = np.asarray(a), np.asarray(b)
+    touched = int(np.bitwise_or.reduce(np.concatenate(
+        [a[a != NIL] >> 9, b[b != NIL] >> 9, [0]]).astype(np.int64)))
+    top = max(touched.bit_length(),
+              next((k + 1 for k in reversed(range(MR_SUBKEYS))
+                    if init // MR_RADIX ** k % MR_RADIX), 1))
+    return 0, MR_RADIX ** top - 1
+
+
+def _mr_reset(init):
+    """A write of `init`'s digits to every subkey: legal from every
+    state, it moves each to `init`."""
+    return MR_WRITE, 0, sum((d << (3 * k)) | (1 << (9 + k))
+                            for k, d in enumerate(_mr_digits(int(init))))
+
+
+def _mr_image(x, legal, f, a, b):
+    """The rows of word table `x` (S states x W words of configurations)
+    moved by one transaction: the rows it is legal from, OR-reduced over
+    each written subkey's digit and placed at the written digit. O(S x
+    W), where the transition product is O(S^2 x W). The table's first
+    6^t rows are every state of the t subkeys _mr_range spans, t the
+    most that S holds."""
+    import jax.numpy as jnp
+    from jax import lax
+    S, rest = x.shape[0], x.shape[1:]
+    t = max(t for t in range(MR_SUBKEYS + 1) if MR_RADIX ** t <= S)
+    n = MR_RADIX ** t
+    x = jnp.where(legal.reshape((S,) + (1,) * len(rest)), x,
+                  jnp.zeros_like(x))
+    x = x[:n].reshape((MR_RADIX,) * t + rest)
+    for k in range(t):
+        axis = t - 1 - k
+        shape = [1] * x.ndim
+        shape[axis] = MR_RADIX
+        hit = jnp.arange(MR_RADIX).reshape(shape) == ((b >> (3 * k)) & 7)
+        moved = jnp.expand_dims(lax.reduce(x, x.dtype.type(0),
+                                           lax.bitwise_or, (axis,)), axis)
+        placed = jnp.where(hit, moved, jnp.zeros_like(x))
+        x = jnp.where(((b >> (9 + k)) & 1) == 1, placed, x)
+    return jnp.concatenate([x.reshape((n,) + rest),
+                            jnp.zeros((S - n,) + rest, x.dtype)])
+
+
 @dataclasses.dataclass(frozen=True)
 class DeviceModel:
     """A model with enumerable int32 state, steppable on device.
@@ -361,6 +480,17 @@ class DeviceModel:
     droppable: frozenset
     state_range: Callable
     validate: Callable | None = None  # (OpArray, model) -> None | raise
+    # (f, a, b) -> the kind of a crashed op: release_crashed gives it a
+    # deadline at the next crash of its kind
+    crash_kind: Callable = lambda f, a, b: (f, a, b)
+    # (words, legal, f, a, b) -> the rows one op moves a word table's
+    # rows to, for a model whose states are too many for the transition
+    # product (see _dense_kernel_cached)
+    image: Callable | None = None
+    # init state -> (f, a, b) of an op legal from every state that moves
+    # each to init: a dense lane then checks keys one after another
+    # (_pack_lanes)
+    reset: Callable | None = None
 
     def __iter__(self):  # legacy tuple shape: (step, codec, droppable)
         return iter((self.step, self.codec, self.droppable))
@@ -394,6 +524,12 @@ DEVICE_MODELS: dict[str, DeviceModel] = {
         _uqueue_step, uqueue_codec, frozenset(),
         lambda init, f, a, b: (0, (1 << (4 * UQ_VALUES)) - 1),
         validate=_uqueue_validate),
+    # write transactions come in hundreds of (f, a, b) kinds, so a crash
+    # would almost never meet a later one of its own: any crashed write
+    # is of one kind
+    "multi-register": DeviceModel(
+        _mr_step, multi_register_codec, frozenset({MR_READ}), _mr_range,
+        crash_kind=lambda f, a, b: f, image=_mr_image, reset=_mr_reset),
 }
 
 
@@ -508,11 +644,14 @@ def required_slots(ops: OpArray) -> int:
     return required_slots_batch([ops])[0]
 
 
-def release_crashed(ops: OpArray, m: int) -> tuple[OpArray, bool]:
+def release_crashed(ops: OpArray, m: int,
+                    kind: Callable = DeviceModel.crash_kind
+                    ) -> tuple[OpArray, bool]:
     """Give each crashed op a deadline: the invocation of the m-th later
-    crashed op of its kind (the same f, a, b), after which it may no
-    longer take effect and its slot is released. Returns the ops and
-    whether any got a deadline.
+    crashed op of its kind (`kind(f, a, b)`, by default the same f, a,
+    b; DeviceModel.crash_kind), after which it may no longer take effect
+    and its slot is released. Returns the ops and whether any got a
+    deadline.
 
     A deadline only narrows when an op may take effect, so a history
     linearizable with deadlines is linearizable, and with none left it
@@ -524,10 +663,10 @@ def release_crashed(ops: OpArray, m: int) -> tuple[OpArray, bool]:
     info = np.flatnonzero(np.asarray(ops.kind) == KIND_INFO)
     if len(info) <= m:
         return ops, False
-    by_kind: dict[tuple[int, int, int], list[int]] = {}
+    by_kind: dict = {}
     for r in info:
-        by_kind.setdefault((int(ops.f[r]), int(ops.a[r]), int(ops.b[r])),
-                           []).append(int(r))
+        by_kind.setdefault(kind(int(ops.f[r]), int(ops.a[r]),
+                                int(ops.b[r])), []).append(int(r))
     ret, kind = np.array(ops.ret), np.array(ops.kind)
     for rows in by_kind.values():
         for j, r in enumerate(rows[:-m]):
@@ -574,8 +713,8 @@ def _prefix(ops: OpArray, position: int, droppable) -> OpArray:
                       for f in dataclasses.fields(OpArray)})
 
 
-def _decide_in_rounds(pre: list, run, droppable, deadlines: bool = True,
-                      site: str = "batch"):
+def _decide_in_rounds(pre: list, run, dm: DeviceModel,
+                      deadlines: bool = True, site: str = "batch"):
     """Decide encoded histories through rounds of `run(indices, ops)`.
 
     A key is first checked with release_crashed's deadlines at m = 1.
@@ -588,7 +727,8 @@ def _decide_in_rounds(pre: list, run, droppable, deadlines: bool = True,
     is exact. `site` labels the checks in jepsen_tpu_wgl_deadline_checks
     (offline: analysis_tpu, whose `run` is one _search a round; batch:
     analysis_tpu_batch, whose `run` plans the round's dispatch groups
-    and checks each once)."""
+    and checks each once). `dm` is the histories' device model: its
+    droppable ops and crash kinds."""
     results: list[dict | None] = [None] * len(pre)
     plan = {i: (1, None) for i in range(len(pre))}   # key -> (m, cut)
     while plan:
@@ -598,9 +738,10 @@ def _decide_in_rounds(pre: list, run, droppable, deadlines: bool = True,
             for i in keys:
                 m, cut = plan[i]
                 if cut is not None:
-                    sent.append((_prefix(pre[i], cut, droppable), False))
+                    sent.append((_prefix(pre[i], cut, dm.droppable),
+                                 False))
                 elif deadlines:
-                    sent.append(release_crashed(pre[i], m))
+                    sent.append(release_crashed(pre[i], m, dm.crash_kind))
                 else:
                     sent.append((pre[i], False))
         nxt = {}
@@ -811,6 +952,7 @@ DEDUP_NONE = "dense-table"   # the dense family has no dedup at all
 # closure-round names (dense family only)
 CLOSURE_PALLAS = "pallas-closure"
 CLOSURE_XLA = "xla-closure"
+CLOSURE_IMAGE = "image-closure"
 
 
 def _hash_gate(F: int, P: int, pack: tuple[int, int] | None) -> bool:
@@ -833,9 +975,12 @@ def dedup_engine(F: int, P: int, pack: tuple[int, int] | None,
     return DEDUP_PALLAS if use and _hash_gate(F, P, pack) else DEDUP_SORT
 
 
-def closure_engine(S: int, P: int, pallas=None) -> str:
-    """Which closure round the dense kernel runs at this shape — the
-    same gate _dense_kernel_cached applies."""
+def closure_engine(model: str, S: int, P: int, pallas=None) -> str:
+    """Which closure round the dense kernel runs for the device model
+    `model` at this shape — the same gates _dense_kernel_cached
+    applies."""
+    if DEVICE_MODELS[model].image is not None:
+        return CLOSURE_IMAGE
     use, _on_tpu = _pallas_enabled(PALLAS_CLOSURE_ENV, pallas)
     from . import wgl_pallas
     return CLOSURE_PALLAS if use and wgl_pallas.eligible(S, P) \
@@ -1290,18 +1435,82 @@ def _dense_kernel_cached(model_name: str, s_lo: int, S: int, P: int,
     # JEPSEN_TPU_PALLAS_CLOSURE=0 (resolved by the _dense_kernel
     # wrapper). Shapes past the VMEM gate fall back to the XLA
     # formulation below.
+    image = DEVICE_MODELS[model_name].image
     pallas_round = None
-    if use_pallas:
+    if use_pallas and image is None:
         from . import wgl_pallas
         if wgl_pallas.eligible(S, P):
             pallas_round = wgl_pallas.closure_round_fn(
                 S, P, interpret=not on_tpu)
+    # With an image the table is held as S x W uint32 words, W = C / L:
+    # configuration c = j * W + w is bit j of word w, so the low log2(W)
+    # slot bits index words and the rest bits within a word, and each
+    # pass over the table moves 32 configurations a lane.
+    W = max(1, C // 32)
+    L = C // W
+    LOGW = W.bit_length() - 1
+    u32 = jnp.uint32
+    JS = jnp.arange(L, dtype=u32)
+    KEEP = [u32(sum(1 << j for j in range(L) if not j >> q & 1))
+            for q in range(L.bit_length() - 1)]   # bits with bit q clear
+
+    def pack(cols):
+        """A (C,) bool column mask as its (W,) words."""
+        return jnp.sum(cols.reshape(L, W).astype(u32) << JS[:, None],
+                       axis=0, dtype=u32)
+
+    def across(x, p, up):
+        """Configurations moved across slot bit p of the word table:
+        up, from c (bit clear) to c | 2^p; down, from c | 2^p to c. The
+        side they leave reads empty."""
+        if p < LOGW:
+            b = 1 << p
+            v = x.reshape(S, W // (2 * b), 2, b)
+            z = jnp.zeros_like(v[:, :, :1, :])
+            halves = [z, v[:, :, :1, :]] if up else [v[:, :, 1:, :], z]
+            return jnp.concatenate(halves, axis=2).reshape(S, W)
+        q = p - LOGW
+        if up:
+            return (x & KEEP[q]) << u32(1 << q)
+        return (x >> u32(1 << q)) & KEEP[q]
+
+    def clear(x, p):
+        """The word table without the configurations holding bit p."""
+        if p < LOGW:
+            b = 1 << p
+            v = x.reshape(S, W // (2 * b), 2, b)
+            return jnp.concatenate([v[:, :, :1, :], jnp.zeros_like(
+                v[:, :, :1, :])], axis=2).reshape(S, W)
+        return x & KEEP[p - LOGW]
+
+    def popcount(x):
+        return lax.population_count(x).sum(dtype=i32)
+
+    def closure_image(words, legal, slot_f, slot_a, slot_b):
+        """The closure by each slot's image (DeviceModel.image) over the
+        word table: each slot's moved rows OR-ed into the columns with
+        its bit set, slot by slot, until the popcount stops growing.
+        O(P * S * C / 32) a round."""
+
+        def wbody(c):
+            tb, cnt, _ = c
+            for p in range(P):
+                tb = tb | across(image(tb, legal[p], slot_f[p], slot_a[p],
+                                       slot_b[p]), p, up=True)
+            return tb, popcount(tb), cnt
+
+        words, _, _ = lax.while_loop(
+            lambda c: c[1] != c[2], wbody,
+            (words, popcount(words), i32(-1)))
+        return words
 
     def closure(table, slot_f, slot_a, slot_b, slot_occ):
         """Close the table under linearization of every occupied slot."""
         legal, new = step(S_VALS[None, :], slot_f[:, None],
                           slot_a[:, None], slot_b[:, None])     # (P, S)
         legal = legal & slot_occ[:, None]
+        if image is not None:
+            return closure_image(table, legal, slot_f, slot_a, slot_b)
         # M[p, s, s2]: linearizing slot p moves state s to s2
         M = (legal[:, :, None]
              & (new[:, :, None] == S_VALS[None, None, :]))      # (P,S,S2)
@@ -1362,9 +1571,13 @@ def _dense_kernel_cached(model_name: str, s_lo: int, S: int, P: int,
         # att, count, max_count) — att is the ABFT attestation
         # accumulator (see the sort kernel's twin): table-occupancy
         # invariant residues sum into it and it must read 0 on host
-        # at every chunk boundary (abft.verify_carry).
-        table = jnp.zeros((S, C), jnp.bool_)
-        table = table.at[init_state - s_lo, 0].set(True)
+        # at every chunk boundary (abft.verify_carry). The table is
+        # (S, C) bool, or (S, W) words with an image.
+        if image is not None:
+            table = jnp.zeros((S, W), u32).at[init_state - s_lo, 0].set(1)
+        else:
+            table = jnp.zeros((S, C), jnp.bool_)
+            table = table.at[init_state - s_lo, 0].set(True)
         return (i32(0), table,
                 jnp.zeros((P,), i32), jnp.full((P,), NIL, i32),
                 jnp.full((P,), NIL, i32), jnp.zeros((P,), jnp.bool_),
@@ -1408,9 +1621,20 @@ def _dense_kernel_cached(model_name: str, s_lo: int, S: int, P: int,
             # identity gather. A release step (F_RELEASE) keeps the
             # configurations that did not take the released ops as well:
             # table'[c] = table[c] | table[c | rm], still closed.
-            done = jnp.take(table, COLS | rm, axis=1)
-            table = jnp.where((s < 0) & (f == F_RELEASE), table | done,
-                              done) & ((COLS & rm) == 0)[None, :]
+            release = (s < 0) & (f == F_RELEASE)
+            if image is not None:
+                # the same bit by bit: each returned bit moves its
+                # configurations down and empties the columns it was in
+                done, kept = table, table
+                for p in range(P):
+                    hit = ((rm >> p) & 1) == 1
+                    done = jnp.where(hit, across(done, p, up=False), done)
+                    kept = jnp.where(hit, clear(kept, p), kept)
+                table = jnp.where(release, kept | done, done)
+            else:
+                done = jnp.take(table, COLS | rm, axis=1)
+                table = jnp.where(release, table | done,
+                                  done) & ((COLS & rm) == 0)[None, :]
             slot_occ = slot_occ & \
                 ~((rm >> ARANGE_P) & 1).astype(jnp.bool_)
             table, slot_f, slot_a, slot_b, slot_occ = lax.cond(
@@ -1430,8 +1654,12 @@ def _dense_kernel_cached(model_name: str, s_lo: int, S: int, P: int,
                               jnp.zeros_like(ARANGE_P)),
                     dtype=i32)
                 badc = (COLS & ~occ_bits) != 0                  # (C,)
-                att = att + jnp.sum(table & badc[None, :], dtype=i32)
-            count = table.sum().astype(i32)
+                if image is not None:
+                    att = att + popcount(table & pack(badc)[None, :])
+                else:
+                    att = att + jnp.sum(table & badc[None, :], dtype=i32)
+            count = popcount(table) if image is not None \
+                else table.sum().astype(i32)
             return (e + 1, table, slot_f, slot_a, slot_b, slot_occ,
                     att, count, jnp.maximum(maxc, count))
 
@@ -1566,7 +1794,7 @@ def _family_costs(S: int, p_dense: int, p_sort: int, F: int,
 
 def _note_engine(dec: "EngineDecision", reason: str) -> "EngineDecision":
     """Count a select_engine outcome. `reason` is the COARSE bucket
-    (forced | slot-cap | dense-caps | cost-model | calibrated) — the
+    (forced | slot-cap | dense-caps | image | cost-model | calibrated) — the
     free-text dec.reason would blow up label cardinality. Also accumulates the
     chosen engine's modeled element-ops, so rate(elementops)/rate(
     chunk_seconds) is the pipeline's modeled throughput."""
@@ -1579,11 +1807,13 @@ def _note_engine(dec: "EngineDecision", reason: str) -> "EngineDecision":
 
 
 def select_engine(srange: tuple[int, int], p_exact: int, n_events: int,
-                  *, slots: int | None = None, frontier: int = 256,
-                  engine: str = "auto", dense_slot_cap: int | None = None,
-                  pallas=None, calibration=None) -> EngineDecision:
+                  *, model: str, slots: int | None = None,
+                  frontier: int = 256, engine: str = "auto",
+                  dense_slot_cap: int | None = None, pallas=None,
+                  calibration=None) -> EngineDecision:
     """Pick the kernel family (and the sort family's dedup engine) for
-    one history shape. engine='dense'/'sort' force a family ('dense'
+    one history shape of the device model `model` (a DEVICE_MODELS
+    name). engine='dense'/'sort' force a family ('dense'
     raises _dense_caps_error when the table cannot fit, the offline
     contract); 'auto' runs the cost model. dense_slot_cap bounds the
     slot count the dense table may be asked to absorb (each slot
@@ -1596,7 +1826,12 @@ def select_engine(srange: tuple[int, int], p_exact: int, n_events: int,
     measured coefficients for BOTH compared variants, the dense-vs-
     sort comparison runs in measured device-seconds instead of raw
     modeled element-ops — the same DENSE_EXACT_BIAS preference for
-    exact verdicts, applied to ground truth."""
+    exact verdicts, applied to ground truth.
+
+    A model with a DeviceModel.image takes the dense family wherever
+    its table fits: the sort family's frontier would have to hold what
+    the table holds. Its dense cost is the transition product's, which
+    bounds the image closure's from above."""
     if engine not in ("auto", "dense", "sort"):
         raise ValueError(f"unknown WGL engine {engine!r}")
     if slots is None:
@@ -1637,6 +1872,14 @@ def select_engine(srange: tuple[int, int], p_exact: int, n_events: int,
         return _note_engine(
             EngineDecision("sort", None, dedup, why, costs, seconds),
             "forced" if engine == "sort" else "dense-caps")
+    if DEVICE_MODELS[model].image is not None:
+        # the sort family's frontier would have to hold S x 2^P
+        # configurations, and escalates towards max_frontier where it
+        # does not; the table decides exactly
+        return _note_engine(EngineDecision(
+            "dense", dense, DEDUP_NONE,
+            f"S={S}: the dense closure by image", costs, seconds),
+            "forced" if engine == "dense" else "image")
     if seconds is not None:
         # measured comparison: same exactness bias, ground-truth units
         dense_v, sort_v = seconds["dense"], seconds[sort_variant]
@@ -1900,8 +2143,7 @@ def analysis_tpu(model, hist, frontier: int = 256, slots: int | None = None,
             final = a
         return [final]
 
-    a, = _decide_in_rounds([pre], run,
-                           DEVICE_MODELS[model.device_model].droppable,
+    a, = _decide_in_rounds([pre], run, DEVICE_MODELS[model.device_model],
                            deadlines=engine != "sort", site="offline")
     a["duration-ms"] = (_time.monotonic() - t0) * 1e3
     if explain:
@@ -2003,8 +2245,8 @@ def _analysis_tpu_once(model, hist, ops: OpArray, frontier: int = 256,
         if slots <= 256:
             srange = _state_range(name, model, [ops])
             decision = select_engine(srange, p_exact, event_count(ops),
-                                     slots=slots, frontier=frontier,
-                                     engine=engine,
+                                     model=name, slots=slots,
+                                     frontier=frontier, engine=engine,
                                      dense_slot_cap=dense_slot_cap,
                                      pallas=pallas)
             if decision.dense is None:
@@ -2100,11 +2342,13 @@ def _analysis_tpu_once(model, hist, ops: OpArray, frontier: int = 256,
                         prev[-2], site="offline liveness")) == 0
                 dt_chunk = _time.monotonic() - t_chunk
                 chunk_obs.observe(dt_chunk)
-                if chunk_i >= 2:
+                if chunk_i >= 2 and DEVICE_MODELS[name].image is None:
                     # the blocking flag read is one chunk behind, so
                     # dt_chunk measures chunk i-1: pair it with THAT
                     # chunk's op share, and start at i>=2 so chunk 0
-                    # (which carries the compile) never enters the fit
+                    # (which carries the compile) never enters the fit.
+                    # An image closure's chunks would fit the dense
+                    # variant's coefficient to work its price overstates
                     _calibrate.observe(engine_variant(decision),
                                        cal_ops_per_entry * prev_span,
                                        dt_chunk)
@@ -2174,7 +2418,7 @@ def _analysis_tpu_once(model, hist, ops: OpArray, frontier: int = 256,
         "final-paths": [],
     }
     if dense is not None:
-        out["closure"] = closure_engine(dense[1], dense[2], pallas)
+        out["closure"] = closure_engine(name, dense[1], dense[2], pallas)
     if att_info is not None:
         out["attested"] = att_info
     if not ok:
@@ -2319,7 +2563,10 @@ def _plan_groups(model, ops: list, p_req: list[int], engine: str,
     2^P-sized), so they spill into a single sort group instead of
     paying one sort-kernel compile per bucket — or, under a forced dense
     engine, raise. Each dense bucket then splits by its keys' lengths
-    (_length_parts).
+    (_length_parts), unless the model's keys share lanes
+    (DeviceModel.reset), where _check_group balances the lengths
+    (_pack_lanes) and a bucket joins the next wider one where that
+    costs less.
 
     Dense groups come cheapest-first and the sort group last, so a
     pathological dense-ineligible key can only starve itself of budget,
@@ -2337,8 +2584,8 @@ def _plan_groups(model, ops: list, p_req: list[int], engine: str,
         d = (_dense_shape(srange, _slot_bucket(p, p_max))
              or _dense_shape(srange, p))
         if d is not None and engine == "auto" and select_engine(
-                srange, d[2], n_events, frontier=frontier,
-                dense_slot_cap=dense_slot_cap,
+                srange, d[2], n_events, model=model.device_model,
+                frontier=frontier, dense_slot_cap=dense_slot_cap,
                 pallas=pallas).family != "dense":
             d = None
         if d is not None:
@@ -2347,9 +2594,24 @@ def _plan_groups(model, ops: list, p_req: list[int], engine: str,
             raise _dense_caps_error(srange, p, key=i)
         else:
             sort_idx.append(i)
-    groups = [_Group(part, d, d[2])
-              for d, idx in (buckets[pg] for pg in sorted(buckets))
-              for part in _length_parts(idx, ops)]
+    dense = [buckets[pg] for pg in sorted(buckets)]
+    if DEVICE_MODELS[model.device_model].reset is None:
+        groups = [_Group(part, d, d[2]) for d, idx in dense
+                  for part in _length_parts(idx, ops)]
+    else:
+        # keys share lanes (_check_group): a bucket joins the next
+        # wider one where one packed group costs less than the two
+        merged: list = []
+        for d, idx in dense:
+            if merged:
+                d0, idx0 = merged[-1]
+                if _pack_lanes(idx0 + idx, ops, d[1], d[2])[0] < (
+                        _pack_lanes(idx0, ops, d0[1], d0[2])[0]
+                        + _pack_lanes(idx, ops, d[1], d[2])[0]):
+                    merged[-1] = (d, idx0 + idx)
+                    continue
+            merged.append((d, idx))
+        groups = [_Group(idx, d, d[2]) for d, idx in merged]
     if sort_idx:
         groups.append(_Group(sort_idx, None, slots))
     return groups
@@ -2382,6 +2644,112 @@ def _length_parts(idx: list[int], pre: list) -> list[list[int]]:
                 continue
         out.append((e, list(keys)))
     return [keys for _, keys in reversed(out)]
+
+
+# A dense step's fixed latency, in table cells stepped (lanes x S x 2^P):
+# fit to three checks of the yugabyte-multi-key-acid cell on one v5e
+# (PERF.md), where a step costs ~0.2 ms and a lane at S 256, P 11 ~0.03
+# ms more
+DENSE_STEP_CELLS = 1 << 22
+# steps a packed group's chunk advances: a lane whose key died waits at
+# most two chunks for its next key
+PACKED_CHUNK = 256
+
+
+def _pack_lanes(idx: list[int], pre: list, S: int, P: int
+                ) -> tuple[int, list[list[int]]]:
+    """Share a dense group's lanes among its keys, for a model with a
+    DeviceModel.reset: a lane checks its keys one after another, so the
+    short keys fill what one key a lane leaves as padding behind the
+    longest. Keys go longest first to the least loaded lane (by event
+    count); the lane count is the power of two whose longest lane's
+    steps cost least, at DENSE_STEP_CELLS a step plus S x 2^P a lane.
+    Returns that cost and the lanes."""
+    size = [max(event_count(pre[i]), 1) for i in idx]
+    order = sorted(range(len(idx)), key=lambda j: -size[j])
+    best, L = None, 1
+    while True:
+        loads = [(0, lane) for lane in range(L)]
+        lanes: list[list[int]] = [[] for _ in range(L)]
+        for j in order:
+            load, lane = heapq.heappop(loads)
+            lanes[lane].append(idx[j])
+            heapq.heappush(loads, (load + size[j], lane))
+        cost = max(loads)[0] * (DENSE_STEP_CELLS + (L * S << P))
+        if best is None or cost < best[0]:
+            best = (cost, [lane for lane in lanes if lane])
+        if L >= len(idx):
+            return best
+        L *= 2
+
+
+def _then_reset(ops: OpArray, fab: tuple[int, int, int]) -> OpArray:
+    """`ops` with its crashed ops released after its last event, and then
+    the op `fab` (f, a, b) invoked and returned: the next key's steps
+    start with every slot free. A crash taking effect after every other
+    op of its key is one never seen, so the key's verdict stands."""
+    inv, ret, kind = (np.asarray(x) for x in (ops.inv, ops.ret, ops.kind))
+    pos = int(max(inv.max(initial=-1),
+                  ret[ret != PENDING_RET].max(initial=-1))) + 1
+    crashed = kind == KIND_INFO
+
+    def add(x, v):
+        x = np.asarray(x)
+        return np.append(x, np.asarray(v, x.dtype))
+
+    f, a, b = fab
+    return OpArray(f=add(ops.f, f), a=add(ops.a, a), b=add(ops.b, b),
+                   kind=add(np.where(crashed, KIND_RELEASED, kind),
+                            KIND_OK),
+                   inv=add(inv, pos),
+                   ret=add(np.where(crashed, pos, ret), pos + 1),
+                   process=add(ops.process, -1), index=add(ops.index, -1))
+
+
+def _lane_steps(lanes: list[list[int]], pre: list, P: int,
+                fab: tuple[int, int, int]):
+    """The step stack of packed lanes: each lane's keys' steps end to
+    end, each key but the lane's last _then_reset by `fab`. Returns the
+    (lanes, E, W+4) stack (lanes bucketed, E the _bucket of the longest
+    lane), each lane's step count, and each lane's keys' first steps
+    with its end."""
+    segs = [_then_reset(pre[i], fab) if j + 1 < len(lane) else pre[i]
+            for lane in lanes for j, i in enumerate(lane)]
+    st = build_steps_batch(segs, P)
+    n = st.n.astype(np.int64)
+    bounds, k = [], 0
+    for lane in lanes:
+        bounds.append(np.concatenate([[0], np.cumsum(n[k:k + len(lane)])]))
+        k += len(lane)
+    ns = np.array([b[-1] for b in bounds], np.int32)
+    E = _bucket(max(int(ns.max(initial=0)), 1))
+    x = np.zeros((_bucket(len(lanes), lo=1), E, st.x.shape[2]), np.int32)
+    x[:, :, st.w:] = (-1, 0, NIL, NIL)   # padding: no invoke
+    k = 0
+    for j, lane in enumerate(lanes):
+        x[j, :ns[j]] = np.concatenate(
+            [st.x[k + m, :n[k + m]] for m in range(len(lane))])
+        k += len(lane)
+    return x, np.pad(ns, (0, len(x) - len(lanes))), bounds
+
+
+@functools.lru_cache(maxsize=1)
+def _reviver():
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def revive(carry, fresh, mask, at):
+        """The dense batch carry with the lanes in `mask` started afresh
+        at step `at`, their attestation residue and largest count
+        kept."""
+        out = [jnp.where(mask.reshape(mask.shape + (1,) * (c.ndim - 1)),
+                         f, c) for c, f in zip(carry, fresh)]
+        out[0] = jnp.where(mask, at, carry[0])
+        out[-3], out[-1] = carry[-3], carry[-1]
+        return tuple(out)
+
+    return revive
 
 
 def analysis_tpu_batch(model, hists: list, frontier: int = 1024,
@@ -2563,7 +2931,7 @@ def analysis_tpu_batch(model, hists: list, frontier: int = 1024,
     with section("wgl.encode"):
         pre = [encode_ops_for_model(model, h) for h in hists]
     results = _decide_in_rounds(pre, run_round,
-                                DEVICE_MODELS[model.device_model].droppable,
+                                DEVICE_MODELS[model.device_model],
                                 deadlines=engine != "sort", site="batch")
     dur = (_time.monotonic() - t0) * 1e3
     for r in results:
@@ -2583,7 +2951,13 @@ def _check_group(model, hists: list, ops: list, group: _Group,
     budget_s / cancel like the scalar path; keys still undecided report
     'unknown'. Returns {key: result} and the keys whose frontier
     overflowed on an invalid verdict, for the caller to check again at
-    a larger frontier."""
+    a larger frontier.
+
+    A dense group of a model with a DeviceModel.reset packs its keys
+    into lanes (_pack_lanes), each lane checking its keys one after
+    another; a lane found dead before its last key starts afresh at the
+    next, in chunks of at most PACKED_CHUNK steps. A packed key's
+    max-frontier is its lane's."""
     import jax
     import jax.numpy as jnp
 
@@ -2597,18 +2971,28 @@ def _check_group(model, hists: list, ops: list, group: _Group,
             srange = _state_range(name, model,
                                   [ops[i] for i in group.keys])
     results: dict[int, dict] = {}
+    reset = DEVICE_MODELS[name].reset if dense is not None else None
     with section("wgl.steps"):
         items = [(i, ops[i]) for i in group.keys]
         E = _bucket(max(max(event_count(o) for _, o in items), 1))
-        # bucket the batch axis like E: the vmapped kernels are jitted
-        # per (B, E) shape, so an exact B would recompile the whole
-        # family for every distinct key count — pad with zero-step
-        # lanes (n=0: never consumed, frontier stays at the initial
-        # config), skipped by the per-item j < len(items) reads below
-        lanes = _bucket(len(items), lo=1)
-        stack = build_steps_batch([o for _, o in items], slots, t=E,
-                                  lanes=lanes)
-        x_np, ns = stack.x, stack.n
+        if reset is None:
+            # a lane a key. Bucket the batch axis like E: the vmapped
+            # kernels are jitted per (B, E) shape, so an exact B would
+            # recompile the whole family for every distinct key count —
+            # pad with zero-step lanes (n=0: never consumed, frontier
+            # stays at the initial config), skipped by the reads of the
+            # real lanes below
+            packed = [[i] for i, _ in items]
+            stack = build_steps_batch([o for _, o in items], slots, t=E,
+                                      lanes=_bucket(len(items), lo=1))
+            x_np, ns = stack.x, stack.n
+            bounds = [np.array([0, n]) for n in ns[:len(items)]]
+        else:
+            _, packed = _pack_lanes(group.keys, ops, dense[1], dense[2])
+            x_np, ns, bounds = _lane_steps(packed, ops, dense[2],
+                                           reset(model.device_state()))
+            chunk_entries = min(chunk_entries, PACKED_CHUNK)
+        lanes, real, rows = len(x_np), len(packed), x_np.shape[1]
 
     family = "dense" if dense is not None else "sort"
     _M_GROUPS.labels(family=family).inc()
@@ -2618,7 +3002,7 @@ def _check_group(model, hists: list, ops: list, group: _Group,
                  keys=len(items), steps=int(ns.max())):
         with section("wgl.stage"):
             if dense is not None:
-                k = _dense_kernel(name, dense[0], dense[1], dense[2], E,
+                k = _dense_kernel(name, dense[0], dense[1], dense[2], rows,
                                   pallas=pallas)
             else:
                 k = _kernel(name, frontier, slots, E,
@@ -2633,15 +3017,43 @@ def _check_group(model, hists: list, ops: list, group: _Group,
                     abft.digest_device(x), site="batch steps attest"),
                     abft.digest_host(x_np))
             s0 = jnp.full(lanes, model.device_state(), jnp.int32)
-            carry = jax.vmap(k.init_carry)(s0)
-        e = 0
+            carry = fresh = jax.vmap(k.init_carry)(s0)
+        e, all_dead = 0, False
         n_max = int(ns.max())
+        # a packed lane whose frontier died before its last key starts
+        # afresh at its next; dead[j]: the keys (places in packed[j])
+        # lane j found invalid
+        dead: dict[int, set] = {}
+
+        def revive(src, dst):
+            """`dst` with each lane that `src` shows dead before its last
+            key started afresh at the next (`src` is `dst`, or the carry
+            before a chunk, in which a dead lane takes no step); and
+            src's steps and counts. Syncs on `src`."""
+            at, counts = (np.asarray(a) for a in guarded_device_get(
+                (src[0], src[-2]), site="batch lane liveness"))
+            mask = np.zeros(lanes, bool)
+            start = np.zeros(lanes, np.int32)
+            for j in np.flatnonzero(counts[:real] == 0):
+                seg = int(np.searchsorted(bounds[j], at[j] - 1,
+                                          side="right")) - 1
+                dead.setdefault(j, set()).add(seg)
+                if seg + 1 < len(packed[j]):
+                    mask[j], start[j] = True, bounds[j][seg + 1]
+            if mask.any():
+                dst = _reviver()(dst, fresh, jnp.asarray(mask),
+                                 jnp.asarray(start))
+                at, counts = np.where(mask, start, at), \
+                    np.where(mask, 1, counts)
+            return dst, mask.any(), at, counts
+
         # pipelined like the scalar loop: enqueue the next vmapped
         # chunk, then read the PREVIOUS chunk's frontier counts while
         # the device computes — all-dead detection lags one chunk
         # (safe: dead frontiers stay dead) in exchange for overlapping
         # the per-chunk sync with compute
         chunk_obs = _M_CHUNK.labels(site="batch", family=family)
+        cells = lanes * dense[1] << dense[2] if dense is not None else 0
         while e < n_max:
             stop = min(e + chunk_entries, n_max)
             maybe_inject_fault("batch")
@@ -2649,13 +3061,19 @@ def _check_group(model, hists: list, ops: list, group: _Group,
             with section("wgl.batch.chunk"):
                 nxt = k.check_chunk_batch(
                     x, jnp.asarray(np.minimum(ns, stop)), carry)
+                if cells:
+                    _M_TABLE_CELLS.labels(family=family).inc(
+                        cells * (stop - e))
                 prev, carry = carry, nxt
                 e = stop
                 # pad entries never consume, so their frontiers stay
-                # alive forever — only the real items' liveness counts
-                all_dead = not np.asarray(guarded_device_get(
-                    prev[-2],
-                    site="batch liveness"))[:len(items)].any()
+                # alive forever — only the real lanes' liveness counts
+                if reset is None:
+                    revived, counts = False, np.asarray(guarded_device_get(
+                        prev[-2], site="batch liveness"))
+                else:
+                    carry, revived, _, counts = revive(prev, carry)
+                all_dead = not revived and not counts[:real].any()
             chunk_obs.observe(_time.monotonic() - t_chunk)
             if all_dead:
                 carry = prev   # every frontier died: all definite
@@ -2665,14 +3083,27 @@ def _check_group(model, hists: list, ops: list, group: _Group,
                         and _time.monotonic() - t0 > budget_s) \
                         or (cancel is not None and cancel()):
                     break
-        # every lane runs every step advanced, over the E rows staged for
+        while reset is not None and not all_dead and not (
+                (budget_s is not None and _time.monotonic() - t0 > budget_s)
+                or (cancel is not None and cancel())):
+            # lanes revived or found dead after the last chunk: run the
+            # keys they have left
+            carry, _, at, counts = revive(carry, carry)
+            left = int(np.where(counts > 0, ns - at, 0)[:real].max())
+            if left <= 0:
+                break
+            with section("wgl.batch.chunk"):
+                carry = k.check_chunk_batch(x, jnp.asarray(ns), carry)
+            _M_TABLE_CELLS.labels(family=family).inc(cells * left)
+            e += left
+        # every lane runs every step advanced, over the rows staged for
         # it; a real key needs only its own steps
         _M_LANE_STEPS.labels(family=family, kind="staged").inc(
-            lanes * E)
+            lanes * rows)
         _M_LANE_STEPS.labels(family=family, kind="dispatched").inc(
             lanes * e)
         _M_LANE_STEPS.labels(family=family, kind="useful").inc(
-            int(np.minimum(ns[:len(items)], e).sum()))
+            int(np.minimum(ns[:real], e).sum()))
         with section("wgl.summarize"):
             if attest_on:
                 # per-key carry attestation at the batch's final
@@ -2701,28 +3132,34 @@ def _check_group(model, hists: list, ops: list, group: _Group,
                            dedup_engine(frontier, slots,
                                         _pack_params(srange, slots),
                                         pallas))
-            # a key is decided if it consumed all entries or its
-            # frontier died (death is definitive no matter how many
-            # entries remain)
-            decided = (np.asarray(consumed) >= ns) | (counts == 0)
+            # a key is invalid where its lane's frontier died (death is
+            # definitive no matter how many entries remain), valid where
+            # its lane went past its steps alive, else undecided
+            consumed = np.asarray(consumed)
             suspects = []    # overflow + invalid: escalate together
             invalids = []    # definite invalid: blame together
-            for j, (i, ops) in enumerate(items):
-                if not bool(decided[j]):
-                    results[i] = _unknown_result(
-                        ops, "batch budget exhausted/cancelled before "
-                        "this key's search finished", t0)
-                elif bool(ok[j]):
-                    results[i] = {
-                        "valid?": True, "analyzer": "tpu-wgl-batch",
-                        "dedup": batch_dedup,
-                        "op-count": len(ops),
-                        "max-frontier": int(max_count[j]),
-                        "configs": [], "final-paths": []}
-                elif bool(overflow[j]):
-                    suspects.append(i)
-                else:
-                    invalids.append((j, i, ops))
+            for j, lane in enumerate(packed):
+                last = int(np.searchsorted(bounds[j], consumed[j] - 1,
+                                           side="right")) - 1
+                for seg, i in enumerate(lane):
+                    if seg in dead.get(j, ()) or (
+                            counts[j] == 0 and seg == last):
+                        if bool(overflow[j]):
+                            suspects.append(i)
+                        else:
+                            invalids.append((j, i, ops[i]))
+                    elif consumed[j] >= bounds[j][seg + 1] and (
+                            counts[j] > 0 or seg < last):
+                        results[i] = {
+                            "valid?": True, "analyzer": "tpu-wgl-batch",
+                            "dedup": batch_dedup,
+                            "op-count": len(ops[i]),
+                            "max-frontier": int(max_count[j]),
+                            "configs": [], "final-paths": []}
+                    else:
+                        results[i] = _unknown_result(
+                            ops[i], "batch budget exhausted/cancelled "
+                            "before this key's search finished", t0)
         if invalids:
             with section("wgl.blame"):
                 # one vmapped unmerged pass names every culprit op (the

@@ -14,9 +14,50 @@ the host checker.
 from __future__ import annotations
 
 import dataclasses
+import numbers
 from typing import Any, Optional
 
 from .history import DeviceEncodingError, UQ_COUNT_MAX, UQ_VALUES, F_CAS, F_READ, F_WRITE, NIL
+
+
+# -- multi-register device layout -------------------------------------------
+# The state packs subkeys [0, MR_SUBKEYS) as mixed-radix digits, subkey k
+# the k-th least significant, digit 0 for nil and v + 1 for value v in
+# [0, MR_VALUES): MR_RADIX ** MR_SUBKEYS = 216 states, yugabyte's
+# multi-key-acid domain (3 subkeys, values 0-4).
+MR_SUBKEYS = 3
+MR_VALUES = 5
+MR_RADIX = MR_VALUES + 1
+
+
+def mr_digit(v: Any) -> int:
+    """The digit of a subkey value: 0 for nil, v + 1 for v in the
+    domain. Raises DeviceEncodingError outside it."""
+    if v is None:
+        return 0
+    if isinstance(v, bool) or not isinstance(v, numbers.Integral) \
+            or not 0 <= v < MR_VALUES:
+        raise DeviceEncodingError(
+            f"multi-register value {v!r} outside the device domain "
+            f"[0, {MR_VALUES}) — use the host model")
+    return int(v) + 1
+
+
+def mr_subkey(k: Any) -> int:
+    """A subkey's digit position. Raises DeviceEncodingError outside
+    [0, MR_SUBKEYS)."""
+    if isinstance(k, bool) or not isinstance(k, numbers.Integral) \
+            or not 0 <= k < MR_SUBKEYS:
+        raise DeviceEncodingError(
+            f"multi-register subkey {k!r} outside the device domain "
+            f"[0, {MR_SUBKEYS}) — use the host model")
+    return int(k)
+
+
+def mr_pack(values: dict) -> int:
+    """The packed state of a {subkey: value} map."""
+    return sum(mr_digit(v) * MR_RADIX ** mr_subkey(k)
+               for k, v in values.items())
 
 
 class Inconsistent:
@@ -277,6 +318,8 @@ class MultiRegister(Model):
     model (`yugabyte/src/yugabyte/multi_key_acid.clj:16-38`)."""
     values: tuple = ()   # sorted ((k, v), ...) so the model hashes
 
+    device_model = "multi-register"
+
     def step(self, op: dict):
         state = dict(self.values)
         for f, k, v in op["value"]:
@@ -290,6 +333,9 @@ class MultiRegister(Model):
             else:
                 return inconsistent(f"unknown micro-op f={f!r}")
         return MultiRegister(tuple(sorted(state.items())))
+
+    def device_state(self) -> int:
+        return mr_pack(dict(self.values))
 
 
 def cas_register(value: Any = None) -> CASRegister:
@@ -356,3 +402,20 @@ def device_step_mutex(state: int, f: int, a: int, b: int) \
     if f == 1:
         return state == 1, 0
     return False, state
+
+
+def device_step_multi_register(state: int, f: int, a: int, b: int) \
+        -> tuple[bool, int]:
+    """Pure integer semantics of one multi-register transaction, as
+    checker/wgl.py's multi_register_codec encodes it: for subkey k,
+    bit 9 + k of `a` constrains its digit to bits [3k, 3k + 3) of `a`,
+    and bit 9 + k of `b` sets it to bits [3k, 3k + 3) of `b`. f only
+    names the kind (0 reads only, 1 writes)."""
+    legal, new = True, 0
+    for k in range(MR_SUBKEYS):
+        w = MR_RADIX ** k
+        d = state // w % MR_RADIX
+        if (a >> (9 + k)) & 1 and d != (a >> (3 * k)) & 7:
+            legal = False
+        new += ((b >> (3 * k)) & 7 if (b >> (9 + k)) & 1 else d) * w
+    return legal, new

@@ -302,6 +302,7 @@ def targets_spec(test: dict) -> dict:
     from .checker.streaming import (DEFAULT_CHECKPOINT_EVERY,
                                     DEFAULT_CHUNK_ENTRIES,
                                     _walk_checkers)
+    from .checker.wgl import DEVICE_MODELS
 
     specs: dict = {}
     tiered = _screen.tier_is_screen(test.get("tier"))
@@ -318,6 +319,9 @@ def targets_spec(test: dict) -> dict:
         if isinstance(c, Linearizable) and "linear" not in specs:
             if c.model.device_model is None or c.algorithm == "host":
                 continue
+            if getattr(DEVICE_MODELS.get(c.model.device_model), "image",
+                       None) is not None:
+                continue       # WglStream has no stream for it yet
             srange = test.get("online-state-range")
             specs["linear"] = {
                 "kind": "wgl",
@@ -647,7 +651,7 @@ def chunk_cost(stream, calibration=None) -> ChunkPrice:
         eng = stream.engine if stream.engine in ("dense", "sort") \
             else "auto"
         dec = wgl.select_engine(tuple(srange), stream.p, stream.chunk,
-                                slots=stream.p,
+                                model=stream.name, slots=stream.p,
                                 frontier=stream.frontier,
                                 pallas=stream.pallas, engine=eng,
                                 calibration=calibration)

@@ -435,6 +435,7 @@ SORT_SHAPE = dict(srange=(0, 511), p=6, n=200)
 
 def _select(shape, cal=None):
     return wgl.select_engine(shape["srange"], shape["p"], shape["n"],
+                             model="cas-register",
                              slots=shape["p"], frontier=128,
                              calibration=cal)
 

@@ -256,8 +256,12 @@ def test_stream_range_escape_downgrades_packed_sort():
 
 # -- engine autoselect (cost model) -----------------------------------------
 
+def _select(srange, p, n, **kw):
+    return wgl.select_engine(srange, p, n, model=MODEL.device_model, **kw)
+
+
 def test_select_engine_prefers_dense_on_small_tables():
-    d = wgl.select_engine((-1, 4), 8, 1000)
+    d = _select((-1, 4), 8, 1000)
     assert d.family == "dense" and d.dense is not None
     assert d.dedup == wgl.DEDUP_NONE
 
@@ -265,29 +269,26 @@ def test_select_engine_prefers_dense_on_small_tables():
 def test_select_engine_routes_big_tables_to_sort():
     # S=512 x 2^13 fits the dense caps but its modeled closure work
     # dwarfs the sort family's — the cost model must route it away
-    d = wgl.select_engine((0, 400), 13, 10_000)
+    d = _select((0, 400), 13, 10_000)
     assert d.family == "sort"
     assert "dense" in d.reason
 
 
 def test_select_engine_dense_slot_cap():
-    d = wgl.select_engine((-1, 4), 8, 1000, dense_slot_cap=6)
+    d = _select((-1, 4), 8, 1000, dense_slot_cap=6)
     assert d.family == "sort" and "dense_slot_cap" in d.reason
     with pytest.raises(ValueError):
-        wgl.select_engine((-1, 4), 8, 1000, engine="dense",
-                          dense_slot_cap=6)
+        _select((-1, 4), 8, 1000, engine="dense", dense_slot_cap=6)
 
 
 def test_select_engine_forced_families():
-    assert wgl.select_engine((-1, 4), 8, 100,
-                             engine="dense").family == "dense"
-    assert wgl.select_engine((-1, 4), 8, 100,
-                             engine="sort").family == "sort"
+    assert _select((-1, 4), 8, 100, engine="dense").family == "dense"
+    assert _select((-1, 4), 8, 100, engine="sort").family == "sort"
     with pytest.raises(ValueError):
-        wgl.select_engine((-1, 4), 8, 100, engine="nope")
+        _select((-1, 4), 8, 100, engine="nope")
     # forced dense past the caps still raises (offline contract)
     with pytest.raises(ValueError):
-        wgl.select_engine((0, 10 ** 6), 8, 100, engine="dense")
+        _select((0, 10 ** 6), 8, 100, engine="dense")
 
 
 def test_checker_options_flow_through_linearizable():
